@@ -33,7 +33,7 @@ import contextlib
 import json
 import os
 import signal
-
+import sys
 import time
 
 import numpy as np
@@ -45,7 +45,12 @@ N_ITER = int(os.environ.get("BENCH_ITERS", "5" if SF <= 10 else "1"))
 # BENCH_FULL=1: additionally time ALL 22 TPC-H queries (the BASELINE.md
 # target metric is the full suite; q1/q3/q5 stay the headline line)
 FULL = os.environ.get("BENCH_FULL", "0") == "1"
-HBM_GBPS = 819.0  # v5e peak HBM bandwidth; v5p is higher, so safe bound
+# Peak HBM bandwidth in GB/s by jax's ``device_kind``. Source: Google
+# Cloud documentation, "TPU v5e" system architecture (16 GB HBM2e at
+# 819 GB/s per chip). A device that is not in the table is an error,
+# not a default.
+PEAK_HBM_GBPS = {"TPU v5 lite": 819.0}  # how a v5e chip names itself
+HBM_GBPS = None  # set by main() from the device it found
 
 # Per-query wall-clock cap. A query that hangs (or an SF that turns out
 # to be hours of parquet IO) records {"error": "timeout"} and the run
@@ -54,13 +59,14 @@ HBM_GBPS = 819.0  # v5e peak HBM bandwidth; v5p is higher, so safe bound
 # no parseable output at all.
 QUERY_TIMEOUT_S = float(os.environ.get("BENCH_QUERY_TIMEOUT",
                                        "600" if SF <= 10 else "1200"))
-# Snapshot written after every query so even a SIGKILL leaves the
-# completed queries' numbers on disk.
-PARTIAL_PATH = os.environ.get("BENCH_PARTIAL_PATH", "BENCH_partial.json")
+# BENCH_PARTIAL_PATH=<file>: a snapshot is written there after every
+# query so even a SIGKILL leaves the completed queries' numbers on disk.
+# Unset (the default), nothing is written.
+PARTIAL_PATH = os.environ.get("BENCH_PARTIAL_PATH", "")
 
 # Global wall-clock budget for the WHOLE bench process. The harness
 # runs bench under an external timeout; hitting that kills the process
-# (rc=124) with only BENCH_partial.json on disk. Budgeting inside the
+# (rc=124) with at most the partial snapshot on disk. Budgeting inside the
 # process instead skips remaining phases (marked in the JSON) so the
 # final complete document always prints. 0 disables.
 WALL_BUDGET_S = float(os.environ.get("BENCH_WALL_BUDGET", "3300"))
@@ -81,17 +87,12 @@ def _wall_remaining() -> float:
     return WALL_BUDGET_S - (time.time() - _WALL_T0)
 
 
-def _query_deadline(extra_s: float = 0.0, cap_s: float = None) -> float:
+def _query_deadline(cap_s: float = None) -> float:
     """Per-query alarm, never longer than what the wall budget has
     left (so the last query degrades to a marked timeout instead of
-    blowing the whole process budget). ``extra_s`` extends the cap for
-    phases where a background fused compile runs concurrently with the
-    measured query (compile/service hot-swap) — a query correctly
-    served by the chunked tier while XLA compiles off-thread must not
-    be marked timed-out just because the compile is still running.
-    ``cap_s`` tightens the cap below QUERY_TIMEOUT_S for auxiliary
-    phases (see PHASE_BUDGET_S)."""
-    base = QUERY_TIMEOUT_S + extra_s
+    blowing the whole process budget). ``cap_s`` tightens the cap below
+    QUERY_TIMEOUT_S for auxiliary phases (see PHASE_BUDGET_S)."""
+    base = QUERY_TIMEOUT_S
     if cap_s is not None:
         base = min(base, cap_s)
     rem = _wall_remaining()
@@ -141,19 +142,13 @@ def _deadline(seconds: float):
 
 
 def _snapshot(payload: dict) -> None:
-    try:
-        with open(PARTIAL_PATH, "w") as f:
-            json.dump(payload, f)
-    except OSError:
-        pass
+    if not PARTIAL_PATH:
+        return
+    with open(PARTIAL_PATH, "w") as f:
+        json.dump(payload, f)
 
 # documented Spark CPU local[*] SF1 estimates (see module docstring)
 BASELINE_MS = {1: 900.0, 3: 700.0, 5: 1100.0}
-
-# BENCH_WARMUP=0 skips the cold-start A/B phase (first-query latency:
-# empty executable store vs populated store vs background-compile path,
-# each measured in a FRESH subprocess so jit caches are honestly cold)
-WARMUP_MODE = os.environ.get("BENCH_WARMUP", "1") == "1"
 
 # BENCH_MVIEW=0 skips the materialized-view refresh A/B (K appended
 # micro-batches x M readers, spark.tpu.mview.incremental off vs on;
@@ -200,132 +195,6 @@ FLEET_MODE = os.environ.get("BENCH_FLEET", "1") == "1"
 # 'slo' in the result JSON
 SLO_MODE = os.environ.get("BENCH_SLO", "1") == "1"
 
-
-def _warmup_child() -> None:
-    """Subprocess entry for the cold-start A/B (BENCH_WARMUP_CHILD=1):
-    a fresh process = honestly cold jit/XLA state. Builds a session
-    against the store dir in BENCH_WARMUP_STORE, times the FIRST
-    collect of the query (that wall time IS the cold-start number),
-    then runs two more collects so the fused re-execution path AOT-
-    compiles and persists — populating the store for the next child.
-    Prints one marker line of JSON on stdout and exits."""
-    import sys
-
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
-
-    from spark_tpu import metrics
-    from spark_tpu.api.session import SparkSession
-    from spark_tpu.tpch.gen import ensure_dataset, register_views
-    from spark_tpu.tpch.queries import QUERIES
-
-    qnum = int(os.environ.get("BENCH_WARMUP_QNUM", "1"))
-    store = os.environ.get("BENCH_WARMUP_STORE", "")
-    background = os.environ.get("BENCH_WARMUP_BACKGROUND", "0") == "1"
-
-    builder = SparkSession.builder
-    if store:
-        builder = builder.config("spark.tpu.compile.store.dir", store)
-    if background:
-        builder = builder.config("spark.tpu.compile.background", "true")
-    spark = builder.getOrCreate()
-    register_views(spark, path=ensure_dataset(SF))
-
-    df = spark.sql(QUERIES[qnum])
-    t0 = time.perf_counter()
-    rows = df.collect()
-    first_ms = (time.perf_counter() - t0) * 1e3
-    digest = __import__("hashlib").sha1(
-        repr([tuple(r) for r in rows]).encode()).hexdigest()[:16]
-    # two more runs: the traced/fused path compiles (and persists to
-    # the store) so the NEXT child's first query can hit the cache
-    df.collect()
-    df.collect()
-    svc = spark.compile_service
-    if svc is not None:
-        svc.wait_background(timeout=QUERY_TIMEOUT_S)
-        post = [tuple(r) for r in df.collect()]
-        post_digest = __import__("hashlib").sha1(
-            repr(post).encode()).hexdigest()[:16]
-    else:
-        post_digest = digest
-    print("BENCH_WARMUP_CHILD_RESULT " + json.dumps({
-        "first_query_ms": round(first_ms, 1),
-        "rows": len(rows),
-        "digest": digest,
-        "post_swap_digest": post_digest,
-        "exec_store": metrics.exec_store_stats(),
-        "compile_cache": metrics.compile_cache_stats(),
-    }), flush=True)
-    sys.exit(0)
-
-
-def _spawn_warmup_child(store: str, background: bool,
-                        qnum: int, timeout_s: float) -> dict:
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env.update({
-        "BENCH_WARMUP_CHILD": "1",
-        "BENCH_WARMUP_STORE": store,
-        "BENCH_WARMUP_BACKGROUND": "1" if background else "0",
-        "BENCH_WARMUP_QNUM": str(qnum),
-    })
-    try:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__)],
-            env=env, capture_output=True, text=True, timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        return {"error": f"timeout after {timeout_s:.0f}s"}
-    for line in proc.stdout.splitlines():
-        if line.startswith("BENCH_WARMUP_CHILD_RESULT "):
-            return json.loads(line.split(" ", 1)[1])
-    return {"error": f"child rc={proc.returncode}: "
-                     f"{proc.stderr.strip()[-500:]}"}
-
-
-def _run_warmup_ab(qnum: int = 1) -> dict:
-    """Cold-start A/B (ROADMAP item 1 acceptance): first-query latency
-    in a fresh process with (a) an empty executable store, (b) the
-    store (a) populated — the cross-session cache win, target >= 5x —
-    and (c) an empty store with background compile on — the first
-    request must be served through the chunked tier without blocking
-    on the fused XLA compile. Byte-identity is asserted across all
-    three children AND across (c)'s pre-swap/post-swap executions."""
-    import tempfile
-
-    store_ab = tempfile.mkdtemp(prefix="bench_exec_store_")
-    store_bg = tempfile.mkdtemp(prefix="bench_exec_store_bg_")
-    out: dict = {"query": qnum}
-    # empty-store cold start: pays trace + XLA compile + store put
-    out["cold_empty"] = _spawn_warmup_child(
-        store_ab, False, qnum, _query_deadline())
-    # populated-store cold start: fresh process, same store dir
-    out["cold_populated"] = _spawn_warmup_child(
-        store_ab, False, qnum, _query_deadline())
-    # background-compile path: chunked serve while XLA compiles
-    # off-thread — the child's own runtime covers the compile, so its
-    # timeout gets the background allowance (see _query_deadline)
-    out["background"] = _spawn_warmup_child(
-        store_bg, True, qnum, _query_deadline(extra_s=QUERY_TIMEOUT_S))
-
-    a, b, c = out["cold_empty"], out["cold_populated"], out["background"]
-    if "first_query_ms" in a and "first_query_ms" in b:
-        out["speedup_populated_vs_empty"] = round(
-            a["first_query_ms"] / max(b["first_query_ms"], 1e-3), 2)
-        out["store_hit_on_populated"] = \
-            b.get("exec_store", {}).get("hits", 0) > 0 \
-            or b.get("compile_cache", {}).get("hits", 0) > 0
-    digests = {r.get("digest") for r in (a, b, c) if r.get("digest")}
-    out["byte_identical"] = len(digests) <= 1 and all(
-        r.get("digest") == r.get("post_swap_digest")
-        for r in (a, b, c) if r.get("digest"))
-    if "exec_store" in c:
-        out["background_served_without_blocking"] = \
-            c["exec_store"].get("background", 0) > 0
-    return out
 
 # robustness events worth surfacing in the result JSON: a benchmark run
 # that silently retried stages or degraded to the chunked tier is not
@@ -1011,10 +880,6 @@ def main():
 
     import jax
 
-    if os.environ.get("BENCH_WARMUP_CHILD") == "1":
-        _warmup_child()
-        return
-
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
         "--concurrency", type=int,
@@ -1036,8 +901,6 @@ def main():
              "on; qps/p50/p95 + byte-identity land under 'serve'")
     args = ap.parse_args()
 
-    jax.config.update("jax_enable_x64", True)
-
     from spark_tpu.api.session import SparkSession
     from spark_tpu.plan.optimizer import optimize
     from spark_tpu.plan.subquery import rewrite_subqueries
@@ -1045,7 +908,17 @@ def main():
     from spark_tpu.tpch.gen import ensure_dataset, register_views
     from spark_tpu.tpch.queries import QUERIES
 
-    platform = jax.devices()[0].platform
+    global HBM_GBPS
+    device = jax.devices()[0]
+    platform = device.platform
+    if platform != "tpu":
+        sys.exit(f"bench.py measures the TPU and found platform="
+                 f"{platform!r}: refusing to run (no CPU fallback)")
+    if device.device_kind not in PEAK_HBM_GBPS:
+        sys.exit(f"bench.py has no peak bandwidth for device_kind="
+                 f"{device.device_kind!r}: add it to PEAK_HBM_GBPS with "
+                 f"its source")
+    HBM_GBPS = PEAK_HBM_GBPS[device.device_kind]
     builder = SparkSession.builder
     # BENCH_MASTER=mesh[N] runs the whole benchmark distributed (and
     # makes the adaptive A/B phase meaningful — it needs exchanges)
@@ -1062,7 +935,6 @@ def main():
     io_s = time.time() - t0
 
     results = {}
-    import sys
 
     # every phase (or query) skipped because the wall budget ran out,
     # by name — the final JSON carries the explicit list so a reader
@@ -1099,20 +971,6 @@ def main():
             results[qnum] = {"error": f"{type(e).__name__}: {e}"}
         _phase_snapshot()
 
-
-    warmup = None
-    if WARMUP_MODE:
-        if _wall_remaining() <= 5:
-            warmup = _budget_skip("warmup")
-        else:
-            print("[bench] warmup A/B: empty store vs populated store "
-                  "vs background compile (fresh subprocesses)",
-                  file=sys.stderr, flush=True)
-            try:
-                warmup = _run_warmup_ab(qnum=1)
-            except Exception as e:
-                warmup = {"error": f"{type(e).__name__}: {e}"}
-        _phase_snapshot(warmup=warmup)
 
     full = {}
     if FULL:
@@ -1363,12 +1221,14 @@ def main():
         "value": round(total_ms, 1),
         "unit": "ms",
         # warmup is accounted SEPARATELY from the headline value: the
-        # metric is steady-state wall-clock; cold-start cost has its
-        # own A/B block ("warmup") and this total
+        # metric is steady-state wall-clock
         "warmup_total_s": round(
             sum(r.get("warmup_s", 0.0) for r in ok.values()), 1),
         "vs_baseline": round(vs, 3),
         "platform": platform,
+        "device_kind": device.device_kind,
+        "device_count": len(jax.devices()),
+        "peak_hbm_gbps": HBM_GBPS,
         "sf": SF,
         "iters": N_ITER,
         "query_timeout_s": QUERY_TIMEOUT_S,
@@ -1381,7 +1241,6 @@ def main():
         "wall_used_s": round(time.time() - _WALL_T0, 1),
         "wall_budget_skipped": wall_skipped,
         "queries": {str(k): v for k, v in results.items()},
-        **({"warmup": warmup} if warmup is not None else {}),
         **({"cached": cached} if cached is not None else {}),
         **({"adaptive": adaptive} if adaptive is not None else {}),
         **({"serving": serving} if serving is not None else {}),
@@ -1402,7 +1261,27 @@ def main():
     # kills the process between here and stdout flush (rc=124 with
     # parsed:null) still finds every completed result on disk
     _snapshot(final)
-    print(json.dumps(final))
+    print(json.dumps(final), flush=True)
+    failed = _failed_phases(final)
+    if failed:
+        print(f"[bench] FAILED phases: {failed}", file=sys.stderr)
+        sys.exit(1)
+
+
+def _failed_phases(doc, path: str = "") -> list:
+    """Every place in the result document where a phase or a query
+    recorded an error (a timeout included). Wall-budget skips are
+    listed under wall_budget_skipped and are not failures."""
+    out = []
+    if isinstance(doc, dict):
+        err = doc.get("error")
+        if isinstance(err, str) and not err.startswith("skipped"):
+            out.append(path or "?")
+        for k, v in doc.items():
+            out.extend(_failed_phases(v, f"{path}.{k}" if path else str(k)))
+    elif isinstance(doc, str) and doc.startswith("error:"):
+        out.append(path)
+    return out
 
 
 def _run_cached(spark, qnums, rounds: int = 3) -> dict:

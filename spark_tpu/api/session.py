@@ -7,6 +7,7 @@ bootstrap, the 'cluster' is the jax device mesh).
 from __future__ import annotations
 
 import datetime
+import os
 import threading
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Union
 
@@ -22,51 +23,30 @@ from spark_tpu.plan import logical as L
 from spark_tpu.types import Field, Schema
 
 
+#: the compile cache's home when JAX_COMPILATION_CACHE_DIR is unset: a
+#: fixed path inside the checkout (the path is part of what jax keys
+#: entries on, so a directory that moves never hits)
+DEFAULT_JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
 def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache. XLA compiles on this class of
-    host are multi-second even for trivial programs; the disk cache turns
-    warm-process startup into sub-second loads (the analogue of the
-    reference reusing Janino-compiled classes across queries,
-    CodeGenerator.scala:1442 'cache')."""
-    import os
-
-    if os.environ.get("SPARK_TPU_JAX_CACHE") in ("0", "off"):
-        # XLA:CPU AOT (de)serialization is not reliable on this host
-        # class (observed: SIGSEGV in deserialize_executable and SIGABRT
-        # in serialize_executable deep into long multi-hundred-compile
-        # processes, always via the persistent cache paths; plus E-level
-        # 'machine feature +prefer-no-scatter not supported' loader
-        # warnings on every hit). The test suite opts out; normal
-        # sessions and the TPU bench keep the disk cache.
+    """Persistent XLA compilation cache: the one place that decides
+    where it lives. ``JAX_COMPILATION_CACHE_DIR`` set -> jax has read it
+    already and no directory is set here; unset -> ``<repo>/.jax_cache``.
+    ``SPARK_TPU_JAX_CACHE=0`` turns the cache off (the test suite: XLA:CPU
+    executable (de)serialization has crashed long multi-hundred-compile
+    processes). The disk cache turns warm-process startup into loads
+    (the analogue of the reference reusing Janino-compiled classes
+    across queries, CodeGenerator.scala:1442 'cache')."""
+    if os.environ.get("SPARK_TPU_JAX_CACHE", "").lower() in ("0", "off"):
         return
-
-    try:
-        platform = jax.default_backend()
-    except Exception:
-        platform = "unknown"
-    # AOT executables embed the compile machine's ISA features; loading
-    # them on a host without those features can SIGILL. Key the cache
-    # dir on a CPU-feature fingerprint as well as the backend.
-    import hashlib
-
-    try:
-        with open("/proc/cpuinfo") as f:
-            flags = next((ln for ln in f if ln.startswith("flags")), "")
-        cpu_tag = hashlib.sha1(flags.encode()).hexdigest()[:8]
-    except OSError:
-        import platform as _plat
-
-        cpu_tag = _plat.machine()
-    cache_dir = os.environ.get(
-        "SPARK_TPU_JAX_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     f"spark_tpu_jax_{platform}_{cpu_tag}"))
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        pass  # older jax without these flags: in-memory caching only
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          DEFAULT_JAX_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     _harden_cache_writes()
 
 
@@ -114,16 +94,12 @@ def _harden_cache_writes() -> None:
 
 
 def _instrument_compile_cache() -> None:
-    """Count persistent compilation-cache hits/misses, and keep the
-    managed executable store within its byte bound. jax's lookup funnel
-    is ``compilation_cache.get_executable_and_time`` — returns a
+    """Count persistent compilation-cache hits/misses. jax's lookup
+    funnel is ``compilation_cache.get_executable_and_time`` — returns a
     deserialized executable on a disk hit, None on a miss (followed by
     a fresh XLA compile, which jax then writes back to the cache dir).
     Wrapping it feeds metrics.note_compile_cache so warmup time is
-    attributable, and — when the compile service routes jax's cache
-    inside the spark.tpu.compile.store.dir root — schedules LRU budget
-    enforcement after each miss, so jax's own cache writes count
-    against the same size bound as our AOT entries."""
+    attributable."""
     try:
         from jax._src import compilation_cache as _cc
     except Exception:
@@ -136,22 +112,8 @@ def _instrument_compile_cache() -> None:
 
     def get_executable_and_time(*a, _orig=fn, **kw):
         out = _orig(*a, **kw)
-        try:
-            executable = out[0] if isinstance(out, tuple) else out
-            hit = executable is not None
-            _metrics.note_compile_cache(hit)
-            if not hit:
-                # a miss means jax is about to write a fresh cache
-                # entry: re-check the managed store's byte bound
-                # (misses happen once per compile — seconds apart —
-                # so the directory walk is off the hot path)
-                from spark_tpu.compile.service import active_service
-
-                svc = active_service()
-                if svc is not None and svc.store is not None:
-                    svc.store.enforce_budget()
-        except Exception:
-            pass
+        executable = out[0] if isinstance(out, tuple) else out
+        _metrics.note_compile_cache(executable is not None)
         return out
 
     get_executable_and_time._spark_tpu_counted = True

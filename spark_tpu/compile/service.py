@@ -28,7 +28,8 @@ import os
 import threading
 import time
 import weakref
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import jax
 
@@ -82,7 +83,8 @@ def maybe_service(session) -> Optional["CompileService"]:
 def build_stage_callable(tier: str, plan, trace_fn: Callable, example_args,
                          schema_box: dict, *, mesh_size: int = 1,
                          platform: Optional[str] = None,
-                         extra: Any = None) -> Callable:
+                         extra: Any = None,
+                         devices: Optional[Sequence] = None) -> Callable:
     """The callable a stage cache stores for a fresh entry.
 
     Without an active service (or with the store disabled) this is
@@ -99,7 +101,8 @@ def build_stage_callable(tier: str, plan, trace_fn: Callable, example_args,
         with trace.span("compile.probe", tier=tier):
             return svc.stage_callable(tier, plan, jitted, example_args,
                                       schema_box, mesh_size=mesh_size,
-                                      platform=platform, extra=extra)
+                                      platform=platform, extra=extra,
+                                      devices=devices)
     except Exception as e:
         metrics.record("compile", phase="stage_callable_error",
                        error=repr(e))
@@ -223,7 +226,6 @@ class CompileService:
         if self.root:
             self.store = ExecutableStore(
                 self.root, int(conf.get(CF.COMPILE_STORE_MAX_BYTES)))
-            self._route_jax_cache()
         hist_path = self._history_path_cfg or (
             os.path.join(self.root, "plan_history.jsonl")
             if self.root else "")
@@ -246,32 +248,18 @@ class CompileService:
         sess = self._session_ref()
         return sess.conf if sess is not None else CF.RuntimeConf()
 
-    def _route_jax_cache(self) -> None:
-        """Point jax's persistent XLA cache inside the store root so
-        the two halves of cross-session persistence (our AOT entries +
-        jax's per-computation cache) share one directory and one byte
-        bound. SPARK_TPU_JAX_CACHE=0 keeps the tier-1 suite's 'no
-        global cache writes' guarantee."""
-        if os.environ.get("SPARK_TPU_JAX_CACHE", "").lower() in ("0", "off"):
-            return
-        try:
-            xla_dir = os.path.join(self.root, "xla")
-            os.makedirs(xla_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", xla_dir)
-        except Exception:
-            pass
-
     # -- stage-cache integration ---------------------------------------------
 
     def stage_callable(self, tier: str, plan, jitted, example_args,
                        schema_box: dict, *, mesh_size: int = 1,
                        platform: Optional[str] = None,
-                       extra: Any = None) -> Callable:
+                       extra: Any = None,
+                       devices: Optional[Sequence] = None) -> Callable:
         store = self.store
         digest = stable_plan_fingerprint(
             tier, plan, example_args, mesh_size=mesh_size,
             platform=platform, extra=extra)
-        entry = store.load(digest, example_args)
+        entry = store.load(digest, example_args, devices)
         if entry is not None:
             metrics.note_exec_store("hits")
             metrics.record("compile", phase="store_hit", tier=tier,

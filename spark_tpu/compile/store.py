@@ -33,7 +33,7 @@ import os
 import pickle
 import threading
 import weakref
-from typing import Any, Optional, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
 
@@ -190,6 +190,12 @@ def stable_plan_fingerprint(tier: str, plan, args, *, mesh_size: int = 1,
     return hashlib.sha256(repr(payload).encode()).hexdigest()[:32]
 
 
+def _default_device():
+    """The device an uncommitted single-device computation runs on."""
+    dev = jax.config.jax_default_device
+    return dev if isinstance(dev, jax.Device) else jax.local_devices()[0]
+
+
 # ---- the store --------------------------------------------------------------
 
 
@@ -201,9 +207,6 @@ class ExecutableStore:
         entries/<digest>.exe   pickled {payload, in_tree, out_tree,
                                schema, sig} — payload is the serialized
                                XLA executable
-        xla/                   jax's persistent compilation cache when
-                               the session routes it here (managed by
-                               jax; counted against the same byte bound)
         plan_history.jsonl     served-plan history (service owns it)
 
     Writes are atomic (temp + rename); loads treat ANY failure as a
@@ -224,10 +227,17 @@ class ExecutableStore:
 
     # -- read side
 
-    def load(self, digest: str, args) -> Optional[dict]:
+    def load(self, digest: str, args,
+             devices: Optional[Sequence] = None) -> Optional[dict]:
         """Return {"compiled", "schema", "sig"} for a stored executable
         whose argument signature matches ``args``, or None. Corrupt or
-        mismatched-structure entries are evicted as misses."""
+        mismatched-structure entries are evicted as misses.
+
+        ``devices`` are the devices the executable runs on: a mesh
+        stage passes its mesh's, a single-device stage leaves None for
+        the default device. (jax's own default is every device of the
+        backend, which turns a one-device program into an N-shard one
+        as soon as N > 1 devices are visible.)"""
         with _LOADED_LOCK:
             cached = _LOADED.get((self.root, digest))
         if cached is not None:
@@ -243,7 +253,9 @@ class ExecutableStore:
             from jax.experimental import serialize_executable as _se
 
             compiled = _se.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"])
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=list(devices) if devices is not None
+                else [_default_device()])
         except Exception as e:
             # treat as a miss AND evict: a poisoned entry must not
             # wedge every future session

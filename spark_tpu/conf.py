@@ -122,7 +122,7 @@ PIPELINE_DEPTH = register(
     "host->device transfer) may run ahead of device compute. 0 runs "
     "the fully serial decode->filter->ship->compute loop; >=1 "
     "overlaps the stages (the ShuffleBlockFetcherIterator in-flight "
-    "window, applied to the host->device tunnel). Results are "
+    "window, applied to the host->device transfer). Results are "
     "byte-identical at every depth: chunks are consumed in source "
     "order, so the device merge order never changes.", int)
 
@@ -401,17 +401,16 @@ SEARCHSORTED_SORT_THRESHOLD = register(
 COMPILE_STORE_DIR = register(
     "spark.tpu.compile.store.dir", "",
     "Root directory of the cross-session executable store: serialized "
-    "AOT stage executables (entries/) plus jax's persistent XLA cache "
-    "(xla/) live here, keyed by a stable plan fingerprint + "
+    "AOT stage executables (entries/) live here, keyed by a stable "
+    "plan fingerprint + "
     "capacity/mesh/device-kind, so a fresh session or worker restart "
     "skips XLA entirely. Empty disables cross-session persistence "
     "(the in-process jit stage caches still apply).", str)
 
 COMPILE_STORE_MAX_BYTES = register(
     "spark.tpu.compile.store.maxBytes", 1 << 30,
-    "Size bound for the executable store directory (AOT entries + the "
-    "managed jax persistent-cache subdir); beyond it the least-"
-    "recently-used entry files are evicted.", int)
+    "Size bound for the executable store directory; beyond it the "
+    "least-recently-used entry files are evicted.", int)
 
 COMPILE_STORE_SERIALIZE = register(
     "spark.tpu.compile.store.serialize", True,

@@ -446,7 +446,7 @@ def reset_agg() -> None:
 #: whole-query native fusion (parallel/executor.py _try_fuse) — fused
 #: programs launched (one per query that fused), exchange+consumer
 #: spans folded into them, bailouts back to staged execution (see the
-#: per-reason fusion_bailout events for the taxonomy), and injected
+#: per-reason fusion_bailout events for the breakdown), and injected
 #: faults absorbed at fusion.decide. Shown in tracing.fusion_profile
 #: and the bench fusion phase.
 _FUSION = {"fused_programs": 0, "fused_spans": 0, "bailouts": 0,

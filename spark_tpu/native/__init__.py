@@ -35,13 +35,19 @@ _tried = False
 
 
 def _build() -> bool:
+    # a temp name of this process's own: several processes may build at
+    # once (xdist workers importing tests/test_native.py on a fresh
+    # checkout), and each must replace the target with a whole file
+    tmp = os.path.join(_DIR, f"_strkernels.{os.getpid()}.so.tmp")
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", _SO + ".tmp", _SRC]
+           "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
     except (OSError, subprocess.SubprocessError):
+        if os.path.exists(tmp):
+            os.unlink(tmp)
         return False
-    os.replace(_SO + ".tmp", _SO)
     return True
 
 

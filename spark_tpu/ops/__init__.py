@@ -7,9 +7,7 @@ qualify."""
 from spark_tpu.ops.pallas_agg import (  # noqa: F401
     maybe_pallas_seg_count,
     maybe_pallas_seg_max,
-    maybe_pallas_seg_mean,
     maybe_pallas_seg_min,
-    maybe_pallas_seg_sum,
     pallas_available,
     pallas_seg_minmax,
     pallas_seg_sum,

@@ -18,32 +18,26 @@ compare, not a pointer chase.
 Constraints (checked by ``pallas_available``): float32 data (TPU
 Pallas has no f64; the engine's f64 columns keep the XLA path),
 2 <= K <= 1024 (VMEM accumulator budget), data length padded to the
-block size by the wrapper. Tests run the same kernel with
-``interpret=True`` on CPU against a numpy oracle.
+block size by the wrapper. ``interpret`` is an argument that tests pass
+(or SPARK_TPU_PALLAS=interpret for a test that goes through the engine);
+it is never inferred from the backend. tests/test_pallas_ops.py checks
+results in interpret mode against numpy; tests/test_tpu_aot_compile.py
+compiles the kernels for a described v5e with x64 on, as the session
+runs them.
 
-Measured on a v5e (N=16M rows, 80% live, 2026-07): per-pass ms
+Not measured on the current chip. The selection in physical/kernels.py
+(K <= 64 XLA fused masked reductions, 64 < K <= 1024 this kernel on
+TPU, else scatter/sort paths) dates from a device set-up that is gone;
+ROADMAP.md Queue A has the re-measurement.
 
-    K          64      128     256     512     1024    2048
-    this       4.8     10.0    17.6    29.3    ~58     ~116
-    XLA fused  3.9     10.4    12.2    16.8    33.5    63.4
-    scatter    149     153     152     153     158     126
-
-XLA's fused multi-reduction ("K-pass" that the compiler collapses to
-one pass) WINS at runtime — but its compile time is the unrolled
-HLO's: 28 s at K=1024, 64 s at K=2048, vs ~1 s flat for this kernel.
-Selection encoded in physical/kernels.py: K <= 64 XLA fused (compile
-stays sub-second), 64 < K <= 1024 this kernel on TPU (avoids both the
-scatter cliff and multi-second compiles), else scatter/sort paths.
-
-Accumulator family (same tiling, same selection table): Sum
-(``pallas_seg_sum``), Count (``maybe_pallas_seg_count`` — the sum
-kernel over the mask with an exact-int epilogue), Min/Max
-(``pallas_seg_minmax`` — sentinel-carried instead of zero-carried, so
-masked-out rows and lane padding cannot win the reduction), and Mean
-(``maybe_pallas_seg_mean`` — sum/count composition, two passes sharing
-the tile layout). Min/Max measure within a few percent of the sum
-kernel at equal K: the inner loop swaps an add for a select-compare,
-both lane-parallel.
+Accumulator family (same tiling): Count (``maybe_pallas_seg_count`` —
+``pallas_seg_sum`` over the mask with an exact-int epilogue) and
+Min/Max (``pallas_seg_minmax`` — sentinel-carried instead of
+zero-carried, so masked-out rows and lane padding cannot win the
+reduction). The engine sends no float *sum* here: float sums must be
+byte-stable across the static and the capacity-compacted layout of the
+same rows, which only the row-ordered scatter-add gives
+(physical/kernels.seg_sum).
 """
 
 from __future__ import annotations
@@ -56,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_tpu import metrics
+
 _BLOCK_ROWS = 64          # (64, 128) tiles: 8k elements per grid step
 _LANES = 128
 _MAX_K = 1024             # (1024, 128) f32 accumulator = 512 KiB VMEM
@@ -63,9 +59,10 @@ _MAX_K = 1024             # (1024, 128) f32 accumulator = 512 KiB VMEM
 
 def pallas_available(dtype, num_segments: int,
                      platform: Optional[str] = None) -> bool:
-    """Whether the Pallas path applies: TPU backend (or forced via
-    SPARK_TPU_PALLAS=force for interpret-mode testing), supported dtype,
-    accumulator-friendly K."""
+    """Whether the Pallas path applies: supported dtype,
+    accumulator-friendly K, and a TPU backend. SPARK_TPU_PALLAS=0 turns
+    the kernels off; SPARK_TPU_PALLAS=interpret (tests only) runs them
+    in interpret mode on whatever backend there is."""
     mode = os.environ.get("SPARK_TPU_PALLAS", "auto")
     if mode == "0":
         return False
@@ -73,14 +70,30 @@ def pallas_available(dtype, num_segments: int,
         return False
     if jnp.dtype(dtype) != jnp.dtype(jnp.float32):
         return False
-    if mode == "force":
+    if mode == "interpret":
         return True
     if platform is None:
-        try:
-            platform = jax.default_backend()
-        except Exception:
-            return False
+        platform = jax.default_backend()
     return platform == "tpu"
+
+
+# The session runs with jax_enable_x64 on, where a Python int traces as
+# int64 — which Mosaic does not lower (RecursionError in its
+# convert_element_type rule for a loop index, "failed to legalize
+# func.return (i32, i64)" for an index map). Every index the kernels
+# handle is therefore typed int32 here, explicitly.
+
+def _tile_index(i):
+    return i, jnp.int32(0)
+
+
+def _acc_index(i):
+    return jnp.int32(0), jnp.int32(0)
+
+
+def _seg_loop(num_segments: int, body) -> None:
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(num_segments), body,
+                      jnp.int32(0))
 
 
 def _kernel(seg_ref, data_ref, mf_ref, acc_ref, *, num_segments: int):
@@ -106,7 +119,7 @@ def _kernel(seg_ref, data_ref, mf_ref, acc_ref, *, num_segments: int):
         acc_ref[pl.ds(k, 1), :] = prev + part
         return carry
 
-    jax.lax.fori_loop(0, num_segments, body, 0)
+    _seg_loop(num_segments, body)
 
 
 @functools.partial(jax.jit,
@@ -137,12 +150,12 @@ def pallas_seg_sum(data: jnp.ndarray, seg: jnp.ndarray,
     m2 = m.reshape(rows, _LANES)
     grid = rows // _BLOCK_ROWS
 
-    spec = pl.BlockSpec((_BLOCK_ROWS, _LANES), lambda i: (i, 0))
+    spec = pl.BlockSpec((_BLOCK_ROWS, _LANES), _tile_index)
     acc = pl.pallas_call(
         functools.partial(_kernel, num_segments=num_segments),
         grid=(grid,),
         in_specs=[spec, spec, spec],
-        out_specs=pl.BlockSpec((num_segments, _LANES), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((num_segments, _LANES), _acc_index),
         out_shape=jax.ShapeDtypeStruct((num_segments, _LANES), f32),
         interpret=interpret,
     )(s2, d2, m2)
@@ -181,7 +194,7 @@ def _minmax_kernel(seg_ref, data_ref, mf_ref, acc_ref, *,
         acc_ref[pl.ds(k, 1), :] = pick(prev, part)
         return carry
 
-    jax.lax.fori_loop(0, num_segments, body, 0)
+    _seg_loop(num_segments, body)
 
 
 @functools.partial(jax.jit,
@@ -211,13 +224,13 @@ def pallas_seg_minmax(data: jnp.ndarray, seg: jnp.ndarray,
     m2 = m.reshape(rows, _LANES)
     grid = rows // _BLOCK_ROWS
 
-    spec = pl.BlockSpec((_BLOCK_ROWS, _LANES), lambda i: (i, 0))
+    spec = pl.BlockSpec((_BLOCK_ROWS, _LANES), _tile_index)
     acc = pl.pallas_call(
         functools.partial(_minmax_kernel, num_segments=num_segments,
                           is_max=is_max),
         grid=(grid,),
         in_specs=[spec, spec, spec],
-        out_specs=pl.BlockSpec((num_segments, _LANES), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((num_segments, _LANES), _acc_index),
         out_shape=jax.ShapeDtypeStruct((num_segments, _LANES), f32),
         interpret=interpret,
     )(s2, d2, m2)
@@ -226,73 +239,56 @@ def pallas_seg_minmax(data: jnp.ndarray, seg: jnp.ndarray,
 
 
 # engine-side selection bound: below this the XLA fused multi-reduce
-# compiles fast and runs faster (see measurement table above)
+# serves the aggregate (physical/kernels._MASKED_SEG_LIMIT)
 MIN_ENGINE_K = 64
 
 
-def maybe_pallas_seg_sum(data, seg, mask, num_segments: int):
-    """Engine entry point for float32 grouped sums: the Pallas path when
-    it qualifies, else None (caller falls back to the XLA kernels)."""
-    if num_segments <= MIN_ENGINE_K or \
-            not pallas_available(data.dtype, num_segments):
+def _engine_mode(dtype, num_segments: int, rows: int) -> Optional[bool]:
+    """None when the engine should keep its XLA path, else the
+    ``interpret`` flag to run the kernel with: False (compiled — the
+    only answer outside tests) unless SPARK_TPU_PALLAS=interpret."""
+    if num_segments <= MIN_ENGINE_K or rows >= (1 << 31) or \
+            not pallas_available(dtype, num_segments):
         return None
-    interpret = jax.default_backend() != "tpu"
-    return pallas_seg_sum(data, seg, mask, num_segments,
-                          interpret=interpret)
+    return os.environ.get("SPARK_TPU_PALLAS") == "interpret"
+
+
+def _note(op: str, num_segments: int, rows: int, interpret: bool) -> None:
+    # trace-time event: says which path a query's program was built from
+    metrics.record("pallas", op=op, k=int(num_segments), rows=int(rows),
+                   interpret=interpret)
 
 
 def maybe_pallas_seg_count(seg, mask, num_segments: int):
-    """Engine entry point for grouped counts (exact int64 result).
-    Per-(group, lane) f32 accumulators stay exact below 2^24 increments,
-    i.e. up to 2^31 rows — beyond any single static batch."""
-    if num_segments <= MIN_ENGINE_K or \
-            not pallas_available(np.float32, num_segments):
+    """Engine entry point for grouped counts (exact int64 result), or
+    None (caller keeps the XLA kernels). Per-(group, lane) f32
+    accumulators stay exact below 2^24 increments, i.e. up to 2^31 rows
+    — beyond any single static batch."""
+    interpret = _engine_mode(np.float32, num_segments, seg.shape[0])
+    if interpret is None:
         return None
-    if seg.shape[0] >= (1 << 31):
+    _note("count", num_segments, seg.shape[0], interpret)
+    return pallas_seg_sum(mask.astype(jnp.float32), seg, mask,
+                          num_segments, interpret=interpret,
+                          exact_int=True)
+
+
+def _maybe_minmax(data, seg, mask, num_segments: int, is_max: bool):
+    interpret = _engine_mode(data.dtype, num_segments, seg.shape[0])
+    if interpret is None:
         return None
-    interpret = jax.default_backend() != "tpu"
-    ones = mask.astype(jnp.float32)
-    return pallas_seg_sum(ones, seg, mask, num_segments,
-                          interpret=interpret, exact_int=True)
+    _note("max" if is_max else "min", num_segments, seg.shape[0], interpret)
+    return pallas_seg_minmax(data, seg, mask, num_segments,
+                             is_max=is_max, interpret=interpret)
 
 
 def maybe_pallas_seg_min(data, seg, mask, num_segments: int):
     """Engine entry point for float32 grouped min: Pallas when it
     qualifies, else None. Empty groups come back +inf, matching the
     XLA sentinel convention in physical/kernels.seg_min."""
-    if num_segments <= MIN_ENGINE_K or \
-            not pallas_available(data.dtype, num_segments):
-        return None
-    interpret = jax.default_backend() != "tpu"
-    return pallas_seg_minmax(data, seg, mask, num_segments,
-                             is_max=False, interpret=interpret)
+    return _maybe_minmax(data, seg, mask, num_segments, False)
 
 
 def maybe_pallas_seg_max(data, seg, mask, num_segments: int):
     """Engine entry point for float32 grouped max (empty groups -inf)."""
-    if num_segments <= MIN_ENGINE_K or \
-            not pallas_available(data.dtype, num_segments):
-        return None
-    interpret = jax.default_backend() != "tpu"
-    return pallas_seg_minmax(data, seg, mask, num_segments,
-                             is_max=True, interpret=interpret)
-
-
-def maybe_pallas_seg_mean(data, seg, mask, num_segments: int):
-    """Engine entry point for float32 grouped mean: sum and count from
-    the same tiled kernels (two passes), divided outside. Empty groups
-    yield NaN (0/0 guarded to 0-count -> NaN via where), which callers
-    mask with their own validity. None when the path doesn't qualify."""
-    if num_segments <= MIN_ENGINE_K or \
-            not pallas_available(data.dtype, num_segments):
-        return None
-    if seg.shape[0] >= (1 << 31):
-        return None
-    interpret = jax.default_backend() != "tpu"
-    s = pallas_seg_sum(data, seg, mask, num_segments,
-                       interpret=interpret)
-    c = pallas_seg_sum(mask.astype(jnp.float32), seg, mask,
-                       num_segments, interpret=interpret,
-                       exact_int=True)
-    return jnp.where(c > 0, s / jnp.maximum(c, 1).astype(jnp.float32),
-                     jnp.float32(jnp.nan))
+    return _maybe_minmax(data, seg, mask, num_segments, True)

@@ -35,11 +35,7 @@ def axis_index() -> jnp.ndarray:
 
 
 def axis_size() -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(DATA_AXIS)
-    # jax < 0.6: psum of a Python literal is evaluated statically under
-    # shard_map, so this is still a concrete int
-    return jax.lax.psum(1, DATA_AXIS)
+    return jax.lax.axis_size(DATA_AXIS)
 
 
 # ---- row routing ------------------------------------------------------------

@@ -1322,16 +1322,9 @@ class MeshExecutor:
                 schema_box["schema"] = batch.schema
                 return batch.data
 
-            if hasattr(jax, "shard_map"):
-                smapped = jax.shard_map(local_fn, mesh=self.mesh,
-                                        in_specs=_SPEC, out_specs=_SPEC,
-                                        check_vma=False)
-            else:  # jax < 0.6: experimental API, check_rep not check_vma
-                from jax.experimental.shard_map import shard_map
-
-                smapped = shard_map(local_fn, mesh=self.mesh,
+            smapped = jax.shard_map(local_fn, mesh=self.mesh,
                                     in_specs=_SPEC, out_specs=_SPEC,
-                                    check_rep=False)
+                                    check_vma=False)
             # cross-session executable store integration (no-op jit
             # when the compile service is off). A plan holding fused
             # spans keys under its own tier with the bucket-ladder
@@ -1349,7 +1342,8 @@ class MeshExecutor:
             entry = (build_stage_callable(
                 tier, plan, smapped,
                 tuple(s.sharded.data for s in scans), schema_box,
-                mesh_size=self.d, platform=key[2], extra=extra),
+                mesh_size=self.d, platform=key[2], extra=extra,
+                devices=tuple(self.mesh.devices.flat)),
                 schema_box)
             _DIST_STAGE_CACHE[key] = entry
         jitted, schema_box = entry

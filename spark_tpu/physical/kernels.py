@@ -228,14 +228,6 @@ def seg_sum(data, seg, mask, num_segments: int, sorted_seg: bool = False):
         return jax.ops.segment_sum(masked, seg, num_segments=num_segments)
     if num_segments <= _MASKED_SEG_LIMIT:
         return _masked_reduce(data, seg, mask, num_segments, jnp.sum, zero)
-    if not sorted_seg:
-        # 64 < K <= 1024, f32, TPU: one-pass Pallas streaming aggregate
-        # (measured 2.5-15x over scatter; see ops/pallas_agg.py table)
-        from spark_tpu.ops import maybe_pallas_seg_sum
-
-        out = maybe_pallas_seg_sum(data, seg, mask, num_segments)
-        if out is not None:
-            return out
     if sorted_seg:
         return _sorted_seg_sum(masked, seg, num_segments)
     return jax.ops.segment_sum(masked, seg, num_segments=num_segments)
@@ -267,8 +259,8 @@ def seg_min(data, seg, mask, num_segments: int, sorted_seg: bool = False):
     if num_segments <= _MASKED_SEG_LIMIT:
         return _masked_reduce(data, seg, mask, num_segments, jnp.min, big)
     if not sorted_seg:
-        # same measured selection table as seg_sum: 64 < K <= 1024 f32
-        # goes through the one-pass Pallas streaming reduction
+        # 64 < K <= 1024, f32, TPU: the one-pass Pallas streaming
+        # reduction (ops/pallas_agg.py)
         from spark_tpu.ops import maybe_pallas_seg_min
 
         out = maybe_pallas_seg_min(data, seg, mask, num_segments)

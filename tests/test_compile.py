@@ -136,6 +136,107 @@ def test_cross_session_cache_hit(fact_parquet, tmp_path):
     assert rows2 == rows1
 
 
+@pytest.mark.timeout(300)
+def test_cross_session_cache_hit_mesh8(fact_parquet, tmp_path):
+    """The same over mesh[8]: a stored distributed stage is loaded onto
+    the mesh's eight devices (the executor hands them to the store)."""
+    store_dir = str(tmp_path / "store")
+    conf = {"spark.tpu.compile.store.dir": store_dir}
+    _forget_process_state()
+    metrics.reset_exec_store()
+    with _session("mesh[8]", **conf) as s1:
+        rows1 = _run_twice(s1, fact_parquet)
+        assert metrics.exec_store_stats()["puts"] >= 1
+
+    _forget_process_state()
+    metrics.reset_exec_store()
+    with _session("mesh[8]", **conf) as s2:
+        rows2 = _run_twice(s2, fact_parquet)
+        st2 = metrics.exec_store_stats()
+        assert st2["hits"] >= 1, f"no store hit over mesh[8]: {st2}"
+        assert st2["corrupt"] == 0
+    assert rows2 == rows1
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("n_devices", [1, 8])
+def test_load_runs_on_the_devices_it_is_given(tmp_path, n_devices):
+    """jax's deserialize_and_load defaults to EVERY device of the
+    backend, which turns a one-device program into an 8-shard one as
+    soon as 8 devices are visible. The store loads a single-device
+    executable onto the default device and a mesh stage onto the
+    devices its caller names."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from spark_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+    assert len(jax.devices()) == 8
+    store = ExecutableStore(str(tmp_path / "store"), max_bytes=1 << 30)
+    host = np.arange(64, dtype=np.int64)
+    if n_devices == 1:
+        fn, devices = (lambda a: a[0] * 2), None
+        args = (jax.device_put(host, jax.devices()[0]),)
+    else:
+        mesh = make_mesh(n_devices)
+        spec = PartitionSpec(DATA_AXIS)
+        fn = jax.shard_map(
+            lambda a: a[0] * 2 + jax.lax.axis_index(DATA_AXIS),
+            mesh=mesh, in_specs=((spec,),), out_specs=spec,
+            check_vma=False)
+        args = (jax.device_put(host, NamedSharding(mesh, spec)),)
+        devices = tuple(mesh.devices.flat)
+    jitted = jax.jit(fn)
+    digest = f"{n_devices:032d}"
+    assert store.put(digest, jitted.lower(args).compile(), None, args)
+
+    clear_process_cache()  # force the disk deserialize path
+    entry = store.load(digest, args, devices)
+    assert entry is not None, "stored executable did not load"
+    out = entry["compiled"](args)
+    assert len(out.sharding.device_set) == n_devices
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(jitted(args)))
+
+
+def _fresh_session_cache_dir(monkeypatch, **env):
+    """jax's cache-dir config after a new session, started from a
+    config that names no directory, under the given environment."""
+    from spark_tpu.api import session as S
+
+    for key in ("JAX_COMPILATION_CACHE_DIR", "SPARK_TPU_JAX_CACHE"):
+        monkeypatch.delenv(key, raising=False)
+    for key, value in env.items():
+        monkeypatch.setenv(key, value)
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    # what jax itself does with the variable at import
+    jax.config.update("jax_compilation_cache_dir",
+                      env.get("JAX_COMPILATION_CACHE_DIR"))
+    try:
+        S._enable_compilation_cache()
+        return jax.config.jax_compilation_cache_dir
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+
+def test_compile_cache_dir_rule(monkeypatch, tmp_path):
+    """One function places the compile cache: JAX_COMPILATION_CACHE_DIR
+    wins and nothing else is set; unset, a fixed directory inside the
+    checkout; SPARK_TPU_JAX_CACHE=0 leaves jax untouched."""
+    from spark_tpu.api.session import DEFAULT_JAX_CACHE_DIR
+
+    placed = str(tmp_path / "placed")
+    assert _fresh_session_cache_dir(
+        monkeypatch, JAX_COMPILATION_CACHE_DIR=placed) == placed
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_JAX_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert _fresh_session_cache_dir(monkeypatch) == DEFAULT_JAX_CACHE_DIR
+    assert _fresh_session_cache_dir(
+        monkeypatch, SPARK_TPU_JAX_CACHE="0") is None
+
+
 @pytest.mark.timeout(120)
 def test_store_disabled_is_legacy(fact_parquet):
     """No compile conf at all → no service, no store traffic, plain

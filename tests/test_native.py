@@ -149,3 +149,25 @@ def test_native_speedup_smoke():
     # 2x slack + a 5ms absolute floor: when both medians sit inside
     # timer/scheduler noise there is no speedup signal to assert on
     assert t_cc < max(t_py * 2, t_py + 0.005), (t_cc, t_py)
+
+
+def test_concurrent_builds_leave_a_whole_library():
+    """Several processes may build at once: on a fresh checkout every
+    xdist worker imports this file, and each import builds. With one
+    shared temp name a finished build's rename took the file a slower
+    one was still about to rename — FileNotFoundError at collection,
+    which makes xdist run no test at all. Each build now has a temp
+    name of its own, and whoever renames last leaves a whole file."""
+    import ctypes
+    import subprocess
+    import sys
+
+    code = ("import sys; from spark_tpu import native; "
+            "sys.exit(0 if native._build() else 1)")
+    procs = [subprocess.Popen([sys.executable, "-c", code])
+             for _ in range(4)]
+    assert [p.wait(timeout=120) for p in procs] == [0] * 4
+    assert ctypes.CDLL(native._SO).like_table is not None
+    leftovers = [f for f in __import__("os").listdir(native._DIR)
+                 if f.endswith(".tmp")]
+    assert not leftovers, leftovers

@@ -46,13 +46,21 @@ def test_availability_gate():
     assert not pallas_available(np.float32, 16, platform="cpu")
 
 
+def _pallas_events():
+    from spark_tpu import metrics
+
+    return [e for e in metrics.recent(4096) if e["kind"] == "pallas"]
+
+
 def test_engine_seg_kernels_take_pallas_path(rng, monkeypatch):
-    """seg_sum/seg_count route 64 < K <= 1024 unsorted f32 aggregations
-    through the Pallas kernel (SPARK_TPU_PALLAS=force -> interpret on
-    CPU) and agree with the scatter path."""
+    """seg_count/seg_min/seg_max route 64 < K <= 1024 unsorted
+    aggregations through the Pallas kernel (SPARK_TPU_PALLAS=interpret:
+    the explicit test switch) and agree with the scatter path; float
+    seg_sum keeps the row-ordered scatter-add either way."""
     import jax.numpy as jnp
 
-    from spark_tpu.physical.kernels import seg_count, seg_sum
+    from spark_tpu.physical.kernels import (seg_count, seg_max, seg_min,
+                                            seg_sum)
 
     n, k = 6000, 100
     data = jnp.asarray(rng.normal(size=n).astype(np.float32))
@@ -61,9 +69,17 @@ def test_engine_seg_kernels_take_pallas_path(rng, monkeypatch):
 
     base_sum = np.asarray(seg_sum(data, seg, mask, k))
     base_cnt = np.asarray(seg_count(seg, mask, k))
-    monkeypatch.setenv("SPARK_TPU_PALLAS", "force")
+    base_min = np.asarray(seg_min(data, seg, mask, k))
+    base_max = np.asarray(seg_max(data, seg, mask, k))
+    assert not _pallas_events(), "Pallas ran without being asked to"
+    monkeypatch.setenv("SPARK_TPU_PALLAS", "interpret")
     got_sum = np.asarray(seg_sum(data, seg, mask, k))
     got_cnt = np.asarray(seg_count(seg, mask, k))
+    np.testing.assert_array_equal(seg_min(data, seg, mask, k), base_min)
+    np.testing.assert_array_equal(seg_max(data, seg, mask, k), base_max)
+    ran = _pallas_events()
+    assert sorted(e["op"] for e in ran) == ["count", "max", "min"]
+    assert all(e["interpret"] for e in ran)
     np.testing.assert_allclose(got_sum, base_sum, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(got_cnt, base_cnt)
     assert got_cnt.dtype == np.int64

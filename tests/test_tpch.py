@@ -125,3 +125,30 @@ def test_query_parity_parquet_scan(tpch, tmp_path, qnum):
         assert_rows_match(got, want, label=f"q{qnum}[parquet]")
     finally:
         register_views(spark, tables)  # restore in-memory views
+
+
+@pytest.fixture(scope="module")
+def smoke_reference(tmp_path_factory):
+    """(sqlite connection, parquet directory) of one SF0.01 dataset."""
+    from spark_tpu.tpch.gen import write_parquet
+
+    tables = generate_tables(0.01, seed=7)
+    path = str(tmp_path_factory.mktemp("smoke_ref") / "tpch")
+    write_parquet(tables, path)
+    return load_sqlite(tables), path
+
+
+@pytest.mark.parametrize("qnum", [1, 3, 5, 6])
+def test_chip_smoke_reference_matches_oracle(smoke_reference, qnum):
+    """chip_smoke.py checks the chip's SF1 answers against a pandas
+    recompute (the sqlite oracle cannot load SF1 inside a smoke run's
+    time); here that recompute is itself held to the oracle, at SF0.01
+    over the same parquet layout. chip_smoke is a plain module until
+    its main() runs: importing it touches neither jax nor the device."""
+    import chip_smoke
+
+    conn, path = smoke_reference
+    got = chip_smoke.REFERENCE[qnum](path)
+    want = run_oracle(conn, QUERIES[qnum])
+    assert want, f"q{qnum}: oracle returned no rows"
+    assert_rows_match(got, want, label=f"q{qnum}[pandas reference]")

@@ -1,0 +1,105 @@
+"""What the chip's compiler says, asked without the chip: the Pallas
+kernels at the engine's real widths and the flagship fused stage are
+compiled for a *described* TPU v5e (``jax.experimental.topologies``),
+with ``jax_enable_x64`` on, as the session runs them.
+
+Interpret mode (tests/test_pallas_ops.py) checks results and sees none
+of what Mosaic refuses — int64 loop indices and index maps did not lower
+under x64 until the kernels typed them int32. Nothing runs here, so
+nothing is said about results or times.
+
+This is the only test file that describes the chip. The topology is
+described inside a module-scoped fixture and never at import: only one
+process may load the TPU's library, and every xdist worker imports every
+test file.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+ROWS = 4 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_the_session_runs():
+    """x64 on; persistent compile cache off (a compile for a described
+    chip is written to it but can never be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    assert jax.config.jax_enable_x64
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _columns(one_chip):
+    """(data f32, seg int64 — the engine's group ids under x64, mask)."""
+    return (jax.ShapeDtypeStruct((ROWS,), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((ROWS,), jnp.int64, sharding=one_chip),
+            jax.ShapeDtypeStruct((ROWS,), jnp.bool_, sharding=one_chip))
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    return compiled, compiled.as_text()
+
+
+@pytest.mark.parametrize("k", [128, 1024])
+@pytest.mark.parametrize("op", ["sum", "count", "min", "max"])
+def test_pallas_kernel_compiles_for_v5e(one_chip, as_the_session_runs,
+                                        op, k):
+    from spark_tpu.ops import pallas_seg_minmax, pallas_seg_sum
+
+    fns = {
+        "sum": lambda d, s, m: pallas_seg_sum(d, s, m, k),
+        "count": lambda d, s, m: pallas_seg_sum(
+            m.astype(jnp.float32), s, m, k, exact_int=True),
+        "min": lambda d, s, m: pallas_seg_minmax(d, s, m, k, is_max=False),
+        "max": lambda d, s, m: pallas_seg_minmax(d, s, m, k, is_max=True),
+    }
+    compiled, text = _compile(fns[op], *_columns(one_chip))
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == (k,)
+    assert out.dtype == (jnp.int64 if op == "count" else jnp.float32)
+
+
+def test_flagship_fused_stage_compiles_for_v5e(one_chip,
+                                               as_the_session_runs):
+    """__graft_entry__.entry(): scan -> filter -> project -> grouped
+    aggregate -> sort as ONE program, with int64 / float64 / dictionary
+    columns — the XLA path the engine takes for everything else."""
+    from __graft_entry__ import entry
+
+    fn, args = entry()
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    compiled, text = _compile(fn, *shapes)
+    assert "sort" in text
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
