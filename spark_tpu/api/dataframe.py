@@ -11,7 +11,9 @@ from __future__ import annotations
 
 from typing import Any, List, Optional, Sequence, Tuple, Union
 
+from spark_tpu import trace
 from spark_tpu.api.row import Row
+from spark_tpu.columnar.batch import Batch
 from spark_tpu.expr import expressions as E
 from spark_tpu.plan import logical as L
 from spark_tpu.types import Schema
@@ -288,8 +290,13 @@ class DataFrame:
 
     # ---- actions -----------------------------------------------------------
 
-    def _execute(self):
-        from spark_tpu import deadline, recovery, trace
+    def _execute(self, materialize=None):
+        """Run the plan to a device Batch; with ``materialize`` (a
+        callable of the Batch: rows, pandas, arrow) return what it
+        makes of the batch instead, from inside the root span — the
+        fetch is the one place the host waits for the device, so
+        ``query.execute`` covers the query and not only its enqueue."""
+        from spark_tpu import deadline, recovery
 
         # root span when standalone; child when a connect server /
         # scheduler ticket already carries a trace for this query.
@@ -300,14 +307,17 @@ class DataFrame:
         # retry budget is bound so every retry seam below draws from
         # ONE pool instead of multiplying per-layer caps
         conf = self._session.conf if self._session is not None else None
-        with deadline.bind_default(conf), \
-                recovery.bind_default_budget(conf), \
-                trace.span("query.execute",
-                           plan=type(self._plan).__name__):
-            return self._execute_traced()
+        # the span is outermost: what the wrappers cost is the root's
+        # own time
+        with trace.span("query.execute",
+                        plan=type(self._plan).__name__), \
+                deadline.bind_default(conf), \
+                recovery.bind_default_budget(conf):
+            batch = self._execute_traced()
+            return batch if materialize is None else materialize(batch)
 
     def _execute_traced(self):
-        from spark_tpu import metrics, trace
+        from spark_tpu import metrics
 
         if self._session is not None:
             self._session._ensure_active()
@@ -341,7 +351,8 @@ class DataFrame:
             from spark_tpu.plan.optimizer import optimize as opt
             from spark_tpu.recovery import run_plan_with_oom_degradation
 
-            lp = opt(plan)
+            with trace.span("query.optimize"):
+                lp = opt(plan)
             svc = self._session.compile_service
             if svc is not None:
                 # compile-service routing: with background compile on,
@@ -402,8 +413,7 @@ class DataFrame:
             pass  # observability must never fail the query
 
     def collect(self) -> List[Row]:
-        batch = self._execute()
-        return [Row.from_dict(d) for d in batch.to_pylist()]
+        return self._execute(_collect_rows)
 
     @property
     def isStreaming(self) -> bool:
@@ -423,7 +433,7 @@ class DataFrame:
         return with_watermark(self, col_name, delay)
 
     def toPandas(self):
-        return self._execute().to_pandas()
+        return self._execute(Batch.to_pandas)
 
     @property
     def na(self):
@@ -483,12 +493,12 @@ class DataFrame:
     def toArrow(self):
         from spark_tpu.columnar.arrow import to_arrow
 
-        return to_arrow(self._execute())
+        return self._execute(to_arrow)
 
     def count(self) -> int:
         agg = L.Aggregate((), (E.Alias(E.Count(None), "count"),), self._plan)
-        batch = self._with(agg)._execute()
-        return int(batch.to_pylist()[0]["count"])
+        rows = self._with(agg)._execute(Batch.to_pylist)
+        return int(rows[0]["count"])
 
     def first(self) -> Optional[Row]:
         rows = self.limit(1).collect()
@@ -561,6 +571,14 @@ class DataFrame:
         if eager:
             df.count()
         return df
+
+
+def _collect_rows(batch: Batch) -> List[Row]:
+    """``collect()``'s host materialisation: one ``query.rows`` span
+    from the fetched planes to the Rows."""
+    fetched = batch.fetch_host()
+    with trace.span("query.rows"):
+        return [Row.from_dict(d) for d in batch.rows_from_host(*fetched)]
 
 
 def _fmt(v, truncate: bool) -> str:

@@ -16,6 +16,7 @@ import numpy as np
 import jax
 
 from spark_tpu import locks
+from spark_tpu import trace
 from spark_tpu import types as T
 from spark_tpu.api.dataframe import DataFrame
 from spark_tpu.conf import RuntimeConf
@@ -503,7 +504,8 @@ class SparkSession:
 
         self._ensure_active()
         # injected parser hooks first (injectParser:318 analogue)
-        plan = self.extensions.parse(query, self.catalog, parse_sql)
+        with trace.span("query.parse"):
+            plan = self.extensions.parse(query, self.catalog, parse_sql)
         df = DataFrame(self, plan)
         # carried for the compile service's served-plan history: SQL
         # text is the cross-process-replayable identity of this plan

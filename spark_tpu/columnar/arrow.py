@@ -20,6 +20,7 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
+from spark_tpu import trace
 from spark_tpu import types as T
 from spark_tpu.columnar.batch import Batch, from_numpy
 from spark_tpu.types import Field, Schema
@@ -325,6 +326,11 @@ def to_arrow(batch: Batch) -> pa.Table:
     columns rebuild arrow lists from the padded 2D layout + '#len'
     companion (which is dropped from the output)."""
     mask, host_cols = batch.fetch_host()
+    with trace.span("query.rows"):
+        return _table_from_host(batch, mask, host_cols)
+
+
+def _table_from_host(batch: Batch, mask, host_cols) -> pa.Table:
     columns = []
     names = []
     by_name = {f.name: hc for f, hc in zip(batch.schema.fields,

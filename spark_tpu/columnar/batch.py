@@ -28,6 +28,7 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from spark_tpu import trace
 from spark_tpu.types import Field, Schema
 
 # jitted column-packers for single-transfer host fetches, keyed on
@@ -140,7 +141,18 @@ class Batch:
         capacity) planes, each fetched with a single transfer, then
         host-side views restore the dtypes. (Not measured on a directly
         attached chip; whether two planes beat per-array fetches there
-        is an open question, ROADMAP.md Queue A.)"""
+        is an open question, ROADMAP.md Queue A.)
+
+        Spans: ``query.fetch`` is the whole of it (its self time: the
+        packer's dispatch and the host-side views); ``device.wait`` is
+        the host blocked until everything enqueued before it is done
+        (the copies are already in flight, as they were when
+        ``np.asarray`` did both), and ``fetch.copy`` what is left of
+        the copies once the sources are ready."""
+        with trace.span("query.fetch"):
+            return self._fetch_host()
+
+    def _fetch_host(self):
         import jax
 
         cols = self.data.columns
@@ -175,21 +187,32 @@ class Batch:
             packer = jax.jit(pack)
             _PACKER_CACHE[sig] = packer
         iplane, fplane = packer(tuple(int_arrays), tuple(flt_arrays))
-        if fplane.size:
-            # fetch the two planes CONCURRENTLY: device_get walks the
-            # tree serially and each blocking transfer pays a full
-            # round trip, so two overlapped fetches cost ~one
-            fut = _FETCH_POOL.submit(np.asarray, fplane)
-            ih = np.asarray(iplane)
-            fh = fut.result()
-        else:
-            # all-integer batch (e.g. decimal money results): do NOT
-            # fetch the empty float plane — even a zero-size device_get
-            # pays a round trip
-            ih = np.asarray(iplane)
-            fh = np.zeros((0, 0), dtype=np.float64)
+        # start the copies behind the programs that make their sources,
+        # then wait: a wait with no copy in flight costs this chip a round
+        # trip of its own (0.12 ms on the v5e, PERF.md) before the copy's
+        pending = [iplane] + ([fplane] if fplane.size else []) \
+            + extra_arrays
+        for x in pending:
+            if hasattr(x, "copy_to_host_async"):  # a 2D column may be numpy
+                x.copy_to_host_async()
+        with trace.span("device.wait"):
+            jax.block_until_ready(pending)
+        with trace.span("fetch.copy"):
+            if fplane.size:
+                # fetch the two planes CONCURRENTLY: device_get walks
+                # the tree serially and each blocking transfer pays a
+                # full round trip, so two overlapped fetches cost ~one
+                fut = _FETCH_POOL.submit(np.asarray, fplane)
+                ih = np.asarray(iplane)
+                fh = fut.result()
+            else:
+                # all-integer batch (e.g. decimal money results): do
+                # NOT fetch the empty float plane — even a zero-size
+                # device_get pays a round trip
+                ih = np.asarray(iplane)
+                fh = np.zeros((0, 0), dtype=np.float64)
 
-        xh = [np.asarray(a) for a in extra_arrays]  # one RTT each
+            xh = [np.asarray(a) for a in extra_arrays]  # one RTT each
 
         def restore(plane, slot, dt):
             if plane == "x":
@@ -215,13 +238,19 @@ class Batch:
     def to_pylist(self) -> list:
         """Materialize live rows as a list of dicts (decoding string
         dictionaries and dates). For tests and `.collect()`."""
+        fetched = self.fetch_host()
+        with trace.span("query.rows"):
+            return self.rows_from_host(*fetched)
+
+    def rows_from_host(self, mask, host_cols) -> list:
+        """``fetch_host()``'s planes -> a list of dicts, one per live
+        row (``to_pylist`` without the fetch)."""
         import datetime
 
         from spark_tpu.types import (ArrayType, DateType, DecimalType,
                                      StringType, TimestampType,
                                      array_len_col)
 
-        mask, host_cols = self.fetch_host()
         out_rows: list = []
         cols = []
         by_name = {f.name: hc for f, hc in zip(self.schema.fields,

@@ -348,6 +348,9 @@ class FileSource:
             filter=_filters_to_pads(filters, self._dtypes()))
         t1 = _time.perf_counter()
         batch = from_arrow(table)  # dict-encode + host->device transfer
+        # wait for the transfer, or transfer_ms is an enqueue time (once
+        # per scan: the batch is cached below)
+        batch.block_until_ready()
         t2 = _time.perf_counter()
         metrics.record("scan", fmt=self.fmt, rows=table.num_rows,
                        decode_ms=round((t1 - t0) * 1e3, 2),

@@ -252,12 +252,18 @@ class _NamedLockBase:
             _note_released(self.name, id(self._inner))
         self._inner.release()
 
+    # acquire()/release() inlined: every ring append and counter bump
+    # in the program enters one of these
     def __enter__(self):
-        self.acquire()
+        self._inner.acquire()
+        if _VALIDATE:
+            _note_acquired(self.name, id(self._inner))
         return self
 
     def __exit__(self, *exc) -> None:
-        self.release()
+        if _VALIDATE:
+            _note_released(self.name, id(self._inner))
+        self._inner.release()
 
     def locked(self) -> bool:
         return self._inner.locked()

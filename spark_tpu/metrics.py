@@ -31,6 +31,7 @@ import atexit
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -75,39 +76,52 @@ _LOG_FLUSH_EVENTS = 128
 _LOG_FLUSH_SECONDS = 0.5
 
 
-def _log_path() -> Optional[str]:
-    from spark_tpu.api.session import SparkSession
+_SESSION_MODULE = "spark_tpu.api.session"
 
-    sess = SparkSession._active
+
+def _log_path() -> Optional[str]:
+    """The JSONL path ``spark.eventLog.dir`` names now, or None. Runs
+    for every event, so where no log is set it costs dict lookups only:
+    no import statement (a session exists only once its module is
+    loaded), and the conf is read each time, so a change of the
+    directory is seen by the next event."""
+    module = sys.modules.get(_SESSION_MODULE)
+    sess = module.SparkSession._active if module is not None else None
     if sess is None:
         return None
-    try:
-        d = sess.conf.get("spark.eventLog.dir")
-    except KeyError:
-        return None
+    d = sess.conf.get("spark.eventLog.dir")
     if not d:
         return None
-    # resolve + mkdir once per configured directory (under the IO lock:
-    # concurrent queries must not race the mkdir/cache fill)
-    with _IO_LOCK:
-        if d not in _PATH_CACHE:
-            os.makedirs(d, exist_ok=True)
-            _PATH_CACHE[d] = os.path.join(d, "events.jsonl")
-        return _PATH_CACHE[d]
+    path = _PATH_CACHE.get(d)
+    if path is None:
+        # resolve + mkdir once per configured directory (under the IO
+        # lock: concurrent queries must not race the mkdir/cache fill)
+        with _IO_LOCK:
+            if d not in _PATH_CACHE:
+                os.makedirs(d, exist_ok=True)
+                _PATH_CACHE[d] = os.path.join(d, "events.jsonl")
+            path = _PATH_CACHE[d]
+    return path
 
 
 def record(kind: str, **fields: Any) -> None:
-    global _counter
     ev = {"ts": round(time.time(), 4), "kind": kind}
     ev.update(fields)
     ctx = _TRACE_CTX.get()
     if ctx is not None:
-        # stamp the enclosing span's identity; explicit fields (the
-        # span event records its own triple) win
+        # stamp the enclosing span's identity; explicit fields win
         ev.setdefault("trace_id", ctx[0])
         ev.setdefault("span_id", ctx[1])
         if ctx[2] is not None:
             ev.setdefault("parent_id", ctx[2])
+    append(ev)
+
+
+def append(ev: Dict[str, Any]) -> None:
+    """Number a finished event and put it into the ring (and the JSONL
+    buffer when a log is set). ``record()`` for everything but spans:
+    a span builds its own event, ids included (trace.span)."""
+    global _counter
     path = _log_path()
     with _LOCK:
         ev["n"] = _counter

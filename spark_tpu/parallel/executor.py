@@ -359,8 +359,15 @@ class MeshExecutor:
                         optimize: bool = True) -> Batch:
         from spark_tpu.plan.optimizer import optimize as opt
 
-        lp = opt(plan) if optimize else plan
-        return self.run(self.plan(lp)).to_batch()
+        if optimize:
+            with _trace.span("query.optimize"):
+                plan = opt(plan)
+        with _trace.span("query.plan"):
+            physical = self.plan(plan)
+        sharded = self.run(physical)
+        with _trace.span("fetch.copy", op="gather"):
+            # the shards come to the host and go back as one batch
+            return sharded.to_batch()
 
     # ---- logical -> distributed physical -----------------------------------
 
@@ -1354,7 +1361,8 @@ class MeshExecutor:
             # trace pays the forced sync (results are identical either
             # way — the host reads the same buffers right after)
             with _trace.span("stage.device", op=type(plan).__name__):
-                data = jitted(tuple(s.sharded.data for s in scans))
+                with _trace.span("stage.dispatch"):
+                    data = jitted(tuple(s.sharded.data for s in scans))
                 data = jax.block_until_ready(data)
         else:
             data = jitted(tuple(s.sharded.data for s in scans))

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from spark_tpu import conf as CF
+from spark_tpu import trace
 from spark_tpu.columnar.batch import Batch
 from spark_tpu.expr import expressions as E
 from spark_tpu.physical import kernels as K
@@ -261,11 +262,15 @@ def _run_fused(plan: P.PhysicalPlan) -> Batch:
         from spark_tpu import metrics
 
         t0 = time.perf_counter()
-        data = jitted(tuple(s.batch.data for s in scans))
+        with trace.span("stage.dispatch", fresh=True):
+            data = jitted(tuple(s.batch.data for s in scans))
         metrics.record("stage_compile", node=plan.node_string(),
                        ms=round((time.perf_counter() - t0) * 1e3, 2))
     else:
-        data = jitted(tuple(s.batch.data for s in scans))
+        # the enqueue alone: nothing waits for the device here (the
+        # wait is measured where the host blocks anyway, in fetch_host)
+        with trace.span("stage.dispatch"):
+            data = jitted(tuple(s.batch.data for s in scans))
     return Batch(schema_box["schema"], data)
 
 
@@ -358,12 +363,19 @@ def _replay_compactions(plan: P.PhysicalPlan) -> P.PhysicalPlan:
 _OUTPUT_STATS = P._AdaptiveStatsCache()
 
 
-def execute(plan: P.PhysicalPlan) -> Batch:
-    """Run a physical plan: fuse what we can, block where we must."""
+def _bind(plan: P.PhysicalPlan):
+    """The last of planning: replay the recorded compactions, attach
+    the recorded runtime stats, look the output capacity up. Returns
+    (plan, its stats key, the recorded output capacity or None)."""
     plan = _replay_compactions(plan)
     _bind_adaptive(plan)
     sk = plan.stats_key()
-    cap = _OUTPUT_STATS.get(sk)
+    return plan, sk, _OUTPUT_STATS.get(sk)
+
+
+def _run_bound(plan: P.PhysicalPlan, sk, cap) -> Batch:
+    """Run a bound physical plan: fuse what we can, block where we
+    must."""
     if cap is not None:
         return _execute(P.CompactExec(plan, cap))
     batch = _execute(plan)
@@ -374,7 +386,7 @@ def execute(plan: P.PhysicalPlan) -> Batch:
 
 
 def _execute(plan: P.PhysicalPlan) -> Batch:
-    from spark_tpu import metrics, trace
+    from spark_tpu import metrics
 
     if isinstance(plan, P.BatchScanExec):
         return plan.batch
@@ -396,5 +408,11 @@ def _execute(plan: P.PhysicalPlan) -> Batch:
 def execute_logical(plan: L.LogicalPlan, optimize: bool = True) -> Batch:
     from spark_tpu.plan.optimizer import optimize as opt
 
-    lp = opt(plan) if optimize else plan
-    return execute(plan_physical(lp))
+    if optimize:
+        with trace.span("query.optimize"):
+            plan = opt(plan)
+    # one span from the logical plan to the bound physical one; it
+    # closes before the first stage runs
+    with trace.span("query.plan"):
+        bound = _bind(plan_physical(plan))
+    return _run_bound(*bound)

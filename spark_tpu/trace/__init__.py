@@ -19,12 +19,23 @@ processes via the ``X-SparkTpu-Trace`` header (``header_value()`` /
 ``from_header()``), so one trace spans client -> federation router ->
 replica -> scheduler -> stages.
 
+Every sampled span is also a ``jax.profiler.TraceAnnotation`` named
+``spark.<name>``: a profiler session (``tracing.trace(dir)``, the
+benchmark's traced slice) then holds the span tree on ``/host:CPU``,
+on the same clock as the device's operations, and an idle stretch of
+the device can be given to the span that covered it
+(benchmark/span_times.py). With no profiler session an annotation is
+an atomic load.
+
 Cost discipline: id stamping is always on (one contextvar read per
-event). Span *events* obey ``spark.tpu.trace.enabled`` and the
-``spark.tpu.trace.sampleRatio`` knob — the sampling decision is made
-once at root creation and inherited, so a trace is either complete or
-absent, never partial. Tracing never touches data: results are
-byte-identical with tracing on or off.
+event). Span *events* and annotations obey ``spark.tpu.trace.enabled``
+and the ``spark.tpu.trace.sampleRatio`` knob — the sampling decision
+is made once at root creation and inherited, so a trace is either
+complete or absent, never partial. A child span costs under 4 us
+(ids from a process-wide counter, a ``__slots__`` context manager,
+the event written straight into the ring); PERF.md has the reading.
+Tracing never touches data: results are byte-identical with tracing
+on or off.
 
 Every span name must be declared in ``SPAN_NAMES`` below —
 tools/lint_invariants.py rule 6 enforces the same discipline conf keys
@@ -33,12 +44,15 @@ and fault points get.
 
 from __future__ import annotations
 
+import itertools
+import os
 import random
 import threading
 import time
-import uuid
 from contextlib import contextmanager
 from typing import Any, Iterator, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 from spark_tpu import conf as CF
 from spark_tpu import metrics
@@ -66,13 +80,24 @@ SPAN_NAMES = frozenset({
     "router.forward",       # one forward attempt to one replica
     "scheduler.queue",      # submit -> admitted (queue + admission gate)
     "scheduler.run",        # prepare + execute on a scheduler worker
-    "query.execute",        # DataFrame._execute (root when standalone)
+    "query.parse",          # SparkSession.sql: text -> resolved plan
+    "query.execute",        # DataFrame._execute (root when standalone):
+                            # the whole query, host materialisation
+                            # (fetch + rows) included
     "query.analysis",       # static plan analysis + submit gate
+    "query.optimize",       # logical optimisation at execution time
+    "query.plan",           # logical -> physical, scan-cache lookup,
+                            # compaction replay, adaptive binding
     "compile.probe",        # AOT executable-store lookup
     "stage.run",            # one physical stage (host glue + device)
     "stage.fused",          # whole-query fused span: multi-exchange
                             # plan as ONE XLA program, zero host sync
+    "stage.dispatch",       # the jitted call alone: flatten + enqueue
     "stage.device",         # device execution, block_until_ready-bounded
+    "query.fetch",          # Batch.fetch_host: device -> host, whole
+    "device.wait",          # host blocked until the enqueued work is done
+    "fetch.copy",           # what is left of the device -> host copies
+    "query.rows",           # decode dictionaries/dates/decimals, build rows
     "exchange.stats",       # AQE host round-trip fetching device stats
     "agg.decide",           # adaptive-agg sketch fetch + strategy pick
     "agg.sort",             # sort rung: range exchange + sorted merge
@@ -104,8 +129,32 @@ class SpanContext(NamedTuple):
         return f"{self.trace_id}-{self.span_id}-{int(self.sampled)}"
 
 
-def _new_id(n: int = 16) -> str:
-    return uuid.uuid4().hex[:n]
+# ids without a system call apiece: a generator of this module's own,
+# seeded from the OS once (and again in a forked child). A trace id is 64
+# random bits. A span id is a per-process random prefix, which keeps two
+# replicas that serve one trace from minting the same id, and a
+# process-wide counter in decimal (its digits are hex digits too, which
+# is all the wire form asks).
+_RNG = random.Random()
+_SPAN_PREFIX = f"{_RNG.getrandbits(24):06x}"
+_SPAN_COUNTER = itertools.count(1)   # next() is atomic under the GIL
+
+
+def _reseed_ids() -> None:
+    global _SPAN_PREFIX
+    _RNG.seed()
+    _SPAN_PREFIX = f"{_RNG.getrandbits(24):06x}"
+
+
+os.register_at_fork(after_in_child=_reseed_ids)
+
+
+def _new_trace_id() -> str:
+    return f"{_RNG.getrandbits(64):016x}"
+
+
+def _new_span_id() -> str:
+    return f"{_SPAN_PREFIX}{next(_SPAN_COUNTER)}"
 
 
 def current() -> Optional[SpanContext]:
@@ -144,42 +193,85 @@ def _sample_root() -> bool:
     return ratio >= 1.0 or random.random() < ratio
 
 
-@contextmanager
-def span(name: str, **attrs: Any) -> Iterator[SpanContext]:
+_ANNOTATION = {name: "spark." + name for name in SPAN_NAMES}
+
+# the hot path's globals, bound once
+_CTX = metrics._TRACE_CTX
+_clock = time.perf_counter
+# a span reads one clock: ``t0`` is perf_counter plus this offset to the
+# epoch, taken again at every trace root, so a child's interval lies
+# inside its parent's to the nanosecond and a long-lived process follows
+# the wall clock's corrections
+_epoch_offset = time.time() - time.perf_counter()
+_thread_ident = threading.get_ident
+_new_context = tuple.__new__
+# is a profiler session collecting? (the atomic load a TraceMe makes
+# itself; asked first, a span outside any session builds no annotation)
+_profiling = getattr(TraceAnnotation, "is_enabled", lambda: True)
+
+
+class span:
     """Open one unit of work as a child of the ambient span (or as a
-    new trace root when none is active). On exit a ``span`` event is
-    recorded into the metrics ring/JSONL with trace_id/span_id/
-    parent_id, start time ``t0`` (epoch s), ``ms`` and the attrs; root
-    exit also flushes the buffered JSONL writer so a finished query is
-    always on disk."""
-    parent = metrics.trace_context()
-    if parent is None:
-        ctx = SpanContext(_new_id(16), _new_id(8), None, _sample_root())
-    else:
-        ctx = SpanContext(parent.trace_id, _new_id(8),
-                          parent.span_id, parent.sampled)
-    token = metrics.set_trace_context(ctx)
-    t0 = time.time()
-    p0 = time.perf_counter()
-    err: Optional[str] = None
-    try:
-        yield ctx
-    except BaseException as e:
-        err = repr(e)
-        raise
-    finally:
-        metrics.reset_trace_context(token)
-        if ctx.sampled:
-            ms = (time.perf_counter() - p0) * 1e3
-            fields = dict(name=name, ms=round(ms, 3), t0=round(t0, 6),
-                          tid=threading.get_ident() % 10_000_000,
-                          trace_id=ctx.trace_id, span_id=ctx.span_id,
-                          parent_id=ctx.parent_id)
-            if err is not None:
-                fields["error"] = err
-            fields.update(attrs)
-            metrics.record("span", **fields)
+    new trace root when none is active): ``with trace.span(name,
+    **attrs) as ctx``. A sampled span is a ``TraceAnnotation``
+    ``spark.<name>`` round its body, and on exit a ``span`` event in
+    the metrics ring/JSONL with trace_id/span_id/parent_id, start time
+    ``t0`` (epoch s), ``ms`` and the attrs; an unsampled one only
+    carries the ids. Root exit also flushes the buffered JSONL writer
+    so a finished query is always on disk."""
+
+    __slots__ = ("name", "attrs", "ctx", "_token", "_annotation", "_p0")
+
+    def __init__(self, name: str, **attrs: Any):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> SpanContext:
+        global _epoch_offset
+        parent = _CTX.get()
         if parent is None:
+            ctx = SpanContext(_new_trace_id(), _new_span_id(), None,
+                              _sample_root())
+            _epoch_offset = time.time() - _clock()
+        else:
+            # the hot path: _new_span_id() and SpanContext(...) without
+            # their two calls
+            ctx = _new_context(SpanContext, (
+                parent[0], f"{_SPAN_PREFIX}{next(_SPAN_COUNTER)}",
+                parent[1], parent[3]))
+        self.ctx = ctx
+        self._token = _CTX.set(ctx)
+        if ctx[3]:
+            if _profiling():
+                name = self.name
+                self._annotation = annotation = TraceAnnotation(
+                    _ANNOTATION.get(name) or "spark." + name)
+                annotation.__enter__()
+            else:
+                self._annotation = None
+            self._p0 = _clock()
+        return ctx
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        ctx = self.ctx
+        _CTX.reset(self._token)
+        if ctx[3]:
+            p0 = self._p0
+            dt = _clock() - p0
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
+            t0 = p0 + _epoch_offset
+            ev = {"ts": t0 + dt, "kind": "span", "name": self.name,
+                  "ms": dt * 1e3, "t0": t0,
+                  "tid": _thread_ident() % 10_000_000,
+                  "trace_id": ctx[0], "span_id": ctx[1],
+                  "parent_id": ctx[2]}
+            if exc is not None:
+                ev["error"] = repr(exc)
+            if self.attrs:
+                ev.update(self.attrs)
+            metrics.append(ev)
+        if ctx[2] is None:
             # trace root closed: a query just finished end-to-end
             metrics.flush_log()
 

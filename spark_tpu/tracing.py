@@ -12,12 +12,14 @@ time, DMA, and ICI traffic); engine-side accounting reuses the stage
 event stream from metrics.py. This module glues the two:
 
 - ``trace(dir)``: context manager capturing a jax profiler trace of
-  everything executed inside (view with TensorBoard or xprof).
-- ``annotate(name)``: names a region so engine stages are findable
-  inside the device trace (TraceAnnotation).
+  everything executed inside (view with TensorBoard or xprof). Every
+  sampled span of spark_tpu/trace/ is a ``spark.<name>``
+  TraceAnnotation, so the capture holds the span tree on the host
+  plane, on the device trace's clock (phases: ``query.parse``,
+  ``query.optimize``, ``query.plan``, ``stage.*``, ``query.fetch``).
 - ``format_trace()`` / ``trace_breakdown()``: the engine-side span
   tree from spark_tpu/trace/ as a text waterfall and as a
-  host/queue/device/transfer time split. The two tracing layers
+  host/queue/device/transfer/fetch time split. The two tracing layers
   compose: spans say WHICH query/stage/chunk owned the wall time,
   the jax profiler says what the device did inside it (Perfetto loads
   both — ``history.chrome_trace`` exports the span side).
@@ -25,14 +27,11 @@ event stream from metrics.py. This module glues the two:
   from the event stream — the text form of the SQL-tab DAG view.
 - ``pipeline_profile()``: the out-of-HBM chunk pipeline's per-tier
   stage/overlap rollup (decode/filter/transfer vs device compute).
-- ``planning_tracker``: phase timing for parse/optimize/plan (the
-  QueryPlanningTracker analogue).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
 from collections import defaultdict
 from typing import Dict, Iterator, List, Optional
 
@@ -50,15 +49,6 @@ def trace(log_dir: str, *, create_perfetto_link: bool = False) -> Iterator[None]
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Mark a named region inside a device trace."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
 
 
 def _trace_events(events_or_id=None) -> List[dict]:
@@ -114,39 +104,47 @@ def format_trace(events_or_id=None, width: int = 40) -> str:
     return "\n".join(lines)
 
 
+#: span name -> the component of ``trace_breakdown`` it is summed into
+_BREAKDOWN = {"scheduler.queue": "queue_ms",
+              "stage.device": "device_ms",    # the mesh's forced sync
+              "device.wait": "device_ms",     # fetch_host: host blocked
+              "pipeline.transfer": "transfer_ms",
+              "fetch.copy": "fetch_ms"}
+
+
 def trace_breakdown(events_or_id=None) -> Dict[str, float]:
     """Split one trace's wall time into where it went: ``wall_ms`` is
     the root span; ``queue_ms`` the scheduler admission wait
-    (scheduler.queue spans), ``device_ms`` the block_until_ready-bounded
-    device execution (stage.device), ``transfer_ms`` the chunk-pipeline
-    host->device staging (pipeline.transfer); ``host_ms`` is the
-    remainder (decode, planning, glue, HTTP) — so the four components
-    sum to wall by construction. Accepts a trace_id, an event list, or
-    nothing (last query)."""
+    (scheduler.queue spans), ``device_ms`` the time the host was blocked
+    on the device (stage.device in the mesh engine, device.wait in
+    fetch_host), ``transfer_ms`` the chunk-pipeline host->device staging
+    (pipeline.transfer), ``fetch_ms`` the device->host copy of the
+    result (fetch.copy); ``host_ms`` is the remainder (decode, planning,
+    glue, HTTP) — so the five components sum to wall by construction.
+    Accepts a trace_id, an event list, or nothing (last query)."""
     evs = _trace_events(events_or_id)
     spans = [e for e in evs if e.get("kind") == "span"]
     out = {"wall_ms": 0.0, "queue_ms": 0.0, "device_ms": 0.0,
-           "transfer_ms": 0.0, "host_ms": 0.0}
+           "transfer_ms": 0.0, "fetch_ms": 0.0, "host_ms": 0.0}
     if not spans:
         return out
     ids = {e.get("span_id") for e in spans}
     roots = [e for e in spans if e.get("parent_id") is None
              or e.get("parent_id") not in ids]
-    out["wall_ms"] = round(max(
-        (float(e.get("ms", 0.0)) for e in roots), default=0.0), 3)
-    sums = {"scheduler.queue": 0.0, "stage.device": 0.0,
-            "pipeline.transfer": 0.0}
+    wall = max((float(e.get("ms", 0.0)) for e in roots), default=0.0)
+    name_of = {e.get("span_id"): e.get("name") for e in spans}
     for e in spans:
-        name = e.get("name")
-        if name in sums:
-            sums[name] += float(e.get("ms", 0.0))
-    out["queue_ms"] = round(sums["scheduler.queue"], 3)
-    out["device_ms"] = round(sums["stage.device"], 3)
-    out["transfer_ms"] = round(sums["pipeline.transfer"], 3)
-    out["host_ms"] = round(max(
-        0.0, out["wall_ms"] - out["queue_ms"] - out["device_ms"]
-        - out["transfer_ms"]), 3)
-    return out
+        ms = float(e.get("ms", 0.0))
+        part = _BREAKDOWN.get(e.get("name"))
+        if part is not None:
+            out[part] += ms
+        elif e.get("name") == "stage.dispatch" \
+                and name_of.get(e.get("parent_id")) == "stage.device":
+            # the mesh's enqueue lies inside its stage.device: host time
+            out["device_ms"] -= ms
+    out["host_ms"] = max(0.0, wall - sum(out.values()))
+    out["wall_ms"] = wall
+    return {k: round(v, 3) for k, v in out.items()}
 
 
 def query_profile(events: Optional[List[dict]] = None) -> Dict[str, dict]:
@@ -760,24 +758,3 @@ def format_mview_profile(profile: Optional[Dict[str, dict]] = None
             lines.append(f"{name:<14} {rec['merges']:>6} "
                          f"{rec['dedups']:>6} {rec['rows']:>6}")
     return "\n".join(lines)
-
-
-class PlanningTracker:
-    """Phase timing for the planning pipeline (reference:
-    catalyst/QueryPlanningTracker.scala). Use as
-    ``with tracker.phase("optimize"): ...``; phases() returns ms."""
-
-    def __init__(self):
-        self._phases: Dict[str, float] = {}
-
-    @contextlib.contextmanager
-    def phase(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._phases[name] = self._phases.get(name, 0.0) + \
-                (time.perf_counter() - t0) * 1e3
-
-    def phases(self) -> Dict[str, float]:
-        return {k: round(v, 3) for k, v in self._phases.items()}

@@ -80,8 +80,87 @@ def test_span_names_registry():
     assert trace.SPAN_NAMES
     for name in ("router.dispatch", "connect.request", "scheduler.run",
                  "query.execute", "stage.run", "stage.device",
-                 "pipeline.decode", "pipeline.transfer", "fault.retry"):
+                 "pipeline.decode", "pipeline.transfer", "fault.retry",
+                 "query.parse", "query.optimize", "query.plan",
+                 "stage.dispatch", "query.fetch", "device.wait",
+                 "fetch.copy", "query.rows"):
         assert name in trace.SPAN_NAMES, name
+
+
+def test_span_ids_unique_and_wire_safe():
+    """Span ids come from a process-wide counter behind a per-process
+    prefix: distinct, and made of the characters the header allows."""
+    with trace.span("query.execute") as root:
+        ids = []
+        for _ in range(100):
+            with trace.span("stage.run") as ctx:
+                ids.append(ctx.span_id)
+                assert ctx.parent_id == root.span_id
+                assert ctx.trace_id == root.trace_id
+        got = trace.from_header(trace.header_value())
+    assert len(set(ids)) == 100 and root.span_id not in ids
+    assert got is not None and got.span_id == root.span_id
+    assert trace.current() is None
+
+
+def test_span_ids_and_ring_numbers_under_threads():
+    """More threads than cores open spans at once: every span id is
+    distinct, every event keeps its own parent, and the ring's numbers
+    rise by one (the harness reads the ring by number)."""
+    import sys
+    import threading
+
+    metrics.reset()
+    threads_n, spans_n = 16, 150
+    seen, errors = [], []
+
+    def work(k):
+        try:
+            with trace.span("query.execute", worker=k) as root:
+                for _ in range(spans_n):
+                    with trace.span("stage.run") as ctx:
+                        assert ctx.parent_id == root.span_id
+                        seen.append(ctx.span_id)
+        except Exception as e:  # surfaced below, on the test's thread
+            errors.append(repr(e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(k,))
+                   for k in range(threads_n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads)
+    assert len(seen) == len(set(seen)) == threads_n * spans_n
+    evs = metrics.recent(4096)
+    spans = _spans(evs)
+    assert len(spans) == threads_n * (spans_n + 1)
+    assert len({e["span_id"] for e in spans}) == len(spans)
+    roots = {e["span_id"]: e for e in spans if e["name"] == "query.execute"}
+    assert len(roots) == threads_n
+    for e in spans:
+        if e["name"] == "stage.run":
+            assert roots[e["parent_id"]]["trace_id"] == e["trace_id"]
+    ns = [e["n"] for e in evs]
+    assert ns == list(range(ns[0], ns[0] + len(ns)))
+
+
+def test_span_records_error_and_resets_context():
+    metrics.reset()
+    with pytest.raises(ValueError):
+        with trace.span("query.execute"):
+            with trace.span("stage.run", op="x"):
+                raise ValueError("boom")
+    assert trace.current() is None
+    spans = {e["name"]: e for e in _spans(metrics.recent(10))}
+    assert "boom" in spans["stage.run"]["error"]
+    assert "boom" in spans["query.execute"]["error"]
+    assert spans["stage.run"]["op"] == "x"
 
 
 def test_header_roundtrip_and_malformed_dropped():
@@ -136,6 +215,139 @@ def test_span_tree_well_formed_multi_stage_plan(spark, tmp_path):
     assert all(e.get("trace_id") == next(iter(tids)) for e in stages)
 
 
+def _inside(child, parent, slack_ms=0.5):
+    """``child``'s interval lies inside ``parent``'s (t0 is on
+    time.time(), ms on perf_counter: allow the two clocks a little)."""
+    c0, p0 = child["t0"] * 1e3, parent["t0"] * 1e3
+    return (c0 >= p0 - slack_ms
+            and c0 + child["ms"] <= p0 + parent["ms"] + slack_ms)
+
+
+def test_collect_is_one_trace_with_every_phase(spark, tmp_path):
+    """One ``spark.sql(...).collect()``: one trace whose root
+    ``query.execute`` covers the query, fetch included, with a span for
+    every phase, children inside their parents and no orphan."""
+    _write_parquet(tmp_path / "tr_ph.parquet", 96, 6)
+    spark.read.parquet(str(tmp_path / "tr_ph.parquet")) \
+        .createOrReplaceTempView("tr_ph")
+    q = "SELECT SUM(v) AS s FROM tr_ph WHERE k = 1"
+    for _ in range(3):
+        spark.sql(q).collect()          # reach the fused steady state
+    df = spark.sql(q)
+    c0 = time.perf_counter()
+    rows = df.collect()
+    wall_ms = (time.perf_counter() - c0) * 1e3
+    assert len(rows) == 1
+    spans = _spans(metrics.last_query())
+    assert len({e["trace_id"] for e in spans}) == 1
+    roots = _roots(spans)
+    assert [r["name"] for r in roots] == ["query.execute"]
+    root = roots[0]
+    by_id = {e["span_id"]: e for e in spans}
+    assert len(by_id) == len(spans)
+    for e in spans:
+        if e is not root:
+            assert e["parent_id"] in by_id, e            # no orphan
+            assert _inside(e, by_id[e["parent_id"]]), e
+
+    def parent_name(name):
+        found = [by_id[e["parent_id"]]["name"] for e in spans
+                 if e["name"] == name]
+        assert found, f"no {name} span"
+        return found
+
+    names = {e["name"] for e in spans}
+    assert {"query.optimize", "query.plan", "stage.run", "stage.dispatch",
+            "query.fetch", "device.wait", "fetch.copy",
+            "query.rows"} <= names
+    assert parent_name("stage.dispatch") == ["stage.run"]
+    assert parent_name("device.wait") == ["query.fetch"]
+    assert parent_name("fetch.copy") == ["query.fetch"]
+    assert parent_name("query.fetch") == ["query.execute"]
+    assert parent_name("query.rows") == ["query.execute"]
+    # the plan is bound before the first stage runs, and the fetch
+    # follows the last one
+    at = {e["name"]: e["t0"] for e in spans}
+    assert at["query.optimize"] <= at["query.plan"] <= at["stage.run"] \
+        <= at["query.fetch"] <= at["query.rows"]
+    # the root is the caller's collect() to within the call overhead
+    assert root["ms"] <= wall_ms
+    assert wall_ms - root["ms"] <= max(0.05 * wall_ms, 0.3), (
+        wall_ms, root["ms"])
+    # self times partition the root
+    own = {e["span_id"]: e["ms"] for e in spans}
+    for e in spans:
+        if e is not root:
+            own[e["parent_id"]] -= e["ms"]
+    assert sum(own.values()) == pytest.approx(root["ms"])
+    assert all(v >= -0.05 for v in own.values()), own
+
+
+@pytest.mark.parametrize("action", ["collect", "toPandas", "toArrow",
+                                    "count"])
+def test_every_action_fetches_inside_the_root(spark, action):
+    df = spark.range(50).filter("id % 2 = 0")
+    getattr(df, action)()
+    spans = _spans(metrics.last_query())
+    roots = _roots(spans)
+    assert [r["name"] for r in roots] == ["query.execute"], action
+    names = {e["name"] for e in spans}
+    assert {"query.fetch", "device.wait", "fetch.copy",
+            "query.rows"} <= names, action
+
+
+def test_mesh_execution_has_the_same_phases():
+    """mesh[2]: the mesh engine's own optimize / plan spans, its
+    stage.device (the forced sync, as it was) with the enqueue as
+    stage.dispatch inside it, and the gather as fetch.copy."""
+    from spark_tpu.api.session import SparkSession
+
+    prev = SparkSession._active
+    SparkSession._reset()
+    try:
+        mesh = (SparkSession.builder.master("mesh[2]")
+                .appName("trace-mesh").getOrCreate())
+        df = mesh.range(4000).groupBy().sum("id")
+        assert df.collect()[0][0] == sum(range(4000))
+        spans = _spans(metrics.last_query())
+    finally:
+        SparkSession._reset()
+        SparkSession._active = prev
+    by_id = {e["span_id"]: e for e in spans}
+    roots = _roots(spans)
+    assert [r["name"] for r in roots] == ["query.execute"]
+    names = {e["name"] for e in spans}
+    assert {"query.optimize", "query.plan", "stage.run", "stage.device",
+            "stage.dispatch", "fetch.copy", "query.fetch",
+            "query.rows"} <= names
+    for e in spans:
+        if e["name"] == "stage.dispatch":
+            assert by_id[e["parent_id"]]["name"] == "stage.device"
+    assert any(e["name"] == "fetch.copy" and e.get("op") == "gather"
+               for e in spans)
+    bd = tracing.trace_breakdown(spans)
+    assert bd["device_ms"] > 0
+    assert bd["device_ms"] + bd["fetch_ms"] + bd["host_ms"] == \
+        pytest.approx(bd["wall_ms"], abs=0.01)
+
+
+def test_tracing_off_same_rows_no_span_event(spark, tmp_path, trace_conf):
+    _write_parquet(tmp_path / "tr_off.parquet", 64, 4)
+    spark.read.parquet(str(tmp_path / "tr_off.parquet")) \
+        .createOrReplaceTempView("tr_off")
+    q = "SELECT k, SUM(v) AS s FROM tr_off GROUP BY k ORDER BY k"
+    want = spark.sql(q).collect()
+    trace_conf.set("spark.tpu.trace.enabled", False)
+    before = metrics.recent(1)[-1]["n"]
+    got = spark.sql(q).collect()
+    assert got == want
+    new = [e for e in metrics.recent(4096) if e["n"] > before]
+    assert new, "flat events are still recorded"
+    assert _spans(new) == []
+    # ids are stamped on flat events all the same
+    assert all(e.get("trace_id") for e in new if e["kind"] == "stage")
+
+
 def test_breakdown_components_sum_to_wall(spark, tmp_path):
     _write_parquet(tmp_path / "tr_bd.parquet", 64, 4)
     spark.read.parquet(str(tmp_path / "tr_bd.parquet")) \
@@ -143,8 +355,11 @@ def test_breakdown_components_sum_to_wall(spark, tmp_path):
     spark.sql("SELECT k, SUM(v) FROM tr_bd GROUP BY k").collect()
     bd = tracing.trace_breakdown()
     assert bd["wall_ms"] > 0
+    # on the single-device session the device's share is the wait in
+    # fetch_host (device.wait), and the copy is taken out of host_ms
+    assert bd["device_ms"] > 0 and bd["fetch_ms"] > 0
     total = (bd["queue_ms"] + bd["device_ms"] + bd["transfer_ms"]
-             + bd["host_ms"])
+             + bd["fetch_ms"] + bd["host_ms"])
     # host_ms is the remainder by construction: the split sums to wall
     # well inside the 10% acceptance bound
     assert abs(total - bd["wall_ms"]) <= max(0.1 * bd["wall_ms"], 1.0)
