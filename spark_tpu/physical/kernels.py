@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_tpu import metrics
+
 
 class SortKey(NamedTuple):
     data: jnp.ndarray
@@ -132,9 +134,15 @@ def group_ids_from_sorted(
 
 # ---- segment aggregation ----------------------------------------------------
 #
-# TPU reality check (measured on v5e): XLA scatter-add (jax.ops.segment_sum)
-# costs ~100 ms/M rows regardless of dtype, while dense masked reductions,
-# cumsum, and associative scans are bandwidth-bound (~free). Strategy:
+# TPU reality check (v5e, one grouped sum over 6,001,664 rows inside a
+# jit with x64 on; tools/probe_seg_sum.py, chip run of PR 27): XLA
+# scatter-add (jax.ops.segment_sum) costs 360 ms on an int64 column and
+# 1,310-1,340 ms on the three emulated-f64 limbs of one (K = 6 ... 200; 730
+# and 2,370 ms at K = 100,000), whatever K is: it pays per row. A dense
+# masked int64 reduction costs 0.9 ms at K = 6 and 2.4 ms at K = 64 (five
+# of them over one (seg, mask) 2.4 and 10 ms), the same through f64 limbs
+# 2.8 and 19.5 ms; cumsum + searchsorted over sorted int64 ids 8 ms at
+# K = 200 and 75 ms at K = 100,000. Strategy:
 #   - K == 1: plain reduction
 #   - K small (<= _MASKED_SEG_LIMIT): K masked dense reductions (XLA fuses
 #     the data reads; cost is K passes of pure bandwidth)
@@ -191,31 +199,14 @@ def _sorted_seg_red(masked, seg, num_segments: int, combine):
     return run[jnp.clip(ends, 0, masked.shape[0] - 1)]
 
 
-def seg_sum(data, seg, mask, num_segments: int, sorted_seg: bool = False):
-    if data.dtype == jnp.int64:
-        # int64 is EMULATED on TPU (no native 64-bit vector ALU) — a
-        # 6M-row int64 masked reduction measured ~12x slower than f64.
-        # Decompose into three 21-bit limbs (arithmetic-shift top limb
-        # keeps two's complement identity), sum each EXACTLY in native
-        # f64 (limb partial sums stay under 2^53 up to ~4B rows), then
-        # recombine; int64 wraparound makes the recombination correct
-        # whenever the true total fits 64 bits. Exactness is what the
-        # scaled-decimal Sum path (Decimal.scala peer) requires.
-        m21 = (1 << 21) - 1
-        parts = []
-        for sh in (0, 21, 42):
-            limb = (data >> sh) & m21 if sh < 42 else data >> 42
-            parts.append(seg_sum(limb.astype(jnp.float64), seg, mask,
-                                 num_segments, sorted_seg))
-        return (parts[0].astype(jnp.int64)
-                + (parts[1].astype(jnp.int64) << 21)
-                + (parts[2].astype(jnp.int64) << 42))
+def _sum_rung(data, seg, mask, num_segments: int, sorted_seg: bool):
+    """(sums, rung) for one column, routed by the dtype it travels as."""
     zero = jnp.zeros((), dtype=data.dtype)
     masked = jnp.where(mask, data, zero)
     if num_segments == 1:
         # global aggregate: a plain reduction beats a 1-segment scatter-add
         # (this is the AggregateBenchmark 'agg w/o group' hot path)
-        return jnp.sum(masked)[None]
+        return jnp.sum(masked)[None], "reduce"
     if jnp.issubdtype(data.dtype, jnp.floating):
         # float addition rounds per combination-tree shape, and every
         # tree-structured reduction here (cumsum difference, masked
@@ -225,12 +216,48 @@ def seg_sum(data, seg, mask, num_segments: int, sorted_seg: bool = False):
         # rows. XLA scatter-add applies updates in row order: the sum
         # depends only on the segment's own rows, byte-stable across
         # layouts (int/decimal sums are exact and keep the fast paths).
-        return jax.ops.segment_sum(masked, seg, num_segments=num_segments)
+        return (jax.ops.segment_sum(masked, seg, num_segments=num_segments),
+                "scatter")
     if num_segments <= _MASKED_SEG_LIMIT:
-        return _masked_reduce(data, seg, mask, num_segments, jnp.sum, zero)
+        return (_masked_reduce(data, seg, mask, num_segments, jnp.sum, zero),
+                "masked")
     if sorted_seg:
-        return _sorted_seg_sum(masked, seg, num_segments)
-    return jax.ops.segment_sum(masked, seg, num_segments=num_segments)
+        return _sorted_seg_sum(masked, seg, num_segments), "cumsum"
+    return (jax.ops.segment_sum(masked, seg, num_segments=num_segments),
+            "scatter")
+
+
+def seg_sum(data, seg, mask, num_segments: int, sorted_seg: bool = False):
+    """Grouped sum. The rule of the ladder: EXACT sums (integers, scaled
+    decimals) follow the integer rungs — reduce, masked, cumsum, scatter,
+    chosen from (num_segments, sorted_seg); only USER FLOATS need row
+    order and take the scatter-add whatever K is."""
+    limbs = (data.dtype == jnp.int64
+             and not 1 < num_segments <= _MASKED_SEG_LIMIT)
+    if limbs:
+        # K == 1 and K > 64 keep the path they had (PR 27 rerouted
+        # 1 < K <= 64 only; ROADMAP A0b has what K > 64 pays): three
+        # 21-bit limbs (arithmetic-shift top limb keeps two's complement
+        # identity), each summed exactly in f64 — limb partial sums stay
+        # under 2^53 up to ~4B rows — and routed by that carrier dtype,
+        # so K > 64 scatter-adds three times. int64 wraparound makes the
+        # recombination correct whenever the true total fits 64 bits.
+        m21 = (1 << 21) - 1
+        parts = []
+        for sh in (0, 21, 42):
+            limb = (data >> sh) & m21 if sh < 42 else data >> 42
+            part, rung = _sum_rung(limb.astype(jnp.float64), seg, mask,
+                                   num_segments, sorted_seg)
+            parts.append(part.astype(jnp.int64))
+        out = parts[0] + (parts[1] << 21) + (parts[2] << 42)
+    else:
+        out, rung = _sum_rung(data, seg, mask, num_segments, sorted_seg)
+    # trace-time event (as ops/pallas_agg._note): the rung this program
+    # was BUILT from; an execution of the compiled stage records nothing
+    metrics.record("seg_sum", rung=rung, k=int(num_segments),
+                   rows=int(data.shape[0]), dtype=str(data.dtype),
+                   limbs=limbs)
+    return out
 
 
 def seg_count(seg, mask, num_segments: int, sorted_seg: bool = False):

@@ -1,0 +1,170 @@
+"""kernels.seg_sum: the rung a sum takes and the bits it returns.
+
+Exact sums (int64 / scaled decimals) follow the integer ladder — with
+1 < K <= 64 the dense masked reduction, never a scatter-add (PR 27: q1's
+8.6 s on the v5e were fifteen emulated-f64 scatter-adds); only user
+floats need row order. The StableHLO tripwire and the trace-time
+``seg_sum`` event stop that regressing unseen.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_tpu import metrics
+from spark_tpu.physical import kernels as K
+
+N = 3000
+KS = [2, 6, 64, 65, 200]
+
+
+def _reference(data, seg, mask, k):
+    ref = np.zeros(k, np.int64)
+    np.add.at(ref, seg[mask], data[mask])  # wraps like the device's int64
+    return ref
+
+
+def _columns(rng):
+    """Limb extremes: values near +-2^62 whose totals wrap, an
+    all-negative column, one whose top limbs are all zero."""
+    wild = rng.integers(-(1 << 62), 1 << 62, N, dtype=np.int64)
+    wild[:8] = [(1 << 62) - 1, -(1 << 62), (1 << 42) - 1, 1 << 42,
+                -1, (1 << 21) - 1, -(1 << 21), 0]
+    return {"wraps": wild,
+            "negative": -rng.integers(1, 1 << 61, N, dtype=np.int64),
+            "money": rng.integers(0, 10 ** 11, N, dtype=np.int64)}
+
+
+def _seg(rng, k, sorted_seg):
+    # slot k - 1 stays empty: an empty segment sums to zero on every rung
+    seg = rng.integers(0, max(k - 1, 1), N, dtype=np.int64)
+    return np.sort(seg) if sorted_seg else seg
+
+
+def _mask(rng, kind):
+    return {"all": np.ones(N, bool), "none": np.zeros(N, bool),
+            "p70": rng.random(N) < 0.7}[kind]
+
+
+def _jitted(k, sorted_seg):
+    return jax.jit(lambda d, s, m: K.seg_sum(d, s, m, k, sorted_seg))
+
+
+@pytest.mark.parametrize("mask_kind", ["all", "none", "p70"])
+@pytest.mark.parametrize("sorted_seg", [False, True])
+@pytest.mark.parametrize("k", KS)
+def test_int64_sum_is_bit_equal_to_numpy(rng, k, sorted_seg, mask_kind):
+    seg, mask = _seg(rng, k, sorted_seg), _mask(rng, mask_kind)
+    fn = _jitted(k, sorted_seg)
+    for name, data in _columns(rng).items():
+        got = np.asarray(fn(jnp.asarray(data), jnp.asarray(seg),
+                            jnp.asarray(mask)))
+        assert got.dtype == np.int64
+        assert np.array_equal(got, _reference(data, seg, mask, k)), name
+
+
+@pytest.mark.parametrize("sorted_seg", [False, True])
+@pytest.mark.parametrize("k", [6, 64, 200])
+def test_static_and_compacted_layouts_give_the_same_bits(rng, k, sorted_seg):
+    """PR 16's invariant, for decimals on the rung they take now: the
+    same live rows at another capacity, dead slots holding garbage."""
+    data, seg = _columns(rng)["wraps"], _seg(rng, k, sorted_seg)
+    mask = _mask(rng, "p70")
+    live = int(mask.sum())
+    cap = 4096
+    c_data = rng.integers(-(1 << 62), 1 << 62, cap, dtype=np.int64)
+    c_data[:live] = data[mask]
+    c_seg = np.full(cap, k - 1, np.int64)  # dead rows: the last segment
+    c_seg[:live] = seg[mask]
+    c_mask = np.arange(cap) < live
+    static = _jitted(k, sorted_seg)(jnp.asarray(data), jnp.asarray(seg),
+                                    jnp.asarray(mask))
+    compact = _jitted(k, sorted_seg)(jnp.asarray(c_data), jnp.asarray(c_seg),
+                                     jnp.asarray(c_mask))
+    assert np.asarray(static).tobytes() == np.asarray(compact).tobytes()
+
+
+CASES = [  # (dtype, K, the rung it must be built from)
+    pytest.param(jnp.int64, 6, "masked", id="int64-k6"),
+    pytest.param(jnp.int64, 65, "scatter", id="int64-k65"),
+    pytest.param(jnp.float64, 6, "scatter", id="float64-k6"),
+]
+
+
+def _shapes(dtype):
+    return (jax.ShapeDtypeStruct((N,), dtype),
+            jax.ShapeDtypeStruct((N,), jnp.int64),
+            jax.ShapeDtypeStruct((N,), jnp.bool_))
+
+
+@pytest.mark.parametrize("dtype,k,rung", CASES)
+def test_stablehlo_scatter_tripwire(dtype, k, rung):
+    """No scatter in an exact sum with K <= 64; K > 64 and user floats
+    (which keep row order) still have theirs."""
+    text = _jitted(k, False).lower(*_shapes(dtype)).as_text()
+    assert ("scatter" in text) == (rung == "scatter")
+
+
+def _events():
+    return [e for e in metrics.recent(4096) if e["kind"] == "seg_sum"]
+
+
+@pytest.mark.parametrize("dtype,k,rung", CASES)
+def test_trace_time_event_names_the_rung(rng, dtype, k, rung):
+    metrics.reset()
+    fn = _jitted(k, False)
+    args = (jnp.asarray(_columns(rng)["money"]).astype(dtype),
+            jnp.asarray(_seg(rng, k, False)), jnp.asarray(_mask(rng, "p70")))
+    fn(*args)
+    (ev,) = _events()
+    assert (ev["rung"], ev["k"], ev["rows"]) == (rung, k, N)
+    assert ev["dtype"] == np.dtype(dtype).name
+    assert ev["limbs"] == (dtype == jnp.int64 and k > 64)
+    fn(*args)  # the compiled program runs: nothing is recorded
+    assert len(_events()) == 1
+
+
+def test_global_sum_keeps_the_plain_reduction(rng):
+    metrics.reset()
+    data, mask = _columns(rng)["wraps"], _mask(rng, "p70")
+    seg = np.zeros(N, np.int64)
+    got = _jitted(1, False)(jnp.asarray(data), jnp.asarray(seg),
+                            jnp.asarray(mask))
+    assert np.array_equal(np.asarray(got), _reference(data, seg, mask, 1))
+    (ev,) = _events()
+    assert (ev["rung"], ev["limbs"]) == ("reduce", True)
+
+
+@pytest.fixture(params=["local", "mesh[4]"])
+def engine(request, spark):
+    """The session's single-device engine, or a mesh[4] session that
+    leaves the suite's own session as it found it."""
+    from spark_tpu.api.session import SparkSession
+
+    if request.param == "local":
+        yield spark
+        return
+    prev = SparkSession._active
+    SparkSession._reset()
+    yield SparkSession.builder.master(request.param).getOrCreate()
+    SparkSession._reset()
+    SparkSession._active = prev
+
+
+def test_q1_sums_are_masked_and_q6_is_a_reduction(engine):
+    """Both engines build TPC-H Q1's decimal sums (K = 6 slots) from the
+    masked rung and Q6's global sum from the plain reduction."""
+    from spark_tpu.tpch.gen import generate_tables, register_views
+    from spark_tpu.tpch.queries import QUERIES
+
+    # an SF no other test uses, so that the stages are traced here
+    register_views(engine, generate_tables(0.0031, seed=27))
+    for query, rung, limbs in ((1, "masked", False), (6, "reduce", True)):
+        metrics.reset()
+        assert engine.sql(QUERIES[query]).collect()
+        events = _events()
+        assert events, f"q{query} traced no seg_sum"
+        assert {(e["rung"], e["limbs"], e["dtype"]) for e in events} == {
+            (rung, limbs, "int64")}, events
+        assert {e["k"] for e in events} == {6 if query == 1 else 1}

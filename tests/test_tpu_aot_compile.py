@@ -103,3 +103,46 @@ def test_flagship_fused_stage_compiles_for_v5e(one_chip,
     compiled, text = _compile(fn, *shapes)
     assert "sort" in text
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+Q1_ROWS = 6_001_664  # lineitem at SF1 (6,000,647) in its 1,024-row bucket
+
+
+def test_q1_fused_stage_has_no_group_slot_scatter_on_v5e(
+        one_chip, as_the_session_runs, spark, monkeypatch):
+    """TPC-H Q1's fused stage, planned by the session on a sliver of
+    data and compiled at SF1's row count: its six group slots (3 x 2
+    dictionary codes) are filled by masked reductions. The scatter-adds
+    that took 8.6 s an execution on the chip (PERF.md, PR 27) showed in
+    the optimised HLO as scatters with an f32[6] result."""
+    import re
+
+    import spark_tpu.compile as compile_pkg
+    from spark_tpu.tpch.gen import generate_tables, register_views
+    from spark_tpu.tpch.queries import QUERIES
+
+    stages = []
+    build = compile_pkg.build_stage_callable
+
+    def capture(tier, plan, trace_fn, example_args, *a, **kw):
+        stages.append((plan, trace_fn, example_args))
+        return build(tier, plan, trace_fn, example_args, *a, **kw)
+
+    monkeypatch.setattr(compile_pkg, "build_stage_callable", capture)
+    # an SF no other test uses: the stage is new to the process's cache
+    register_views(spark, generate_tables(0.0027, seed=27))
+    assert len(spark.sql(QUERIES[1]).collect()) == 4
+    (stage,) = [s for s in stages if "Aggregate" in s[0].tree_string()]
+    _, trace_fn, example_args = stage
+    cap = max(a.shape[0] for a in jax.tree.leaves(example_args) if a.ndim)
+
+    def at_sf1(a):
+        shape = ((Q1_ROWS,) + a.shape[1:]
+                 if a.ndim and a.shape[0] == cap else a.shape)
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
+
+    _, text = _compile(trace_fn, jax.tree.map(at_sf1, example_args))
+    assert f"[{Q1_ROWS}]" in text
+    scatters = [line for line in text.splitlines()
+                if "scatter" in line and re.search(r"f(32|64)\[6\]", line)]
+    assert not scatters, scatters[:3]
