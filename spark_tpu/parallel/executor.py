@@ -1323,7 +1323,8 @@ class MeshExecutor:
                     if isinstance(p, _ShardSlot):
                         return Pipe.from_batch_data(p.scan_schema, next(it))
                     pipes = [go(c) for c in p.children()]
-                    return p.trace(pipes)
+                    with _trace.operator_scope(p):
+                        return p.trace(pipes)
 
                 batch = go(skeleton).to_batch()
                 schema_box["schema"] = batch.schema

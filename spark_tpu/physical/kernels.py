@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_tpu import metrics
+from spark_tpu import trace
 
 
 class SortKey(NamedTuple):
@@ -68,7 +68,17 @@ def searchsorted(a: jnp.ndarray, v: jnp.ndarray,
     threshold = _searchsorted_sort_threshold()
     method = ("scan" if v.size < 4096 or v.size * threshold <= a.size
               else "sort")
+    if method == "sort":
+        _built_sort("searchsorted", a.size + v.size, a.dtype)
     return jnp.searchsorted(a, v, side=side, method=method)
+
+
+def _built_sort(site: str, rows: int, dtype) -> None:
+    """A ``sort`` build event (as seg_sum's): one per XLA sort BUILT,
+    inside a stage's trace or eagerly in a blocking run; an execution of
+    a compiled stage records none. On the chip's compiler a sort costs
+    22-69 s cold (ROADMAP A2), so their count is what a first run pays."""
+    trace.built("sort", site=site, rows=int(rows), dtype=str(dtype))
 
 
 def lexsort_permutation(keys: Sequence[SortKey], row_mask: jnp.ndarray) -> jnp.ndarray:
@@ -89,20 +99,24 @@ def lexsort_permutation(keys: Sequence[SortKey], row_mask: jnp.ndarray) -> jnp.n
             # whatever the previous keys made it)
             v = key.validity[perm]
             d = jnp.where(v, d, jnp.zeros((), d.dtype))
+        _built_sort("lexsort", n, d.dtype)
         idx = jnp.argsort(d, stable=True, descending=not key.ascending)
         perm = perm[idx]
         if key.validity is not None:
             v = key.validity[perm]
             # nulls_first: invalid(False) first -> ascending sort on bool
+            _built_sort("lexsort", n, v.dtype)
             idx = jnp.argsort(v, stable=True, descending=not key.nulls_first)
             perm = perm[idx]
     live = row_mask[perm]
+    _built_sort("lexsort", n, live.dtype)
     idx = jnp.argsort(~live, stable=True)  # live rows (False) first
     return perm[idx]
 
 
 def compaction_permutation(row_mask: jnp.ndarray) -> jnp.ndarray:
     """Permutation moving live rows to the front, preserving order."""
+    _built_sort("compaction", row_mask.shape[0], row_mask.dtype)
     return jnp.argsort(~row_mask, stable=True)
 
 
@@ -254,9 +268,9 @@ def seg_sum(data, seg, mask, num_segments: int, sorted_seg: bool = False):
         out, rung = _sum_rung(data, seg, mask, num_segments, sorted_seg)
     # trace-time event (as ops/pallas_agg._note): the rung this program
     # was BUILT from; an execution of the compiled stage records nothing
-    metrics.record("seg_sum", rung=rung, k=int(num_segments),
-                   rows=int(data.shape[0]), dtype=str(data.dtype),
-                   limbs=limbs)
+    trace.built("seg_sum", rung=rung, k=int(num_segments),
+                rows=int(data.shape[0]), dtype=str(data.dtype),
+                limbs=limbs)
     return out
 
 
@@ -484,6 +498,7 @@ def make_join_index(build_key: jnp.ndarray, build_ok: jnp.ndarray,
     cnt_table|None) device arrays."""
     sentinel = _pos_sentinel(build_key.dtype)
     masked = jnp.where(build_ok, build_key, sentinel)
+    _built_sort("join_index", masked.shape[0], masked.dtype)
     perm = jnp.argsort(masked, stable=True)
     skey = masked[perm]
     lo_t = cnt_t = None

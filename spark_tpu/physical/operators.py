@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_tpu import trace as _trace
 from spark_tpu import types as T
 from spark_tpu.columnar.batch import Batch, BatchData, ColumnData
 from spark_tpu.expr import compiler as C
@@ -1479,7 +1480,17 @@ class JoinExec(PhysicalPlan):
     def _indexed_ranges(self, build_key, build_ok, probe_key, probe_ok,
                         child_pipes: List[Pipe], want: str):
         """Join ranges via the cached index when one with the right
-        orientation is bound; the live build_join_ranges otherwise."""
+        orientation is bound; the live build_join_ranges otherwise.
+        Records the ``join`` build event: the rung this traced join was
+        BUILT from (``table``: dense lo / cnt lookup; ``index``: cached
+        perm + sorted key, searched; ``live``: sort + search inside the
+        program), once per trace and never per execution."""
+
+        def built(rung: str) -> None:
+            _trace.built("join", rung=rung, how=self.how, orient=want,
+                         build_rows=int(build_key.shape[0]),
+                         probe_cap=int(probe_key.shape[0]))
+
         if self.index_scan is not None and len(child_pipes) > 2 \
                 and self.index_orient == want:
             ipipe = child_pipes[2]
@@ -1498,8 +1509,10 @@ class JoinExec(PhysicalPlan):
                     tpipe = child_pipes[3]
                     lo_t = tpipe.cols["lo"].data
                     cnt_t = tpipe.cols["cnt"].data
+                built("index" if lo_t is None else "table")
                 return K.ranges_from_index(perm, skey, lo_t, cnt_t,
                                            probe_key, probe_ok)
+        built("live")
         return K.build_join_ranges(build_key, build_ok,
                                    probe_key, probe_ok)
 

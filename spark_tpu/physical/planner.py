@@ -236,7 +236,8 @@ def _run_fused(plan: P.PhysicalPlan) -> Batch:
                 if isinstance(p, _ScanSlot):
                     return P.Pipe.from_batch_data(p.scan_schema, next(it))
                 pipes = [go(c) for c in p.children()]
-                return p.trace(pipes)
+                with trace.operator_scope(p):
+                    return p.trace(pipes)
 
             batch = go(skeleton).to_batch()
             schema_box["schema"] = batch.schema

@@ -52,6 +52,7 @@ import time
 from contextlib import contextmanager
 from typing import Any, Iterator, NamedTuple, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from spark_tpu import conf as CF
@@ -115,6 +116,36 @@ SPAN_NAMES = frozenset({
     "slo.admit",            # SLO feasibility check at submit time
     "slo.observe",          # fold a finished query into the SLO model
 })
+
+
+#: kinds of the build events: one per program piece BUILT (inside the
+#: trace of a stage, or eagerly in a blocking run), never one per
+#: execution of a compiled stage. lint_invariants rule 6 holds every
+#: ``trace.built("<kind>", ...)`` literal to this set.
+BUILD_EVENTS = frozenset({
+    "seg_sum",   # kernels.seg_sum: rung (reduce/masked/cumsum/scatter),
+                 # k, rows, dtype, limbs
+    "join",      # JoinExec.trace: rung (table/index/live), how,
+                 # orientation, build rows, probe capacity
+    "sort",      # kernels: one per XLA sort built; site, rows, dtype
+})
+
+#: prefix of the ``jax.named_scope`` round each operator's ``trace()``
+#: in a fused stage: device operations carry ``spark.<Operator>`` in
+#: their ``op_name`` (lowered text, HLO metadata, the profiler's trace)
+SCOPE_PREFIX = "spark."
+
+
+def built(kind: str, **fields: Any) -> None:
+    """Record a build event (``BUILD_EVENTS``) in the metrics ring."""
+    metrics.record(kind, **fields)
+
+
+def operator_scope(plan: Any):
+    """``jax.named_scope("spark.<Operator>")`` for ``plan.trace()``.
+    Names only: the compiled program and its cache keys are the same
+    with and without it."""
+    return jax.named_scope(SCOPE_PREFIX + type(plan).__name__)
 
 
 class SpanContext(NamedTuple):

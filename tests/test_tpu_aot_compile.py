@@ -106,17 +106,12 @@ def test_flagship_fused_stage_compiles_for_v5e(one_chip,
 
 
 Q1_ROWS = 6_001_664  # lineitem at SF1 (6,000,647) in its 1,024-row bucket
+Q1_ROWS_SF10 = 59_990_016  # at SF10 (59,989,771): benchmark tpch_sf10_q1
 
 
-def test_q1_fused_stage_has_no_group_slot_scatter_on_v5e(
-        one_chip, as_the_session_runs, spark, monkeypatch):
+def _q1_stage_at(rows, sliver_sf, one_chip, spark, monkeypatch):
     """TPC-H Q1's fused stage, planned by the session on a sliver of
-    data and compiled at SF1's row count: its six group slots (3 x 2
-    dictionary codes) are filled by masked reductions. The scatter-adds
-    that took 8.6 s an execution on the chip (PERF.md, PR 27) showed in
-    the optimised HLO as scatters with an f32[6] result."""
-    import re
-
+    data and compiled for the described chip at ``rows`` rows."""
     import spark_tpu.compile as compile_pkg
     from spark_tpu.tpch.gen import generate_tables, register_views
     from spark_tpu.tpch.queries import QUERIES
@@ -130,19 +125,49 @@ def test_q1_fused_stage_has_no_group_slot_scatter_on_v5e(
 
     monkeypatch.setattr(compile_pkg, "build_stage_callable", capture)
     # an SF no other test uses: the stage is new to the process's cache
-    register_views(spark, generate_tables(0.0027, seed=27))
+    register_views(spark, generate_tables(sliver_sf, seed=27))
     assert len(spark.sql(QUERIES[1]).collect()) == 4
     (stage,) = [s for s in stages if "Aggregate" in s[0].tree_string()]
     _, trace_fn, example_args = stage
     cap = max(a.shape[0] for a in jax.tree.leaves(example_args) if a.ndim)
 
-    def at_sf1(a):
-        shape = ((Q1_ROWS,) + a.shape[1:]
+    def at_rows(a):
+        shape = ((rows,) + a.shape[1:]
                  if a.ndim and a.shape[0] == cap else a.shape)
         return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
 
-    _, text = _compile(trace_fn, jax.tree.map(at_sf1, example_args))
-    assert f"[{Q1_ROWS}]" in text
+    compiled, text = _compile(trace_fn, jax.tree.map(at_rows, example_args))
+    assert f"[{rows}]" in text
+    return compiled, text
+
+
+def test_q1_fused_stage_has_no_group_slot_scatter_on_v5e(
+        one_chip, as_the_session_runs, spark, monkeypatch):
+    """At SF1's row count the six group slots (3 x 2 dictionary codes)
+    are filled by masked reductions. The scatter-adds that took 8.6 s an
+    execution on the chip (PERF.md, PR 27) showed in the optimised HLO as
+    scatters with an f32[6] result."""
+    import re
+
+    _, text = _q1_stage_at(Q1_ROWS, 0.0027, one_chip, spark, monkeypatch)
     scatters = [line for line in text.splitlines()
                 if "scatter" in line and re.search(r"f(32|64)\[6\]", line)]
     assert not scatters, scatters[:3]
+
+
+def test_q1_fused_stage_fits_one_v5e_at_sf10(
+        one_chip, as_the_session_runs, spark, monkeypatch):
+    """The benchmark's tpch_sf10_q1 must run resident: the stage's
+    arguments, temporaries and outputs at 59,990,016 rows fit the chip's
+    16 GB by the compiler's count (it counts this program, not what else
+    the process keeps on the device)."""
+    compiled, _ = _q1_stage_at(Q1_ROWS_SF10, 0.0028, one_chip, spark,
+                               monkeypatch)
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes)
+    print(f"q1 at {Q1_ROWS_SF10} rows: arguments {m.argument_size_in_bytes}"
+          f" temporaries {m.temp_size_in_bytes} outputs "
+          f"{m.output_size_in_bytes}")
+    assert 2e9 < m.argument_size_in_bytes
+    assert total < 16e9

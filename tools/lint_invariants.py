@@ -19,7 +19,9 @@ Seven rules the engine relies on but Python cannot enforce:
    ``trace.span("<name>", ...)`` must be declared in
    ``spark_tpu.trace.SPAN_NAMES`` (same discipline as conf keys and
    fault points); an undeclared span name fragments the waterfall and
-   the host/device attribution that key off the registry.
+   the host/device attribution that key off the registry. Likewise
+   every ``trace.built("<kind>", ...)`` literal (the build events
+   ``seg_sum`` / ``join`` / ``sort``) against ``trace.BUILD_EVENTS``.
 
 3. **fingerprint-purity** — functions on the structural-fingerprint
    path (compile/store.py and planner._stable_adaptive_snapshot) must
@@ -218,30 +220,34 @@ def _check_span_names(tree: ast.AST, rel: str,
                       out: List[Finding]) -> None:
     """Every literal span name opened via ``trace.span("<name>", ...)``
     (or a bare imported ``span("<name>", ...)``) must be declared in
-    the central ``spark_tpu.trace.SPAN_NAMES`` registry."""
+    the central ``spark_tpu.trace.SPAN_NAMES`` registry, and every
+    ``trace.built("<kind>", ...)`` kind in ``trace.BUILD_EVENTS``."""
     from spark_tpu import trace
 
-    valid: Set[str] = set(trace.SPAN_NAMES)
+    registries = {"span": ("SPAN_NAMES", set(trace.SPAN_NAMES)),
+                  "built": ("BUILD_EVENTS", set(trace.BUILD_EVENTS))}
     for node in ast.walk(tree):
         if not (isinstance(node, ast.Call) and node.args):
             continue
         fn = node.func
         if isinstance(fn, ast.Attribute):
             base = fn.value
-            if not (fn.attr == "span" and isinstance(base, ast.Name)
+            if not (fn.attr in registries and isinstance(base, ast.Name)
                     and base.id in ("trace", "_trace")):
                 continue
+            registry, valid = registries[fn.attr]
         elif isinstance(fn, ast.Name) and fn.id == "span":
-            pass
+            registry, valid = registries["span"]
         else:
             continue
         name = _const_str(node.args[0])
         if name is not None and name not in valid:
             out.append(Finding(
                 "span-names", rel, node.lineno,
-                f"span name {name!r} is not declared in "
-                "spark_tpu.trace.SPAN_NAMES — register it so the "
-                "waterfall/attribution rollups see it"))
+                f"name {name!r} is not declared in "
+                f"spark_tpu.trace.{registry} — register it so the "
+                "waterfall/attribution rollups and the benchmark's "
+                "readers see it"))
 
 
 # ---- rule 7: bounded retry loops draw from the unified budget ---------------
