@@ -148,18 +148,31 @@ def group_ids_from_sorted(
 
 # ---- segment aggregation ----------------------------------------------------
 #
-# TPU reality check (v5e, one grouped sum over 6,001,664 rows inside a
-# jit with x64 on; tools/probe_seg_sum.py, chip run of PR 27): XLA
+# TPU reality check (v5e, inside a jit with x64 on; tools/probe_seg_sum.py,
+# chip runs of PR 27 and PR 29). One grouped sum over 6,001,664 rows: XLA
 # scatter-add (jax.ops.segment_sum) costs 360 ms on an int64 column and
 # 1,310-1,340 ms on the three emulated-f64 limbs of one (K = 6 ... 200; 730
-# and 2,370 ms at K = 100,000), whatever K is: it pays per row. A dense
-# masked int64 reduction costs 0.9 ms at K = 6 and 2.4 ms at K = 64 (five
-# of them over one (seg, mask) 2.4 and 10 ms), the same through f64 limbs
-# 2.8 and 19.5 ms; cumsum + searchsorted over sorted int64 ids 8 ms at
-# K = 200 and 75 ms at K = 100,000. Strategy:
+# and 2,370 ms at K = 100,000), whatever K is: it pays per row. cumsum +
+# searchsorted over sorted int64 ids 8 ms at K = 200 and 75 ms at
+# K = 100,000. A dense masked reduction pays per PASS over its column, and
+# how many it makes is for this file to say, not the compiler: written as
+# one reduction a slot, K minima of an f32 or int32 column are merged into
+# one fusion, but a 64-bit reduction is already variadic (a u32 pair under
+# the emulation) and stays one fusion a slot, each a read of the column at
+# HBM speed — 30 of them were 24 of q1's 40 ms at SF10. Written as one
+# variadic reduction for G slots, an int64 sum over 59,990,016 rows reads
+# (ms, the split of column and ids into u32 pairs, 5.8, included):
+#   K = 6    six passes 13.5   one pass 7.0
+#   K = 16   sixteen    26.2   G = 8: 8.4    G = 16: 8.3 (compile 5.0 s)
+#   K = 64   sixty-four 87.2   G = 8: 16.1   G = 16: 17.2   G = 32: 20.7
+#                              (compile 2.7 s / 6.4 s / 14.7 s)
+# and all 64 slots at once do not compile (the operands are materialised:
+# 28.66 GB). Past eight int64 slots a pass the K selects a row are the
+# cost, not the bytes. COUNT reads the same (K = 64: 43.8 -> 10.2); f32
+# MIN at K = 64: 8.9 as the compiler merged it, 7.5 at G = 16. Strategy:
 #   - K == 1: plain reduction
-#   - K small (<= _MASKED_SEG_LIMIT): K masked dense reductions (XLA fuses
-#     the data reads; cost is K passes of pure bandwidth)
+#   - K small (<= _MASKED_SEG_LIMIT): dense masked reductions, G slots to
+#     a pass: ceil(K / G) reads of the column
 #   - monotone seg ids (sort-based aggregation, where rows are already
 #     sorted by key): inclusive cumsum + searchsorted segment boundaries
 #   - otherwise: scatter-add fallback
@@ -167,13 +180,37 @@ def group_ids_from_sorted(
 # (TungstenAggregationIterator.scala:82 switchToSortBasedAggregation).
 
 _MASKED_SEG_LIMIT = 64
+# u32 accumulators a pass of the masked rung carries: G = 16 slots of a
+# 32-bit column, 8 of an int64 or f64 one (a u32 pair each). Set from the
+# probe above: the fastest that compiles in a few seconds.
+_MASKED_PASS_ACCUMULATORS = 16
 
 
-def _masked_reduce(data, seg, mask, num_segments: int, red, init):
+def _slots_a_pass(dtype) -> int:
+    """G: the group slots one pass over a column of ``dtype`` fills."""
+    words = max(1, jnp.dtype(dtype).itemsize // 4)
+    return max(1, _MASKED_PASS_ACCUMULATORS // words)
+
+
+def _masked_passes(num_segments: int, dtype) -> int:
+    return -(-num_segments // _slots_a_pass(dtype))
+
+
+def _masked_reduce(data, seg, mask, num_segments: int, combine, init):
+    """One variadic reduction per G slots: every slot's selected operand
+    ``where(mask & (seg == k), data, init)`` is combined in the same pass
+    over ``data``. For exact sums, counts, min and max, whose results
+    do not depend on the order of combination."""
+    init = jnp.asarray(init, data.dtype)
+    g = _slots_a_pass(data.dtype)
     cols = []
-    for k in range(num_segments):
-        sel = mask & (seg == k)
-        cols.append(red(jnp.where(sel, data, init)))
+    for lo in range(0, num_segments, g):
+        ks = range(lo, min(lo + g, num_segments))
+        cols += jax.lax.reduce(
+            tuple(jnp.where(mask & (seg == k), data, init) for k in ks),
+            (init,) * len(ks),
+            lambda a, b: tuple(combine(x, y) for x, y in zip(a, b)),
+            dimensions=(0,))
     return jnp.stack(cols)
 
 
@@ -233,7 +270,7 @@ def _sum_rung(data, seg, mask, num_segments: int, sorted_seg: bool):
         return (jax.ops.segment_sum(masked, seg, num_segments=num_segments),
                 "scatter")
     if num_segments <= _MASKED_SEG_LIMIT:
-        return (_masked_reduce(data, seg, mask, num_segments, jnp.sum, zero),
+        return (_masked_reduce(data, seg, mask, num_segments, jnp.add, zero),
                 "masked")
     if sorted_seg:
         return _sorted_seg_sum(masked, seg, num_segments), "cumsum"
@@ -270,7 +307,9 @@ def seg_sum(data, seg, mask, num_segments: int, sorted_seg: bool = False):
     # was BUILT from; an execution of the compiled stage records nothing
     trace.built("seg_sum", rung=rung, k=int(num_segments),
                 rows=int(data.shape[0]), dtype=str(data.dtype),
-                limbs=limbs)
+                limbs=limbs,
+                passes=(_masked_passes(num_segments, data.dtype)
+                        if rung == "masked" else None))
     return out
 
 
@@ -279,7 +318,7 @@ def seg_count(seg, mask, num_segments: int, sorted_seg: bool = False):
     if num_segments == 1:
         return jnp.sum(ones)[None]
     if num_segments <= _MASKED_SEG_LIMIT:
-        return _masked_reduce(ones, seg, mask, num_segments, jnp.sum,
+        return _masked_reduce(ones, seg, mask, num_segments, jnp.add,
                               jnp.zeros((), jnp.int64))
     if not sorted_seg:
         from spark_tpu.ops import maybe_pallas_seg_count
@@ -298,7 +337,7 @@ def seg_min(data, seg, mask, num_segments: int, sorted_seg: bool = False):
     if num_segments == 1:
         return jnp.min(masked)[None]
     if num_segments <= _MASKED_SEG_LIMIT:
-        return _masked_reduce(data, seg, mask, num_segments, jnp.min, big)
+        return _masked_reduce(data, seg, mask, num_segments, jnp.minimum, big)
     if not sorted_seg:
         # 64 < K <= 1024, f32, TPU: the one-pass Pallas streaming
         # reduction (ops/pallas_agg.py)
@@ -318,7 +357,8 @@ def seg_max(data, seg, mask, num_segments: int, sorted_seg: bool = False):
     if num_segments == 1:
         return jnp.max(masked)[None]
     if num_segments <= _MASKED_SEG_LIMIT:
-        return _masked_reduce(data, seg, mask, num_segments, jnp.max, small)
+        return _masked_reduce(data, seg, mask, num_segments, jnp.maximum,
+                              small)
     if not sorted_seg:
         from spark_tpu.ops import maybe_pallas_seg_max
 
@@ -340,8 +380,8 @@ def seg_first(data, seg, mask, num_segments: int, capacity: int,
         starts, ends = seg_bounds(seg, num_segments)
         first_pos = jnp.where(ends >= starts, first_pos, capacity)
     elif num_segments <= _MASKED_SEG_LIMIT:
-        first_pos = _masked_reduce(pos, seg, mask, num_segments, jnp.min,
-                                   jnp.asarray(capacity, pos.dtype))
+        first_pos = _masked_reduce(pos, seg, mask, num_segments,
+                                   jnp.minimum, capacity)
     else:
         first_pos = jax.ops.segment_min(pos, seg, num_segments=num_segments)
     idx = jnp.clip(first_pos, 0, capacity - 1)

@@ -3,8 +3,10 @@
 Exact sums (int64 / scaled decimals) follow the integer ladder — with
 1 < K <= 64 the dense masked reduction, never a scatter-add (PR 27: q1's
 8.6 s on the v5e were fifteen emulated-f64 scatter-adds); only user
-floats need row order. The StableHLO tripwire and the trace-time
-``seg_sum`` event stop that regressing unseen.
+floats need row order. The masked rung (sum, count, min, max) reads its
+column once for every G slots, not once a slot (PR 29: 36 passes were 24
+of q1's 40 ms at SF10). The StableHLO tripwires and the trace-time
+``seg_sum`` event stop either regressing unseen.
 """
 
 import jax
@@ -17,12 +19,6 @@ from spark_tpu.physical import kernels as K
 
 N = 3000
 KS = [2, 6, 64, 65, 200]
-
-
-def _reference(data, seg, mask, k):
-    ref = np.zeros(k, np.int64)
-    np.add.at(ref, seg[mask], data[mask])  # wraps like the device's int64
-    return ref
 
 
 def _columns(rng):
@@ -47,21 +43,60 @@ def _mask(rng, kind):
             "p70": rng.random(N) < 0.7}[kind]
 
 
-def _jitted(k, sorted_seg):
-    return jax.jit(lambda d, s, m: K.seg_sum(d, s, m, k, sorted_seg))
+OPS = {  # op -> (kernel, numpy's ufunc, what an empty slot reads)
+    "sum": (K.seg_sum, np.add, lambda dt: 0),
+    "count": (lambda d, s, m, k, sorted_seg: K.seg_count(s, m, k, sorted_seg),
+              np.add, lambda dt: 0),
+    "min": (K.seg_min, np.minimum, lambda dt: K._pos_sentinel(dt)),
+    "max": (K.seg_max, np.maximum, lambda dt: K._neg_sentinel(dt)),
+}
+
+
+def _jitted(k, sorted_seg, op="sum"):
+    return jax.jit(lambda d, s, m: OPS[op][0](d, s, m, k, sorted_seg))
+
+
+def _op_reference(op, data, seg, mask, k):
+    _, ufunc, empty = OPS[op]
+    if op == "count":
+        data = np.ones(len(seg), np.int64)
+    ref = np.full(k, empty(data.dtype), data.dtype)
+    ufunc.at(ref, seg[mask], data[mask])  # add wraps like the device's
+    return ref
 
 
 @pytest.mark.parametrize("mask_kind", ["all", "none", "p70"])
 @pytest.mark.parametrize("sorted_seg", [False, True])
 @pytest.mark.parametrize("k", KS)
-def test_int64_sum_is_bit_equal_to_numpy(rng, k, sorted_seg, mask_kind):
+@pytest.mark.parametrize("op", list(OPS))
+def test_int64_reduction_is_bit_equal_to_numpy(rng, op, k, sorted_seg,
+                                               mask_kind):
     seg, mask = _seg(rng, k, sorted_seg), _mask(rng, mask_kind)
-    fn = _jitted(k, sorted_seg)
+    fn = _jitted(k, sorted_seg, op)
     for name, data in _columns(rng).items():
         got = np.asarray(fn(jnp.asarray(data), jnp.asarray(seg),
                             jnp.asarray(mask)))
         assert got.dtype == np.int64
-        assert np.array_equal(got, _reference(data, seg, mask, k)), name
+        # an empty slot's MIN / MAX is NULL by its count: on the sorted
+        # rungs (K > 64) its payload is whatever row the clip lands on
+        live = (np.bincount(seg[mask], minlength=k) > 0
+                if op in ("min", "max") else slice(None))
+        ref = _op_reference(op, data, seg, mask, k)
+        assert np.array_equal(got[live], ref[live]), name
+
+
+@pytest.mark.parametrize("mask_kind", ["all", "none", "p70"])
+@pytest.mark.parametrize("k", [2, 6, 64])
+@pytest.mark.parametrize("op", ["min", "max"])
+def test_f32_min_max_are_bit_equal_to_numpy(rng, op, k, mask_kind):
+    """Every dtype takes the masked rung for MIN / MAX: user floats too
+    (an order of combination changes no minimum)."""
+    seg, mask = _seg(rng, k, False), _mask(rng, mask_kind)
+    data = rng.standard_normal(N).astype(np.float32)
+    got = np.asarray(_jitted(k, False, op)(
+        jnp.asarray(data), jnp.asarray(seg), jnp.asarray(mask)))
+    assert got.dtype == np.float32
+    assert got.tobytes() == _op_reference(op, data, seg, mask, k).tobytes()
 
 
 @pytest.mark.parametrize("sorted_seg", [False, True])
@@ -87,6 +122,7 @@ def test_static_and_compacted_layouts_give_the_same_bits(rng, k, sorted_seg):
 
 CASES = [  # (dtype, K, the rung it must be built from)
     pytest.param(jnp.int64, 6, "masked", id="int64-k6"),
+    pytest.param(jnp.int64, 64, "masked", id="int64-k64"),
     pytest.param(jnp.int64, 65, "scatter", id="int64-k65"),
     pytest.param(jnp.float64, 6, "scatter", id="float64-k6"),
 ]
@@ -106,6 +142,34 @@ def test_stablehlo_scatter_tripwire(dtype, k, rung):
     assert ("scatter" in text) == (rung == "scatter")
 
 
+def _pass_cases():
+    """(op, dtype, K): K around the slots a pass fills for the dtype."""
+    for op in OPS:
+        for dtype in ((jnp.int64, jnp.float32) if op in ("min", "max")
+                      else (jnp.int64,)):
+            g = K._slots_a_pass(dtype)
+            for k in sorted({2, 6, g, g + 1, 64}):
+                yield pytest.param(op, dtype, k,
+                                   id=f"{op}-{np.dtype(dtype).name}-k{k}")
+
+
+@pytest.mark.parametrize("op,dtype,k", _pass_cases())
+def test_stablehlo_pass_tripwire(op, dtype, k):
+    """The masked rung lowers to one (variadic) reduction for every G
+    slots: ceil(K / G) reads of the column, where one reduction a slot
+    read it K times (the chip's compiler does not merge them: PR 29)."""
+    text = _jitted(k, False, op).lower(*_shapes(dtype)).as_text()
+    assert text.count("stablehlo.reduce") == K._masked_passes(k, dtype)
+    assert "scatter" not in text
+
+
+@pytest.mark.parametrize("op", list(OPS))
+def test_global_aggregate_is_one_plain_reduction(op):
+    text = _jitted(1, False, op).lower(*_shapes(jnp.int32)).as_text()
+    assert text.count("stablehlo.reduce") == 1
+    assert "scatter" not in text
+
+
 def _events():
     return [e for e in metrics.recent(4096) if e["kind"] == "seg_sum"]
 
@@ -121,6 +185,8 @@ def test_trace_time_event_names_the_rung(rng, dtype, k, rung):
     assert (ev["rung"], ev["k"], ev["rows"]) == (rung, k, N)
     assert ev["dtype"] == np.dtype(dtype).name
     assert ev["limbs"] == (dtype == jnp.int64 and k > 64)
+    assert ev["passes"] == (K._masked_passes(k, dtype)
+                            if rung == "masked" else None)
     fn(*args)  # the compiled program runs: nothing is recorded
     assert len(_events()) == 1
 
@@ -131,7 +197,8 @@ def test_global_sum_keeps_the_plain_reduction(rng):
     seg = np.zeros(N, np.int64)
     got = _jitted(1, False)(jnp.asarray(data), jnp.asarray(seg),
                             jnp.asarray(mask))
-    assert np.array_equal(np.asarray(got), _reference(data, seg, mask, 1))
+    assert np.array_equal(np.asarray(got),
+                          _op_reference("sum", data, seg, mask, 1))
     (ev,) = _events()
     assert (ev["rung"], ev["limbs"]) == ("reduce", True)
 

@@ -14,7 +14,9 @@ process may load the TPU's library, and every xdist worker imports every
 test file.
 """
 
+import contextlib
 import os
+import re
 
 import pytest
 
@@ -42,8 +44,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def as_the_session_runs():
+@contextlib.contextmanager
+def _session_settings():
     """x64 on; persistent compile cache off (a compile for a described
     chip is written to it but can never be read back without one)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -52,9 +54,17 @@ def as_the_session_runs():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+@pytest.fixture
+def as_the_session_runs():
+    with _session_settings():
+        yield
 
 
 def _columns(one_chip):
@@ -147,22 +157,33 @@ def test_q1_fused_stage_has_no_group_slot_scatter_on_v5e(
     are filled by masked reductions. The scatter-adds that took 8.6 s an
     execution on the chip (PERF.md, PR 27) showed in the optimised HLO as
     scatters with an f32[6] result."""
-    import re
-
     _, text = _q1_stage_at(Q1_ROWS, 0.0027, one_chip, spark, monkeypatch)
     scatters = [line for line in text.splitlines()
                 if "scatter" in line and re.search(r"f(32|64)\[6\]", line)]
     assert not scatters, scatters[:3]
 
 
-def test_q1_fused_stage_fits_one_v5e_at_sf10(
-        one_chip, as_the_session_runs, spark, monkeypatch):
+def _reduce_fusions(text):
+    """The optimised program's grouped reductions: each such fusion is
+    one pass over its operands (one to an instruction, at its `= `)."""
+    return re.findall(r"^\s*%?(\S*reduce\S*fusion\S*) = ", text, re.M)
+
+
+@pytest.fixture(scope="module")
+def q1_stage_at_sf10(one_chip, spark):
+    """Compiled once for the tests that read it: the session's cache
+    holds the stage after the first plan, so a second capture finds none."""
+    with _session_settings(), pytest.MonkeyPatch.context() as monkeypatch:
+        return _q1_stage_at(Q1_ROWS_SF10, 0.0028, one_chip, spark,
+                            monkeypatch)
+
+
+def test_q1_fused_stage_fits_one_v5e_at_sf10(q1_stage_at_sf10):
     """The benchmark's tpch_sf10_q1 must run resident: the stage's
     arguments, temporaries and outputs at 59,990,016 rows fit the chip's
     16 GB by the compiler's count (it counts this program, not what else
     the process keeps on the device)."""
-    compiled, _ = _q1_stage_at(Q1_ROWS_SF10, 0.0028, one_chip, spark,
-                               monkeypatch)
+    compiled, _ = q1_stage_at_sf10
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.temp_size_in_bytes
              + m.output_size_in_bytes)
@@ -171,3 +192,31 @@ def test_q1_fused_stage_fits_one_v5e_at_sf10(
           f"{m.output_size_in_bytes}")
     assert 2e9 < m.argument_size_in_bytes
     assert total < 16e9
+
+
+def test_q1_fused_stage_reads_each_column_once_at_sf10(q1_stage_at_sf10):
+    """Six group slots, five decimal sums and the counts: one pass a
+    column and count set, where one reduction a slot made 36 (PR 29; 24
+    of the chip's 40 ms an execution were those passes)."""
+    _, text = q1_stage_at_sf10
+    fusions = _reduce_fusions(text)
+    print(f"q1 at {Q1_ROWS_SF10} rows: {len(fusions)} reduce fusions",
+          sorted(fusions))
+    assert 1 <= len(fusions) <= 8, fusions
+
+
+def test_int64_seg_sum_with_64_slots_compiles_at_sf10(one_chip,
+                                                      as_the_session_runs):
+    """The masked rung's largest shape: 64 int64 slots in ONE variadic
+    reduction do not compile at this capacity (the compiler materialises
+    the operands: 28.66 GB of the chip's 15.75), so the slots go in
+    passes of G, each one fusion."""
+    from spark_tpu.physical import kernels as K
+
+    shapes = [jax.ShapeDtypeStruct((Q1_ROWS_SF10,), dt, sharding=one_chip)
+              for dt in (jnp.int64, jnp.int64, jnp.bool_)]
+    compiled, text = _compile(lambda d, s, m: K.seg_sum(d, s, m, 64), *shapes)
+    assert len(_reduce_fusions(text)) == K._masked_passes(64, jnp.int64)
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes) < 16e9

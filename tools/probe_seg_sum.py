@@ -1,10 +1,15 @@
-"""Chip probe behind kernels.seg_sum's ladder (PR 27; PERF.md section 6).
+"""Chip probe behind kernels.seg_sum's ladder (PR 27, PR 29; PERF.md
+section 6).
 
 What one exact (int64 / scaled-decimal) grouped sum costs on the attached
-chip at q1's shape, by rung, each inside a jitted function with x64 on:
+chip at q1's shape, by rung, each inside a jitted function with x64 on,
+and what the masked rung costs by the number of passes it makes over its
+column (K reductions of one slot each against ceil(K / G) variadic
+reductions of G slots; int64 sum, count, f32 min; SF1's and SF10's rows):
 
-  chiprun -- python tools/probe_seg_sum.py            # all of it, ~5 min
+  chiprun -- python tools/probe_seg_sum.py            # the rungs, ~5 min
   chiprun -- python tools/probe_seg_sum.py --small-k  # K <= 64 only
+  chiprun --timeout 1800 -- python tools/probe_seg_sum.py --passes  # ~6 min
 
 Every variant is written out from primitives here, so the probe reads the
 same after the engine's own choice changes. Each line of output is one
@@ -33,6 +38,7 @@ import numpy as np  # noqa: E402
 from spark_tpu.physical import kernels as K  # noqa: E402
 
 ROWS = 6_001_664  # lineitem's 6,000,647 rows in the engine's 1,024 bucket
+ROWS_SF10 = 59_990_016  # SF10's 59,989,771
 
 
 def _limbs(data, red):
@@ -50,29 +56,124 @@ def _scatter(x, seg, mask, k):
     return jax.ops.segment_sum(masked, seg, num_segments=k)
 
 
+def _k_passes(x, seg, mask, k, red, init):
+    """K reductions of one slot each: K passes over ``x``."""
+    fill = jnp.asarray(init, x.dtype)
+    return jnp.stack([red(jnp.where(mask & (seg == j), x, fill))
+                      for j in range(k)])
+
+
 def _masked(x, seg, mask, k):
-    return K._masked_reduce(x, seg, mask, k, jnp.sum, jnp.zeros((), x.dtype))
+    return _k_passes(x, seg, mask, k, jnp.sum, 0)
 
 
-def _cumsum(x, seg, mask, k):
-    masked = jnp.where(mask, x, jnp.zeros((), x.dtype))
-    return K._sorted_seg_sum(masked, seg, k)
+def _one_pass(x, seg, mask, k, g, combine, init):
+    """ceil(k / g) variadic reductions of up to g slots each."""
+    init = jnp.asarray(init, x.dtype)
+    cols = []
+    for lo in range(0, k, g):
+        js = range(lo, min(lo + g, k))
+        cols += jax.lax.reduce(
+            tuple(jnp.where(mask & (seg == j), x, init) for j in js),
+            (init,) * len(js),
+            lambda a, b: tuple(combine(p, q) for p, q in zip(a, b)),
+            dimensions=(0,))
+    return jnp.stack(cols)
 
 
-def variants(k, sorted_seg):
-    """name -> fn(data, seg, mask): each rung on the int64 column itself
-    and on its three f64 limbs."""
-    rungs = {"scatter": _scatter}
-    if k <= K._MASKED_SEG_LIMIT:
-        rungs["masked"] = _masked
-    if sorted_seg:
-        rungs["cumsum"] = _cumsum
-    out = {}
-    for name, red in rungs.items():
-        out[f"limb_{name}"] = lambda d, s, m, red=red: _limbs(
-            d, lambda x: red(x, s, m, k))
-        out[f"int64_{name}"] = lambda d, s, m, red=red: red(d, s, m, k)
-    return out
+# op -> (column kind, combine, init, the K-pass form's reduction, numpy's)
+PASS_OPS = {
+    "sum_int64": ("int64", jnp.add, 0, jnp.sum, np.add),
+    "count": ("ones", jnp.add, 0, jnp.sum, np.add),
+    "min_f32": ("f32", jnp.minimum, np.inf, jnp.min, np.minimum),
+}
+PASS_KS = (6, 16, 64)
+PASS_GS = (2, 4, 8, 16, 32)
+
+
+def _pass_variant(op, k, g):
+    """fn(data, seg, mask); g == 0 is one reduction a slot (K passes)."""
+    kind, combine, init, red, _ = PASS_OPS[op]
+
+    def fn(x, seg, mask):
+        if kind == "ones":  # as kernels.seg_count: the mask as int64
+            x = mask.astype(jnp.int64)
+        if g:
+            return _one_pass(x, seg, mask, k, g, combine, init)
+        return _k_passes(x, seg, mask, k, red, init)
+
+    return fn
+
+
+def _pass_reference(op, x, seg, mask, k):
+    kind, _, init, _, at = PASS_OPS[op]
+    if kind == "ones":
+        x = np.ones(len(seg), np.int64)
+    ref = np.full(k, init, x.dtype)
+    at.at(ref, seg[mask], x[mask])
+    return ref
+
+
+def _measure(line, fn, args, reps, refs):
+    """Time one variant and print its line; True if it failed (the
+    compiler's refusal is a line too) or differs from ``refs``."""
+    try:
+        ms, piped, comp, out = _time(jax.jit(fn), args, reps)
+    except Exception as e:
+        line["error"] = f"{type(e).__name__}: {e}"[:300]
+        equal = False
+    else:
+        equal = all(np.array_equal(np.asarray(o), r)
+                    for o, r in zip(out, refs))
+        line.update(ms=round(ms, 3), pipelined_ms=round(piped, 3),
+                    compile_s=round(comp, 2), bit_equal=equal)
+    print(json.dumps(line), flush=True)
+    return not equal
+
+
+def probe_passes(rows_list, reps=20):
+    """The masked rung by pass count. Returns the number of wrong or
+    failed variants."""
+    bad = 0
+    for n in rows_list:
+        rng = np.random.default_rng(29)
+        cols = [rng.integers(-(1 << 40), 1 << 40, n, dtype=np.int64)
+                for _ in range(5)]
+        f32 = rng.standard_normal(n).astype(np.float32)
+        mask = rng.random(n) < 0.98
+        d_cols = [jnp.asarray(c) for c in cols]
+        d_f32, d_mask = jnp.asarray(f32), jnp.asarray(mask)
+        for k in PASS_KS:
+            seg = rng.integers(0, k, n, dtype=np.int64)
+            d_seg = jnp.asarray(seg)
+            for op, (kind, *_rest) in PASS_OPS.items():
+                host, dev = ((f32, d_f32) if kind == "f32"
+                             else (cols[0], d_cols[0]))
+                ref = _pass_reference(op, host, seg, mask, k)
+                for g in [0] + sorted({min(g, k) for g in PASS_GS}):
+                    one = _pass_variant(op, k, g)
+                    bad += _measure(
+                        {"rows": n, "op": op, "k": k, "g": g,
+                         "passes": -(-k // g) if g else k},
+                        lambda x, s, m, one=one: [one(x, s, m)],
+                        (dev, d_seg, d_mask), reps, [ref])
+            if k != 6:
+                continue
+            # q1's stage: five sums and a count over one (seg, mask)
+            refs = ([_pass_reference("sum_int64", c, seg, mask, k)
+                     for c in cols]
+                    + [_pass_reference("count", cols[0], seg, mask, k)])
+            for g in (0, k):
+                def stage(cs, s, m, g=g):
+                    return ([_pass_variant("sum_int64", k, g)(c, s, m)
+                             for c in cs]
+                            + [_pass_variant("count", k, g)(cs[0], s, m)])
+
+                bad += _measure(
+                    {"rows": n, "op": "q1_stage", "k": k, "g": g,
+                     "passes": 6 * (1 if g else k)},
+                    stage, (d_cols, d_seg, d_mask), reps, refs)
+    return bad
 
 
 def _time(fn, args, reps):
@@ -102,7 +203,10 @@ def _reference(data, seg, mask, k):
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--small-k", action="store_true")
-    ap.add_argument("--rows", type=int, default=ROWS)
+    ap.add_argument("--passes", action="store_true",
+                    help="only the masked rung by pass count, at SF1's "
+                    "and SF10's rows (or at --rows)")
+    ap.add_argument("--rows", type=int)
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearsal only: times mean nothing")
     a = ap.parse_args()
@@ -110,9 +214,13 @@ def main() -> int:
     if dev.platform != "tpu" and not a.allow_cpu:
         print("probe_seg_sum: no TPU", file=sys.stderr)
         return 2
-    print(json.dumps({"device": dev.device_kind, "rows": a.rows}))
+    if a.passes:
+        rows_list = [a.rows] if a.rows else [ROWS, ROWS_SF10]
+        print(json.dumps({"device": dev.device_kind, "rows": rows_list}))
+        return 1 if probe_passes(rows_list) else 0
+    n = a.rows or ROWS
+    print(json.dumps({"device": dev.device_kind, "rows": n}))
     rng = np.random.default_rng(27)
-    n = a.rows
     cols = [rng.integers(-(1 << 40), 1 << 40, n, dtype=np.int64)
             for _ in range(5)]
     mask = rng.random(n) < 0.98
@@ -150,7 +258,7 @@ def main() -> int:
 
             def five(cs, s, m, one=one):
                 return ([one(c, s, m) for c in cs]
-                        + [K.seg_count(s, m, k)])
+                        + [_masked(m.astype(jnp.int64), s, m, k)])
 
             ms, piped, comp, out = _time(jax.jit(five),
                                          (d_cols, d_seg, d_mask), 20)
