@@ -329,15 +329,11 @@ class DataFrame:
             with trace.span("query.analysis"):
                 maybe_gate(self._plan, self._session.conf)
         metrics.query_start(self._plan.node_string())
-        ex = getattr(self._session, "mesh_executor", None) \
-            if self._session is not None else None
+        from spark_tpu.physical.planner import execute_logical_on
 
         def run(plan, optimize=True):
-            if ex is not None:
-                return ex.execute_logical(plan, optimize)
-            from spark_tpu.physical.planner import execute_logical
-
-            return execute_logical(plan, optimize)
+            # no session: the one-chip planner
+            return execute_logical_on(self._session, plan, optimize)
 
         def run_full(plan):
             """Engine run with the out-of-HBM chunking decision applied

@@ -461,8 +461,7 @@ def reset_agg() -> None:
 #: programs launched (one per query that fused), exchange+consumer
 #: spans folded into them, bailouts back to staged execution (see the
 #: per-reason fusion_bailout events for the breakdown), and injected
-#: faults absorbed at fusion.decide. Shown in tracing.fusion_profile
-#: and the bench fusion phase.
+#: faults absorbed at fusion.decide. Shown in tracing.fusion_profile.
 _FUSION = {"fused_programs": 0, "fused_spans": 0, "bailouts": 0,
            "fault_fallbacks": 0}
 

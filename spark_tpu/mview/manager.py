@@ -403,12 +403,9 @@ class ViewManager:
     def _run(self, plan: L.LogicalPlan):
         """Engine for stream-view delta/merge plans — same dispatch the
         streaming runtime uses (mesh when the session has one)."""
-        ex = getattr(self._session, "mesh_executor", None)
-        if ex is not None:
-            return ex.execute_logical(plan)
-        from spark_tpu.physical.planner import execute_logical
+        from spark_tpu.physical.planner import execute_logical_on
 
-        return execute_logical(plan)
+        return execute_logical_on(self._session, plan)
 
     def _repopulate_serve(self, view: MaterializedView, batch) -> None:
         """Push the refreshed result into the serve-tier ResultCache

@@ -1,9 +1,10 @@
 """Pallas TPU kernels: one-pass segmented (grouped) reductions.
 
 The hot aggregation path in physical/kernels.py handles small group
-counts with K masked dense reductions (`_masked_reduce`) — K full passes
-over the data column from HBM. That is the right call for tiny K, but
-HBM traffic scales as K*N. This kernel makes ONE pass: the column is
+counts (K <= 64) with masked dense reductions (`_masked_reduce`): one
+variadic reduction for G slots, so `ceil(K / G)` passes over the data
+column from HBM (G = 8 slots of a 64-bit column, 16 of a 32-bit one;
+PR 29). This kernel makes ONE pass whatever K is: the column is
 streamed HBM -> VMEM in (block_rows, 128) tiles, per-group partial sums
 accumulate in a VMEM-resident (K, 128) lane-parallel accumulator, and
 the final cross-lane reduce of the tiny (K, 128) result happens in
@@ -28,7 +29,7 @@ runs them.
 Not measured on the current chip. The selection in physical/kernels.py
 (K <= 64 XLA fused masked reductions, 64 < K <= 1024 this kernel on
 TPU, else scatter/sort paths) dates from a device set-up that is gone;
-ROADMAP.md Queue A has the re-measurement.
+ROADMAP.md A8 / C12 name the probe that would settle it.
 
 Accumulator family (same tiling): Count (``maybe_pallas_seg_count`` —
 ``pallas_seg_sum`` over the mask with an exact-int epilogue) and

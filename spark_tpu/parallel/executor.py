@@ -37,6 +37,7 @@ from spark_tpu.parallel.mesh import DATA_AXIS, mesh_size
 from spark_tpu.parallel.sharded import ShardedBatch
 from spark_tpu.physical import kernels as K
 from spark_tpu.physical import operators as P
+from spark_tpu.physical import stage
 from spark_tpu.physical.operators import Pipe
 from spark_tpu.plan import logical as L
 from spark_tpu.types import Schema
@@ -174,46 +175,6 @@ def _walk_plan(plan: P.PhysicalPlan):
     yield plan
     for c in plan.children():
         yield from _walk_plan(c)
-
-
-@dataclass(eq=False)
-class _ShardSlot(P.PhysicalPlan):
-    """Leaf placeholder inside cached stage closures (mirror of
-    planner._ScanSlot): schema only, data arrives as arguments."""
-
-    scan_schema: Schema
-    traceable = True
-
-    @property
-    def schema(self):
-        return self.scan_schema
-
-
-def _collect_shard_scans(plan: P.PhysicalPlan,
-                         out: List[D.ShardScanExec]) -> None:
-    if isinstance(plan, D.ShardScanExec):
-        out.append(plan)
-        return
-    for c in plan.children():
-        _collect_shard_scans(c, out)
-
-
-def _strip_leaves(plan: P.PhysicalPlan) -> P.PhysicalPlan:
-    if isinstance(plan, D.ShardScanExec):
-        return _ShardSlot(plan.schema)
-    fields = {}
-    for f in dataclasses.fields(plan):
-        v = getattr(plan, f.name)
-        fields[f.name] = _strip_leaves(v) if isinstance(
-            v, P.PhysicalPlan) else v
-    return dataclasses.replace(plan, **fields)
-
-
-def _fully_traceable(plan: P.PhysicalPlan) -> bool:
-    if isinstance(plan, D.ShardScanExec):
-        return True
-    return (plan.traceable and not plan.has_blocking_exprs()
-            and all(_fully_traceable(c) for c in plan.children()))
 
 
 @dataclass(eq=False)
@@ -577,7 +538,7 @@ class MeshExecutor:
             plan = self._materialize_exchanges(plan)
         if isinstance(plan, D.ShardScanExec):
             return plan.sharded
-        if not _fully_traceable(plan):
+        if not stage.fully_traceable(plan, D.ShardScanExec):
             raise NotImplementedError(
                 "plan contains host-only (arrow UDF) expressions, which "
                 "the mesh executor cannot trace; run on the "
@@ -614,7 +575,7 @@ class MeshExecutor:
         the host."""
         from spark_tpu import faults, metrics
 
-        if not _fully_traceable(plan):
+        if not stage.fully_traceable(plan, D.ShardScanExec):
             return None  # both paths reject it; let staged raise
         if not any(isinstance(p, _ADAPTIVE_EXCHANGES)
                    for p in _walk_plan(plan)):
@@ -734,19 +695,9 @@ class MeshExecutor:
                         "sort_elide",
                         "producer order guarantee elides the sort")
                 return pair(p, ex)
-            fields = {}
-            changed = False
-            for f in dataclasses.fields(p):
-                v = getattr(p, f.name)
-                if isinstance(v, P.PhysicalPlan):
-                    nv = rewrite(v)
-                    changed |= nv is not v
-                    fields[f.name] = nv
-                else:
-                    fields[f.name] = v
             if isinstance(p, _ADAPTIVE_EXCHANGES):
                 spans[0] += 1  # bare exchange, kept inline
-            return dataclasses.replace(p, **fields) if changed else p
+            return p.map_children(rewrite)
 
         return rewrite(plan), spans[0]
 
@@ -818,17 +769,7 @@ class MeshExecutor:
             return dataclasses.replace(plan, child=D.ShardScanExec(sb))
         if isinstance(plan, _ADAPTIVE_EXCHANGES):
             return D.ShardScanExec(self._run_adaptive_exchange(plan))
-        fields = {}
-        changed = False
-        for f in dataclasses.fields(plan):
-            v = getattr(plan, f.name)
-            if isinstance(v, P.PhysicalPlan):
-                nv = self._materialize_exchanges(v)
-                changed |= nv is not v
-                fields[f.name] = nv
-            else:
-                fields[f.name] = v
-        return dataclasses.replace(plan, **fields) if changed else plan
+        return plan.map_children(self._materialize_exchanges)
 
     def _run_adaptive_exchange(self, ex: P.PhysicalPlan,
                                consumer=None) -> ShardedBatch:
@@ -1280,17 +1221,7 @@ class MeshExecutor:
     def _materialize_boundaries(self, plan: P.PhysicalPlan) -> P.PhysicalPlan:
         if isinstance(plan, D.DistJoinBoundary):
             return D.ShardScanExec(self._run_join(plan))
-        fields = {}
-        changed = False
-        for f in dataclasses.fields(plan):
-            v = getattr(plan, f.name)
-            if isinstance(v, P.PhysicalPlan):
-                nv = self._materialize_boundaries(v)
-                changed |= nv is not v
-                fields[f.name] = nv
-            else:
-                fields[f.name] = v
-        return dataclasses.replace(plan, **fields) if changed else plan
+        return plan.map_children(self._materialize_boundaries)
 
     def _run_stage(self, plan: P.PhysicalPlan) -> ShardedBatch:
         from spark_tpu import metrics, trace
@@ -1308,51 +1239,28 @@ class MeshExecutor:
         return sb
 
     def _run_stage_inner(self, plan: P.PhysicalPlan) -> ShardedBatch:
-        scans: List[D.ShardScanExec] = []
-        _collect_shard_scans(plan, scans)
+        scans = stage.collect_leaves(plan, D.ShardScanExec)
         key = (plan.plan_key(), self.d, self.mesh.devices.flat[0].platform)
         entry = _DIST_STAGE_CACHE.get(key)
         if entry is None:
-            schema_box: dict = {}
-            skeleton = _strip_leaves(plan)
-
-            def local_fn(leaf_datas):
-                it = iter(leaf_datas)
-
-                def go(p: P.PhysicalPlan) -> Pipe:
-                    if isinstance(p, _ShardSlot):
-                        return Pipe.from_batch_data(p.scan_schema, next(it))
-                    pipes = [go(c) for c in p.children()]
-                    with _trace.operator_scope(p):
-                        return p.trace(pipes)
-
-                batch = go(skeleton).to_batch()
-                schema_box["schema"] = batch.schema
-                return batch.data
-
-            smapped = jax.shard_map(local_fn, mesh=self.mesh,
-                                    in_specs=_SPEC, out_specs=_SPEC,
-                                    check_vma=False)
-            # cross-session executable store integration (no-op jit
-            # when the compile service is off). A plan holding fused
-            # spans keys under its own tier with the bucket-ladder
-            # parameters folded into the digest: the store never
-            # replays a fused executable across a ladder conf change,
-            # and prewarm replays fused programs as themselves
-            from spark_tpu.compile import build_stage_callable
-
+            # in the executable store a plan holding fused spans keys
+            # under its own tier with the bucket-ladder parameters
+            # folded into the digest: the store never replays a fused
+            # executable across a ladder conf change, and prewarm
+            # replays fused programs as themselves
             fused_nodes: List[D.FusedSpanExec] = []
             _collect_fused(plan, fused_nodes)
-            tier = "fused_span" if fused_nodes else "dist"
-            extra = tuple(
-                ("ladder", f.bucket, f.variants) for f in fused_nodes
-            ) or None
-            entry = (build_stage_callable(
-                tier, plan, smapped,
-                tuple(s.sharded.data for s in scans), schema_box,
-                mesh_size=self.d, platform=key[2], extra=extra,
-                devices=tuple(self.mesh.devices.flat)),
-                schema_box)
+            entry = stage.build_stage(
+                "fused_span" if fused_nodes else "dist", plan,
+                D.ShardScanExec, tuple(s.sharded.data for s in scans),
+                name="local_fn",
+                wrap=lambda fn: jax.shard_map(
+                    fn, mesh=self.mesh, in_specs=_SPEC, out_specs=_SPEC,
+                    check_vma=False),
+                mesh_size=self.d, platform=key[2],
+                extra=tuple(("ladder", f.bucket, f.variants)
+                            for f in fused_nodes) or None,
+                devices=tuple(self.mesh.devices.flat))
             _DIST_STAGE_CACHE[key] = entry
         jitted, schema_box = entry
         ctx = _trace.current()

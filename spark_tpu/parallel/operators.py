@@ -28,8 +28,7 @@ from spark_tpu.parallel import exchange as X
 from spark_tpu.parallel.sharded import ShardedBatch
 from spark_tpu.physical import kernels as K
 from spark_tpu.physical import operators as P
-from spark_tpu.physical.operators import (Pipe, _distinct_mask_cached,
-                                          rewrite_agg_outputs)
+from spark_tpu.physical.operators import Pipe, rewrite_agg_outputs
 from spark_tpu.types import Field, Schema
 
 
@@ -549,76 +548,24 @@ class DistLimitExec(P.PhysicalPlan):
 # ---- distributed aggregation ------------------------------------------------
 
 
-def _merged_agg(agg: E.AggregateExpression, env: Env, seg, mask,
-                num_segments: int, capacity: int) -> TV:
-    """One aggregate, locally reduced per segment then merged across the
-    mesh with psum/pmin/pmax — the partial->final two-phase plan
-    (reference: aggregate/AggUtils.scala:33 map-side combine + shuffled
-    merge) collapsed into a single program with an ICI collective as the
-    phase boundary."""
-    if isinstance(agg, E.Count) and agg.child is None:
-        return TV(X.psum(K.seg_count(seg, mask, num_segments)), None,
-                  T.INT64, None)
+class _MeshMerge:
+    """``P._compute_agg``'s merge on the mesh: each device's per-segment
+    partial becomes the global value through one ICI collective."""
 
-    child = agg.child  # type: ignore[attr-defined]
-    tv = C.evaluate(child, env)
-    ok = mask & tv.valid_or_true(capacity)
-    if getattr(agg, "distinct", False):
-        # Local dedup + psum is exact ONLY when equal values are
-        # co-resident; the planner guarantees it by hash-exchanging on
-        # the distinct child (MeshExecutor._plan_aggregate) before this
-        # operator runs.
-        ok = ok & _distinct_mask_cached(env, agg.child, tv, seg, ok)
-    cnt = X.psum(K.seg_count(seg, ok, num_segments))
-    # dedup keeps >= 1 head per non-empty group, so post-dedup positivity
-    # matches pre-dedup — no separate psum needed
-    any_valid = cnt > 0
+    sum = staticmethod(X.psum)
+    min = staticmethod(X.pmin)
+    max = staticmethod(X.pmax)
 
-    if isinstance(agg, E.Count):
-        return TV(cnt, None, T.INT64, None)
-    if isinstance(agg, E.Sum):
-        if isinstance(tv.dtype, T.DecimalType):
-            s = X.psum(K.seg_sum(tv.data, seg, ok, num_segments))
-            return TV(s, any_valid, P.decimal_sum_type(tv.dtype), None)
-        out_dt = T.INT64 if tv.dtype.is_integral else tv.dtype
-        data = tv.data.astype(C._jnp_dtype(out_dt))
-        s = X.psum(K.seg_sum(data, seg, ok, num_segments))
-        return TV(s, any_valid, out_dt, None)
-    if isinstance(agg, E.Avg):
-        if isinstance(tv.dtype, T.DecimalType):
-            total = X.psum(K.seg_sum(tv.data, seg, ok, num_segments))
-            data, out_dt = P.decimal_avg(total, cnt, tv.dtype)
-            return TV(data, any_valid, out_dt, None)
-        s = X.psum(K.seg_sum(tv.data.astype(jnp.float64), seg, ok,
-                             num_segments))
-        return TV(s / jnp.maximum(cnt, 1), any_valid, T.FLOAT64, None)
-    if isinstance(agg, E.Min):
-        return TV(X.pmin(K.seg_min(tv.data, seg, ok, num_segments)),
-                  any_valid, tv.dtype, tv.dictionary)
-    if isinstance(agg, E.Max):
-        return TV(X.pmax(K.seg_max(tv.data, seg, ok, num_segments)),
-                  any_valid, tv.dtype, tv.dictionary)
-    if isinstance(agg, E.StddevVariance):
-        x = tv.data.astype(jnp.float64)
-        c = cnt.astype(jnp.float64)
-        s = X.psum(K.seg_sum(x, seg, ok, num_segments))
-        s2 = X.psum(K.seg_sum(x * x, seg, ok, num_segments))
-        m2 = jnp.maximum(s2 - (s * s) / jnp.maximum(c, 1.0), 0.0)
-        kind = agg.kind
-        denom = c - 1.0 if kind.endswith("_samp") else c
-        var = m2 / jnp.maximum(denom, 1.0)
-        data = jnp.sqrt(var) if kind.startswith("stddev") else var
-        enough = c >= (2.0 if kind.endswith("_samp") else 1.0)
-        return TV(data, any_valid & enough, T.FLOAT64, None)
-    if isinstance(agg, E.First):
-        use = ok if agg.ignore_nulls else mask
-        data, found = K.seg_first(tv.data, seg, use, num_segments, capacity)
-        if tv.validity is not None:
-            vfirst, _ = K.seg_first(tv.valid_or_true(capacity), seg, use,
-                                    num_segments, capacity)
-        else:
-            vfirst = jnp.ones((num_segments,), jnp.bool_)
-        # choose the lowest device index that found a first row
+    @staticmethod
+    def one_copy(mask):
+        """The merged result is replicated; keep device 0's."""
+        return jnp.where(X.axis_index() == 0, mask, jnp.zeros_like(mask))
+
+    @staticmethod
+    def first(data, found, vfirst):
+        """The lowest device index that found a first row wins."""
+        if vfirst is None:
+            vfirst = jnp.ones(found.shape, jnp.bool_)
         d = X.axis_size()
         me = X.axis_index()
         winner = X.pmin(jnp.where(found, me, d))
@@ -626,8 +573,7 @@ def _merged_agg(agg: E.AggregateExpression, env: Env, seg, mask,
         zero = jnp.zeros((), dtype=data.dtype)
         data = X.psum(jnp.where(mine, data, zero))
         valid = X.psum(jnp.where(mine, vfirst, False).astype(jnp.int32)) > 0
-        return TV(data, (winner < d) & valid, tv.dtype, tv.dictionary)
-    raise NotImplementedError(f"distributed aggregate {agg!r}")
+        return data, (winner < d) & valid
 
 
 @dataclass(eq=False)
@@ -652,42 +598,9 @@ class PSumAggExec(P.PhysicalPlan):
                                    self.child).schema
 
     def trace(self, child_pipes: List[Pipe]) -> Pipe:
-        pipe = child_pipes[0]
-        env = pipe.env()
-        cap = pipe.capacity
-        key_tvs = [C.evaluate(g, env) for g in self.groupings]
-        codes, validities, cards = P.group_key_codes(key_tvs)
-
-        if not key_tvs:
-            seg = jnp.zeros((cap,), dtype=jnp.int32)
-            num_segments = 1
-        else:
-            seg, num_segments = K.pack_codes(codes, validities, cards)
-            seg = seg.astype(jnp.int32)
-
-        _, agg_calls = rewrite_agg_outputs(self.groupings, self.aggregates)
-        agg_tvs = [_merged_agg(a, env, seg, pipe.mask, num_segments, cap)
-                   for a in agg_calls]
-
-        present = X.psum(K.seg_count(seg, pipe.mask, num_segments)) > 0
-        if not key_tvs:
-            out_mask = jnp.ones((1,), dtype=jnp.bool_)
-            out_keys: List[TV] = []
-        else:
-            out_mask = present
-            nullable = [v is not None for v in validities]
-            unpacked = K.unpack_code(jnp.arange(num_segments), cards, nullable)
-            out_keys = []
-            for (code, valid), tv in zip(unpacked, key_tvs):
-                data = code.astype(C._jnp_dtype(tv.dtype))
-                out_keys.append(TV(data, valid, tv.dtype, tv.dictionary))
-        # result is replicated; keep one copy (device 0)
-        out_mask = jnp.where(X.axis_index() == 0, out_mask,
-                             jnp.zeros_like(out_mask))
-        agg_exec = P.HashAggregateExec(self.groupings, self.aggregates,
-                                       self.child)
-        return agg_exec._finalize(out_keys, agg_tvs, out_mask,
-                                  max(1, num_segments))
+        return P.HashAggregateExec(
+            self.groupings, self.aggregates, self.child)._trace_direct(
+                child_pipes[0], merge=_MeshMerge)
 
     def node_string(self):
         return (f"PSumAgg[keys=[{', '.join(map(str, self.groupings))}], "
@@ -1135,18 +1048,9 @@ class DistHashPartialAggExec(P.PhysicalPlan):
 # ---- distributed join -------------------------------------------------------
 
 
-@dataclass(eq=False)
-class _SchemaLeaf(P.PhysicalPlan):
-    leaf_schema: Schema
-    traceable = True
-
-    @property
-    def schema(self) -> Schema:
-        return self.leaf_schema
-
-
 def join_output_schema(left: Schema, right: Schema, how: str) -> Schema:
-    return P.JoinExec(_SchemaLeaf(left), _SchemaLeaf(right), how, (), ()).schema
+    return P.JoinExec(P._SchemaOnly(left), P._SchemaOnly(right), how,
+                      (), ()).schema
 
 
 def packed_join_keys(lpipe: Pipe, rpipe: Pipe,

@@ -116,6 +116,18 @@ class PhysicalPlan:
         pipes = [Pipe.from_batch_data(b.schema, b.data) for b in child_batches]
         return self.trace(pipes).to_batch()
 
+    def map_children(self, fn) -> "PhysicalPlan":
+        """This node with ``fn`` applied to every field that holds a
+        plan; the node itself when ``fn`` changed none of them."""
+        new = {}
+        for f in dataclasses.fields(self):
+            v = getattr(self, f.name)
+            if isinstance(v, PhysicalPlan):
+                nv = fn(v)
+                if nv is not v:
+                    new[f.name] = nv
+        return dataclasses.replace(self, **new) if new else self
+
     def stats_key(self) -> tuple:
         """Identity for adaptive runtime stats: plan structure + leaf
         array ids (jax arrays are immutable, so id-equality implies
@@ -895,62 +907,89 @@ def decimal_avg(total, cnt, dt: "T.DecimalType"):
     return data, out_dt
 
 
+class _LocalMerge:
+    """How one chip turns a segment's partial aggregate into its total:
+    it already is, so the argument comes back and nothing is traced.
+    The mesh's counterpart (``parallel/operators.py::_MeshMerge``) puts
+    a collective at each of these points."""
+
+    @staticmethod
+    def sum(x):
+        return x
+
+    min = max = one_copy = sum
+
+    @staticmethod
+    def first(data, found, vfirst):
+        """(value, validity) of First from the segment's first row, its
+        ``found`` flag and that row's validity (None: not nullable)."""
+        return data, found if vfirst is None else found & vfirst
+
+
 def _compute_agg(agg: E.AggregateExpression, env: Env, seg, mask,
                  num_segments: int, capacity: int,
-                 sorted_seg: bool = False) -> TV:
-    """Compute one aggregate over segments. Nulls in the input are
-    excluded per SQL semantics; a group with no valid input yields NULL
-    (except count). ``sorted_seg`` marks monotone segment ids (the
-    sort-agg path) unlocking the cumsum-based kernels — scatter-add is
-    pathologically slow on TPU (see kernels.py)."""
+                 sorted_seg: bool = False, merge=_LocalMerge) -> TV:
+    """Compute one aggregate over segments: THE evaluator of both
+    engines. Nulls in the input are excluded per SQL semantics; a group
+    with no valid input yields NULL (except count). ``sorted_seg`` marks
+    monotone segment ids (the sort-agg path) unlocking the cumsum-based
+    kernels — scatter-add is pathologically slow on TPU (see
+    kernels.py). ``merge`` makes a local reduction global: the identity
+    on one chip; on the mesh (``PSumAggExec``) psum / pmin / pmax — the
+    partial->final two-phase plan (reference: aggregate/AggUtils.scala:33
+    map-side combine + shuffled merge) collapsed into a single program
+    with an ICI collective as the phase boundary."""
     if isinstance(agg, E.Count) and agg.child is None:
-        cnt = K.seg_count(seg, mask, num_segments, sorted_seg)
+        cnt = merge.sum(K.seg_count(seg, mask, num_segments, sorted_seg))
         return TV(cnt, None, T.INT64, None)
 
     child = agg.child  # type: ignore[attr-defined]
     tv = C.evaluate(child, env)
     ok = mask & tv.valid_or_true(capacity)
-    any_valid = K.seg_count(seg, ok, num_segments, sorted_seg) > 0
     if getattr(agg, "distinct", False):
-        # DISTINCT: keep one ok row per (group, value); any_valid is
-        # computed before dedup (unchanged by it anyway).
+        # DISTINCT: keep one ok row per (group, value). On the mesh,
+        # local dedup + psum is exact ONLY when equal values are
+        # co-resident; the planner guarantees it by hash-exchanging on
+        # the distinct child (MeshExecutor._plan_aggregate) before
+        # PSumAggExec runs.
         ok = ok & _distinct_mask_cached(env, agg.child, tv, seg, ok)
+    cnt = merge.sum(K.seg_count(seg, ok, num_segments, sorted_seg))
+    # dedup keeps >= 1 head per non-empty group, so post-dedup positivity
+    # matches pre-dedup — one count serves both
+    any_valid = cnt > 0
+
+    def total(x):
+        """The merged per-segment sum of ``x`` over the ok rows."""
+        return merge.sum(K.seg_sum(x, seg, ok, num_segments, sorted_seg))
 
     if isinstance(agg, E.Count):
-        cnt = K.seg_count(seg, ok, num_segments, sorted_seg)
         return TV(cnt, None, T.INT64, None)
     if isinstance(agg, E.Sum):
         if isinstance(tv.dtype, T.DecimalType):
             # exact scaled-int64 sum (reference: Sum.scala resultType)
-            s = K.seg_sum(tv.data, seg, ok, num_segments, sorted_seg)
-            return TV(s, any_valid, decimal_sum_type(tv.dtype), None)
+            return TV(total(tv.data), any_valid,
+                      decimal_sum_type(tv.dtype), None)
         out_dt = T.INT64 if tv.dtype.is_integral else tv.dtype
-        data = tv.data.astype(C._jnp_dtype(out_dt))
-        s = K.seg_sum(data, seg, ok, num_segments, sorted_seg)
+        s = total(tv.data.astype(C._jnp_dtype(out_dt)))
         return TV(s, any_valid, out_dt, None)
     if isinstance(agg, E.Avg):
-        c = K.seg_count(seg, ok, num_segments, sorted_seg)
         if isinstance(tv.dtype, T.DecimalType):
-            total = K.seg_sum(tv.data, seg, ok, num_segments, sorted_seg)
-            data, out_dt = decimal_avg(total, c, tv.dtype)
+            data, out_dt = decimal_avg(total(tv.data), cnt, tv.dtype)
             return TV(data, any_valid, out_dt, None)
-        s = K.seg_sum(tv.data.astype(jnp.float64), seg, ok, num_segments,
-                      sorted_seg)
-        data = s / jnp.maximum(c, 1)
-        return TV(data, any_valid, T.FLOAT64, None)
+        s = total(tv.data.astype(jnp.float64))
+        return TV(s / jnp.maximum(cnt, 1), any_valid, T.FLOAT64, None)
     if isinstance(agg, E.Min):
-        m = K.seg_min(tv.data, seg, ok, num_segments, sorted_seg)
+        m = merge.min(K.seg_min(tv.data, seg, ok, num_segments, sorted_seg))
         return TV(m, any_valid, tv.dtype, tv.dictionary)
     if isinstance(agg, E.Max):
-        m = K.seg_max(tv.data, seg, ok, num_segments, sorted_seg)
+        m = merge.max(K.seg_max(tv.data, seg, ok, num_segments, sorted_seg))
         return TV(m, any_valid, tv.dtype, tv.dictionary)
     if isinstance(agg, E.StddevVariance):
         x = tv.data.astype(jnp.float64)
-        c = K.seg_count(seg, ok, num_segments, sorted_seg).astype(jnp.float64)
-        s = K.seg_sum(x, seg, ok, num_segments, sorted_seg)
-        s2 = K.seg_sum(x * x, seg, ok, num_segments, sorted_seg)
-        m2 = s2 - (s * s) / jnp.maximum(c, 1.0)
-        m2 = jnp.maximum(m2, 0.0)
+        c = cnt.astype(jnp.float64)
+        s = total(x)
+        s2 = total(x * x)
+        m2 = jnp.maximum(s2 - (s * s) / jnp.maximum(c, 1.0), 0.0)
         kind = agg.kind
         denom = c - 1.0 if kind.endswith("_samp") else c
         var = m2 / jnp.maximum(denom, 1.0)
@@ -961,10 +1000,14 @@ def _compute_agg(agg: E.AggregateExpression, env: Env, seg, mask,
         use = ok if agg.ignore_nulls else mask
         data, found = K.seg_first(tv.data, seg, use, num_segments, capacity,
                                   sorted_seg)
-        valid = found if tv.validity is None else (
-            found & K.seg_first(tv.valid_or_true(capacity), seg, use,
-                                num_segments, capacity, sorted_seg)[0])
+        vfirst = None if tv.validity is None else K.seg_first(
+            tv.valid_or_true(capacity), seg, use, num_segments, capacity,
+            sorted_seg)[0]
+        data, valid = merge.first(data, found, vfirst)
         return TV(data, valid, tv.dtype, tv.dictionary)
+    if merge is not _LocalMerge:
+        # Percentile and Collect need a group's rows in one place
+        raise NotImplementedError(f"distributed aggregate {agg!r}")
     if isinstance(agg, E.Percentile):
         # EXACT per-group percentile: one (group, value) lexsort, then a
         # rank gather vectorized over all groups — same device sort
@@ -975,7 +1018,6 @@ def _compute_agg(agg: E.AggregateExpression, env: Env, seg, mask,
             [K.SortKey(seg, None, True, True),
              K.SortKey(tv.data, tv.validity, True, True)], ok)
         svals = tv.data[perm]
-        cnt = K.seg_count(seg, ok, num_segments, sorted_seg)
         starts = jnp.cumsum(cnt) - cnt
         hi_cap = capacity - 1
         if agg.interpolate:
@@ -1121,6 +1163,13 @@ class HashAggregateExec(PhysicalPlan):
         pipe = child_pipes[0]
         if not self._static_direct_ok():
             return self._trace_sorted(pipe)
+        return self._trace_direct(pipe)
+
+    def _trace_direct(self, pipe: Pipe, merge=_LocalMerge) -> Pipe:
+        """Dense group ids from trace-time key cardinalities, then
+        segment reductions. ``merge`` as in ``_compute_agg``: under the
+        mesh's (``PSumAggExec``) every device reduces its rows, the
+        collectives make the result global and one copy of it is kept."""
         env = pipe.env()
         cap = pipe.capacity
         key_tvs = [C.evaluate(g, env) for g in self.groupings]
@@ -1134,10 +1183,12 @@ class HashAggregateExec(PhysicalPlan):
             seg = seg.astype(jnp.int32)
 
         _, agg_calls = rewrite_agg_outputs(self.groupings, self.aggregates)
-        agg_tvs = [_compute_agg(a, env, seg, pipe.mask, num_segments, cap)
+        agg_tvs = [_compute_agg(a, env, seg, pipe.mask, num_segments, cap,
+                                merge=merge)
                    for a in agg_calls]
 
-        group_present = K.seg_count(seg, pipe.mask, num_segments) > 0
+        group_present = merge.sum(
+            K.seg_count(seg, pipe.mask, num_segments)) > 0
         if not key_tvs:
             out_mask = jnp.ones((1,), dtype=jnp.bool_)
             out_keys: List[TV] = []
@@ -1149,7 +1200,8 @@ class HashAggregateExec(PhysicalPlan):
             for (code, valid), tv in zip(unpacked, key_tvs):
                 data = code.astype(C._jnp_dtype(tv.dtype))
                 out_keys.append(TV(data, valid, tv.dtype, tv.dictionary))
-        return self._finalize(out_keys, agg_tvs, out_mask, max(1, num_segments))
+        return self._finalize(out_keys, agg_tvs, merge.one_copy(out_mask),
+                              max(1, num_segments))
 
     # -- sort-based path ------------------------------------------------------
 

@@ -17,11 +17,8 @@ stage-boundary analogue, reference: AdaptiveSparkPlanExec.scala:247).
 
 from __future__ import annotations
 
-import dataclasses
-from dataclasses import dataclass
-from typing import Dict, List, Tuple
+import operator
 
-import jax
 import numpy as np
 
 from spark_tpu import conf as CF
@@ -30,6 +27,7 @@ from spark_tpu.columnar.batch import Batch
 from spark_tpu.expr import expressions as E
 from spark_tpu.physical import kernels as K
 from spark_tpu.physical import operators as P
+from spark_tpu.physical import stage
 from spark_tpu.plan import logical as L
 
 
@@ -91,45 +89,6 @@ from spark_tpu.storage.lru import LruDict  # noqa: E402
 _STAGE_CACHE = LruDict("fused", CF.JIT_STAGE_CACHE_ENTRIES)
 
 
-def _fully_traceable(plan: P.PhysicalPlan) -> bool:
-    if isinstance(plan, P.BatchScanExec):
-        return True
-    return (plan.traceable and not plan.has_blocking_exprs()
-            and all(_fully_traceable(c) for c in plan.children()))
-
-
-def _collect_scans(plan: P.PhysicalPlan, out: List[P.BatchScanExec]) -> None:
-    if isinstance(plan, P.BatchScanExec):
-        out.append(plan)
-        return
-    for c in plan.children():
-        _collect_scans(c, out)
-
-
-@dataclass(eq=False)
-class _ScanSlot(P.PhysicalPlan):
-    """Leaf placeholder in cached stage closures: carries only the scan
-    schema so cached jit functions never pin leaf device buffers."""
-
-    scan_schema: "object"
-    traceable = True
-
-    @property
-    def schema(self):
-        return self.scan_schema
-
-
-def _strip_leaf_data(plan: P.PhysicalPlan) -> P.PhysicalPlan:
-    if isinstance(plan, P.BatchScanExec):
-        return _ScanSlot(plan.batch.schema)
-    fields = {}
-    for f in dataclasses.fields(plan):
-        v = getattr(plan, f.name)
-        fields[f.name] = _strip_leaf_data(v) if isinstance(
-            v, P.PhysicalPlan) else v
-    return dataclasses.replace(plan, **fields)
-
-
 def _bind_adaptive(plan: P.PhysicalPlan) -> None:
     """Attach recorded runtime stats to join nodes (the re-optimization
     step of AQE, reference: AdaptiveSparkPlanExec.getFinalPhysicalPlan:247
@@ -159,9 +118,12 @@ def _bind_adaptive(plan: P.PhysicalPlan) -> None:
         plan.adaptive = P._GEN_STATS.get(plan.stats_key())
 
 
-def _adaptive_snapshot(plan: P.PhysicalPlan) -> tuple:
+def _adaptive_snapshot(plan: P.PhysicalPlan,
+                       scan_key=operator.methodcaller("plan_key")) -> tuple:
     """Adaptive state of every join in tree order — part of the fused
-    stage cache key (plan_key alone is stable across stats changes)."""
+    stage cache key (plan_key alone is stable across stats changes).
+    ``scan_key`` names an embedded index / table scan: its
+    ``plan_key()`` for this process's stage cache."""
     out = []
 
     def go(p: P.PhysicalPlan) -> None:
@@ -170,9 +132,9 @@ def _adaptive_snapshot(plan: P.PhysicalPlan) -> tuple:
             # deliberately excluded from plan_key (stats identity)
             out.append((p.adaptive, p.index_orient,
                         None if p.index_scan is None
-                        else p.index_scan.plan_key(),
+                        else scan_key(p.index_scan),
                         None if p.table_scan is None
-                        else p.table_scan.plan_key()))
+                        else scan_key(p.table_scan)))
         elif isinstance(p, (P.HashAggregateExec, P.GenerateExec)):
             out.append(p.adaptive)
         elif isinstance(p, P.CompactExec):
@@ -187,31 +149,13 @@ def _adaptive_snapshot(plan: P.PhysicalPlan) -> tuple:
 
 
 def _stable_adaptive_snapshot(plan: P.PhysicalPlan) -> tuple:
-    """_adaptive_snapshot for the cross-session executable store:
-    identical structure, but the embedded index/table scan identities
-    use the store's content-digest keys instead of plan_key() (whose
-    hash(dicts) component is salted per process). Only computed on the
-    fresh-stage-entry path."""
+    """_adaptive_snapshot for the cross-session executable store: the
+    embedded index/table scan identities use the store's content-digest
+    keys instead of plan_key() (whose hash(dicts) component is salted
+    per process). Only computed on the fresh-stage-entry path."""
     from spark_tpu.compile.store import stable_plan_key
 
-    out = []
-
-    def go(p: P.PhysicalPlan) -> None:
-        if isinstance(p, P.JoinExec):
-            out.append((p.adaptive, p.index_orient,
-                        None if p.index_scan is None
-                        else stable_plan_key(p.index_scan),
-                        None if p.table_scan is None
-                        else stable_plan_key(p.table_scan)))
-        elif isinstance(p, (P.HashAggregateExec, P.GenerateExec)):
-            out.append(p.adaptive)
-        elif isinstance(p, P.CompactExec):
-            out.append(("compact", p.cap))
-        for c in p.children():
-            go(c)
-
-    go(plan)
-    return tuple(out)
+    return _adaptive_snapshot(plan, stable_plan_key)
 
 
 def _run_fused(plan: P.PhysicalPlan) -> Batch:
@@ -220,38 +164,15 @@ def _run_fused(plan: P.PhysicalPlan) -> Batch:
     (analogue of CodeGenerator.compile's generated-class cache,
     reference: codegen/CodeGenerator.scala:1442). Cached closures hold a
     leaf-stripped plan skeleton — leaf batch data arrives as arguments."""
-    scans: List[P.BatchScanExec] = []
-    _collect_scans(plan, scans)
+    scans = stage.collect_leaves(plan, P.BatchScanExec)
     key = (plan.plan_key(), _adaptive_snapshot(plan))
     entry = _STAGE_CACHE.get(key)
     fresh = entry is None
     if fresh:
-        schema_box: dict = {}
-        skeleton = _strip_leaf_data(plan)
-
-        def stage_fn(leaf_datas):
-            it = iter(leaf_datas)
-
-            def go(p: P.PhysicalPlan) -> P.Pipe:
-                if isinstance(p, _ScanSlot):
-                    return P.Pipe.from_batch_data(p.scan_schema, next(it))
-                pipes = [go(c) for c in p.children()]
-                with trace.operator_scope(p):
-                    return p.trace(pipes)
-
-            batch = go(skeleton).to_batch()
-            schema_box["schema"] = batch.schema
-            return batch.data
-
-        # the stored callable consults the cross-session executable
-        # store when the compile service is active; otherwise this is
-        # exactly jax.jit(stage_fn)
-        from spark_tpu.compile import build_stage_callable
-
-        entry = (build_stage_callable(
-            "fused", plan, stage_fn,
-            tuple(s.batch.data for s in scans), schema_box,
-            extra=_stable_adaptive_snapshot(plan)), schema_box)
+        entry = stage.build_stage(
+            "fused", plan, P.BatchScanExec,
+            tuple(s.batch.data for s in scans), name="stage_fn",
+            extra=_stable_adaptive_snapshot(plan))
         _STAGE_CACHE[key] = entry
     jitted, schema_box = entry
     if fresh:
@@ -339,22 +260,15 @@ def _replay_compactions(plan: P.PhysicalPlan) -> P.PhysicalPlan:
     so fused traces reproduce the identical intermediate arrays."""
     if isinstance(plan, P.BatchScanExec):
         return plan
-    fields = {}
-    changed = False
-    for f in dataclasses.fields(plan):
-        v = getattr(plan, f.name)
-        if isinstance(v, P.PhysicalPlan) and not isinstance(
-                v, P.BatchScanExec):
-            nv = _replay_compactions(v)
-            cap = _COMPACT_STATS.get(nv.stats_key())
-            if cap:
-                nv = P.CompactExec(nv, cap)
-            if nv is not v:
-                changed = True
-            fields[f.name] = nv
-        else:
-            fields[f.name] = v
-    return dataclasses.replace(plan, **fields) if changed else plan
+
+    def replay(child: P.PhysicalPlan) -> P.PhysicalPlan:
+        if isinstance(child, P.BatchScanExec):
+            return child
+        child = _replay_compactions(child)
+        cap = _COMPACT_STATS.get(child.stats_key())
+        return P.CompactExec(child, cap) if cap else child
+
+    return plan.map_children(replay)
 
 
 #: Observed live output rows per (plan, leaf-array-ids): re-executions
@@ -391,7 +305,7 @@ def _execute(plan: P.PhysicalPlan) -> Batch:
 
     if isinstance(plan, P.BatchScanExec):
         return plan.batch
-    if _fully_traceable(plan):
+    if stage.fully_traceable(plan, P.BatchScanExec):
         with trace.span("stage.run", op="fused"), \
                 metrics.stage_timer("fused", node=plan.node_string()):
             return _run_fused(plan)
@@ -417,3 +331,14 @@ def execute_logical(plan: L.LogicalPlan, optimize: bool = True) -> Batch:
     with trace.span("query.plan"):
         bound = _bind(plan_physical(plan))
     return _run_bound(*bound)
+
+
+def execute_logical_on(session, plan: L.LogicalPlan,
+                       optimize: bool = True) -> Batch:
+    """THE place that picks the engine: the session's mesh executor
+    when it runs under a mesh master, else (or with no session) the
+    one-chip planner above."""
+    ex = getattr(session, "mesh_executor", None)
+    if ex is not None:
+        return ex.execute_logical(plan, optimize)
+    return execute_logical(plan, optimize)

@@ -403,7 +403,7 @@ def _as_replica(i: int, r) -> Replica:
 
 class Federation:
     """The replica set + dispatch engine; owned by a FederationRouter
-    but usable headless (bench drives it directly)."""
+    but usable headless."""
 
     def __init__(self, replicas: Sequence, conf=None,
                  timeout: float = 120.0):

@@ -12,7 +12,7 @@ the client sends it on its next request.
 
 Deployment shapes:
 
-- **in-process fleet** (tests, single-host bench): ``serve_fleet``
+- **in-process fleet** (tests, one host): ``serve_fleet``
   spawns N ConnectServers as threads over ONE session — they share
   the device mesh, the HBM store, and one ResultCache (so the
   single-flight herd guarantee spans replicas).

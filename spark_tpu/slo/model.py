@@ -20,7 +20,7 @@ inside the trace) folds its wall time into a separate ``cold_ms``/
 outlier used to multiply the run-time estimate by the compile time
 and poison admission for the next N queries. ``predict_run_ms`` stays
 warm-only (a replayed/prewarmed plan never pays the compile again);
-``cold_ms`` is observability for the snapshot and bench. Journals
+``cold_ms`` is observability for the snapshot. Journals
 written before this field existed load with cold_ms = cold_n = 0.
 
 Prediction scales the device+transfer share by the ratio of the

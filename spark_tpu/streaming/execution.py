@@ -124,12 +124,9 @@ class StreamingQuery:
     # -- execution ------------------------------------------------------------
 
     def _run(self, plan: L.LogicalPlan):
-        ex = getattr(self._session, "mesh_executor", None)
-        if ex is not None:
-            return ex.execute_logical(plan)
-        from spark_tpu.physical.planner import execute_logical
+        from spark_tpu.physical.planner import execute_logical_on
 
-        return execute_logical(plan)
+        return execute_logical_on(self._session, plan)
 
     def _to_arrow(self, plan: L.LogicalPlan) -> pa.Table:
         from spark_tpu.columnar.arrow import to_arrow

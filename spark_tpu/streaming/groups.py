@@ -130,12 +130,9 @@ class GroupStateQuery:
 
     def _to_arrow(self, plan: L.LogicalPlan) -> pa.Table:
         from spark_tpu.columnar.arrow import to_arrow
-        from spark_tpu.physical.planner import execute_logical
+        from spark_tpu.physical.planner import execute_logical_on
 
-        ex = getattr(self._session, "mesh_executor", None)
-        batch = ex.execute_logical(plan) if ex is not None \
-            else execute_logical(plan)
-        return to_arrow(batch)
+        return to_arrow(execute_logical_on(self._session, plan))
 
     def process_all_available(self) -> None:
         while True:

@@ -421,7 +421,7 @@ def write_parquet_streamed(sf: float, path: str, seed: int = 20260729,
 def ensure_dataset(sf: float, base: str = "/tmp",
                    seed: int = 20260729) -> str:
     """Generate-once disk cache (SF100 generation is ~15 min of rng on
-    one core; benches must not pay it per run). Returns the dataset
+    one core; a run must not pay it again). Returns the dataset
     directory; a _DONE marker guards against half-written caches."""
     import os
     import shutil

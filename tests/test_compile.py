@@ -544,8 +544,7 @@ def test_prewarm_from_history(fact_parquet, tmp_path):
 @pytest.mark.timeout(120)
 def test_prewarm_time_budget_skips(fact_parquet, tmp_path):
     """A zero time budget replays nothing and records why — the
-    skipped marks name the budget, mirroring bench's phase-skip
-    contract."""
+    skipped marks name the budget."""
     store_dir = str(tmp_path / "store")
     with _session(**{"spark.tpu.compile.store.dir": store_dir}) as s:
         s.read.parquet(fact_parquet).createOrReplaceTempView("budget_t")

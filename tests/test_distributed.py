@@ -117,6 +117,93 @@ def test_direct_group_agg_psum(ex, rel):
         assert got[k][1] == want[k][1]
 
 
+@pytest.fixture(scope="module")
+def typed_rel():
+    """Dictionary keys over a decimal, an int, a float and a second
+    dictionary column, each with nulls; a generator of its own (the
+    session's ``rng`` is a stream other tests draw from)."""
+    import decimal
+
+    r = np.random.default_rng(30)
+    n = 4000
+
+    def nulled(values, share=0.15):
+        out = np.array(values, dtype=object)
+        out[r.random(n) < share] = None
+        return list(out)
+
+    cents = r.integers(-50_000, 900_000, n)
+    return L.Relation(from_arrow(pa.table({
+        "tag": pa.array(list(np.array(["red", "green", "blue", "gold"])[
+            r.integers(0, 4, n)]), pa.string()),
+        "d": pa.array(nulled([decimal.Decimal(int(c)).scaleb(-2)
+                              for c in cents]), pa.decimal128(12, 2)),
+        "i": pa.array(nulled(r.integers(-1000, 1000, n).tolist()),
+                      pa.int64()),
+        "f": pa.array(nulled((r.normal(size=n) * 10).tolist()),
+                      pa.float64()),
+        "s": pa.array(nulled(np.array(["ash", "elm", "oak", "yew", "fir"])[
+            r.integers(0, 5, n)].tolist()), pa.string()),
+    })))
+
+
+@pytest.mark.parametrize("agg, exact", [
+    (E.Count(None), True),
+    (E.Count(E.Col("f")), True),
+    (E.Sum(E.Col("d")), True),
+    (E.Sum(E.Col("i")), True),
+    (E.Sum(E.Col("f")), False),
+    (E.Avg(E.Col("d")), True),
+    (E.Avg(E.Col("f")), False),
+    (E.Min(E.Col("i")), True),
+    (E.Max(E.Col("d")), True),
+    (E.Min(E.Col("s")), True),
+    (E.Max(E.Col("s")), True),
+    (E.StddevVariance("stddev_samp", E.Col("f")), False),
+    (E.StddevVariance("var_pop", E.Col("i")), False),
+    (E.First(E.Col("i")), True),
+    (E.First(E.Col("i"), ignore_nulls=True), True),
+    (E.First(E.Col("s"), ignore_nulls=True), True),
+    (E.Count(E.Col("i"), distinct=True), True),
+], ids=lambda a: getattr(a, "name", None))
+@pytest.mark.parametrize("grouped", [True, False],
+                         ids=["by_tag", "global"])
+def test_aggregate_evaluator_agrees_across_engines(ex, typed_rel, agg,
+                                                   exact, grouped):
+    """One evaluator (``P._compute_agg``) under both engines: with
+    dictionary keys, or no keys, the mesh runs it through ``PSumAggExec``
+    with psum / pmin / pmax as its merge (DISTINCT by keys: through the
+    exchange and ``DistSortAggExec``), one chip with the identity.
+    Integers, decimals and dictionary values agree exactly, floats to
+    1e-9."""
+    keys = (E.Col("tag"),) if grouped else ()
+    plan = L.Aggregate(keys, keys + (E.Alias(agg, "x"),), typed_rel)
+    got = {r[:-1]: r[-1] for r in _rows(ex.execute_logical(plan))}
+    want = {r[:-1]: r[-1] for r in _rows(execute_logical(plan))}
+    assert set(got) == set(want) and len(want) == (4 if grouped else 1)
+    for k, w in want.items():
+        assert w is not None
+        if exact:
+            assert got[k] == w and type(got[k]) is type(w)
+        else:
+            assert got[k] == pytest.approx(w, rel=1e-9)
+
+
+@pytest.mark.parametrize("agg", [
+    E.Percentile(E.Col("f"), 0.5),
+    E.Percentile(E.Col("f"), 0.5, interpolate=True),
+    E.Collect(E.Col("i")),
+    E.Collect(E.Col("i"), unique=True),
+], ids=lambda a: a.name)
+def test_mesh_merge_refuses_what_needs_a_groups_rows_together(
+        ex, typed_rel, agg):
+    plan = L.Aggregate((E.Col("tag"),),
+                       (E.Col("tag"), E.Alias(agg, "x")), typed_rel)
+    with pytest.raises(NotImplementedError, match="distributed aggregate"):
+        ex.execute_logical(plan)
+    assert len(_rows(execute_logical(plan))) == 4
+
+
 @pytest.mark.slow
 def test_shuffle_group_agg(ex, rel):
     """int keys -> hash exchange + sort-agg path."""

@@ -571,7 +571,7 @@ def test_fault_profile_rollup(spark, fconf, fact_parquet):
 
 def test_fault_events_reach_event_log(spark, fconf, fact_parquet, tmp_path):
     """Injected faults land in the JSONL event log, so post-mortem
-    tooling (history/bench) sees them without live metrics access."""
+    tooling (history) sees them without live metrics access."""
     import json
     import os
 

@@ -89,23 +89,64 @@ def test_lowered_stage_names_its_operators(stages, query, operators):
     assert operators <= _scopes(trace_fn, example_args)
 
 
-def test_mesh_stage_names_its_operators(spark):
-    """The mesh's counterpart (parallel/executor.py::_run_stage_inner):
-    the shard_map'd local function carries the same scopes."""
+@pytest.fixture(scope="module")
+def mesh_spark(spark):
+    """A session of its own over mesh[2]. ``builder.master("mesh[2]")
+    .getOrCreate()`` would hand back the active one-chip session with a
+    conf key set: a session builds its mesh when it is made."""
+    from spark_tpu import conf as CF
     from spark_tpu.api.session import SparkSession
 
-    mesh = SparkSession.builder.master("mesh[2]").getOrCreate()
-    try:
-        with _captured_stages() as captured:
-            df = mesh.range(4099).filter("id % 7 = 3").groupBy().count()
-            assert df.collect()[0][0] == len(range(3, 4099, 7))
-    finally:
-        SparkSession._active = spark
+    session = SparkSession("scopes-mesh", {CF.MESH_DEVICES.key: 2})
+    assert session.mesh_executor is not None
+    return session
+
+
+def test_mesh_stage_names_its_operators(mesh_spark):
+    """The mesh's counterpart (parallel/executor.py::_run_stage_inner):
+    the shard_map'd local function carries the same scopes."""
+    with _captured_stages() as captured:
+        df = mesh_spark.range(4099).filter("id % 7 = 3").groupBy().count()
+        assert df.collect()[0][0] == len(range(3, 4099, 7))
     assert captured
     names = set()
     for _plan, trace_fn, example_args in captured:
         names |= _scopes(trace_fn, example_args)
     assert "FilterExec" in names and any("Agg" in n for n in names)
+
+
+@pytest.mark.parametrize("engine", ["one_chip", "mesh"])
+def test_second_execution_builds_nothing(spark, mesh_spark, engine):
+    """The shared builder (physical/stage.py::build_stage) runs on a
+    stage-cache miss only: an execution that finds its stages cached
+    records no build event, compiles nothing and leaves both caches the
+    size they were."""
+    from spark_tpu.parallel import executor as MX
+    from spark_tpu.physical import planner as PL
+
+    session = spark if engine == "one_chip" else mesh_spark
+
+    def run():
+        # 4,111 rows: a shape no other test of this file compiles
+        return session.range(4111).filter("id % 5 = 2").groupBy().count(
+        ).collect()[0][0]
+
+    def sizes():
+        return len(PL._STAGE_CACHE), len(MX._DIST_STAGE_CACHE)
+
+    with _captured_stages() as first:
+        want = run()
+        run()   # one chip: a first run records its output capacity
+    before = sizes()
+    last = metrics.recent(1)[-1]["n"]
+    with _captured_stages() as again:
+        assert run() == want == len(range(2, 4111, 5))
+    assert first and again == [] and sizes() == before
+    assert [e["kind"] for e in metrics.recent(4096) if e["n"] > last
+            and e["kind"] in trace.BUILD_EVENTS | {"stage_compile"}] == []
+    # each engine built its own function, under its own name
+    assert {fn.__name__ for _plan, fn, _args in first} == {
+        "stage_fn" if engine == "one_chip" else "local_fn"}
 
 
 def test_results_are_byte_identical_under_a_profiler_session(

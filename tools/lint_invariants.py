@@ -24,7 +24,7 @@ Seven rules the engine relies on but Python cannot enforce:
    ``seg_sum`` / ``join`` / ``sort``) against ``trace.BUILD_EVENTS``.
 
 3. **fingerprint-purity** — functions on the structural-fingerprint
-   path (compile/store.py and planner._stable_adaptive_snapshot) must
+   path (compile/store.py and planner's two _adaptive_snapshot functions) must
    not call ``hash()`` or ``id()`` (process-seeded / address-based:
    both break cross-session executable reuse) and must not iterate a
    dict's ``.items()/.keys()/.values()`` unless wrapped in
@@ -78,7 +78,7 @@ DEFAULT_CONFIG = {
     "fingerprint_paths": {
         os.path.join("spark_tpu", "compile", "store.py"): [],
         os.path.join("spark_tpu", "physical", "planner.py"):
-            ["_stable_adaptive_snapshot"],
+            ["_adaptive_snapshot", "_stable_adaptive_snapshot"],
     },
     "locked_modules": [os.path.join("spark_tpu", "metrics.py")],
     # module state -> lock that must guard its mutations
