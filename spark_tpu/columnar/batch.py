@@ -107,6 +107,14 @@ class Batch:
                 total += cd.validity.size * cd.validity.dtype.itemsize
         return int(total)
 
+    def narrowed(self) -> int:
+        """How many columns the device holds narrower than their schema
+        dtype (``from_numpy``'s ``narrow_transfer``)."""
+        return sum(
+            cd.data.ndim == 1
+            and cd.data.dtype.itemsize < np.dtype(f.dtype.np_dtype).itemsize
+            for f, cd in zip(self.schema.fields, self.data.columns))
+
     def block_until_ready(self) -> "Batch":
         """Wait for all pending host->device transfers of this batch's
         arrays. The pipeline producer calls this so a chunk's transfer
@@ -369,11 +377,16 @@ def from_numpy(
 ) -> Batch:
     """Build a device batch from host numpy columns, padding to capacity.
 
-    ``narrow_transfer`` ships int64 columns whose values fit int32 as
-    int32 — the stage runner widens them back at trace entry
-    (Pipe.from_batch_data), so the cast runs ON DEVICE and the
-    host->device link moves half the bytes: the out-of-HBM tiers
-    stream tens of GB through this path."""
+    ``narrow_transfer`` keeps a 1-D int64-backed column (decimal,
+    bigint) whose observed values fit int32 as int32 ON THE DEVICE:
+    every stage widens it back to the schema's dtype at trace entry
+    (Pipe.from_batch_data), the convert fuses into its consumers, and
+    the chip is spared the split of an int64 *parameter* into the u32
+    pairs it computes with (paid on every execution of a resident scan;
+    PERF.md, PR 31) besides half the host->device bytes (the out-of-HBM
+    tiers stream tens of GB through this path). What decides is the
+    column's min and max, nothing else; an empty column stays as its
+    schema says."""
     n = int(arrays[0].shape[0]) if arrays else 0
     for a in arrays:
         assert a.shape[0] == n, "all columns must have equal length"
@@ -386,14 +399,14 @@ def from_numpy(
     for f, arr, val in zip(schema.fields, arrays, validities):
         np_dt = arr.dtype if arr.ndim > 1 else f.dtype.np_dtype
         if narrow_transfer and arr.ndim == 1 \
-                and np.dtype(np_dt) == np.int64 and n > 0:
-            lo = int(arr.min()) if n else 0
-            hi = int(arr.max()) if n else 0
-            if -(1 << 31) <= lo and hi < (1 << 31):
-                np_dt = np.int32
+                and np.dtype(np_dt) == np.int64 and n > 0 \
+                and -(1 << 31) <= int(arr.min()) \
+                and int(arr.max()) < (1 << 31):
+            np_dt = np.int32
         shape = (cap,) + tuple(arr.shape[1:])
         padded = np.zeros(shape, dtype=np_dt)
-        padded[:n] = arr.astype(np_dt, copy=False)
+        # one cast into place (values known to fit), no temporary
+        np.copyto(padded[:n], arr, casting="unsafe")
         v = None
         if val is not None:
             pv = np.zeros((cap,), dtype=bool)

@@ -84,19 +84,18 @@ def _leaf_key(plan) -> Optional[tuple]:
     """Stable identity for the two leaf scan node types (the unstable
     ``hash(dicts)`` component of their plan_key is replaced by a
     content digest)."""
+    from spark_tpu.physical.operators import scan_plan_key
+
     batch = getattr(plan, "batch", None)
     if batch is not None and hasattr(batch, "schema") \
             and hasattr(batch, "capacity"):
-        sch = batch.schema
-        return ("BatchScan", int(batch.capacity),
-                tuple((f.name, repr(f.dtype)) for f in sch.fields),
-                _dict_digest(sch))
+        return scan_plan_key("BatchScan", int(batch.capacity), batch.schema,
+                             batch.data, _dict_digest(batch.schema))
     sharded = getattr(plan, "sharded", None)
     if sharded is not None:
-        sch = sharded.schema
-        return ("ShardScan", int(sharded.per_device_capacity),
-                tuple((f.name, repr(f.dtype)) for f in sch.fields),
-                _dict_digest(sch))
+        return scan_plan_key("ShardScan", int(sharded.per_device_capacity),
+                             sharded.schema, sharded.data,
+                             _dict_digest(sharded.schema))
     return None
 
 

@@ -347,14 +347,19 @@ class FileSource:
             columns=list(columns) if columns is not None else None,
             filter=_filters_to_pads(filters, self._dtypes()))
         t1 = _time.perf_counter()
-        batch = from_arrow(table)  # dict-encode + host->device transfer
-        # wait for the transfer, or transfer_ms is an enqueue time (once
-        # per scan: the batch is cached below)
+        # dict-encode + host->device transfer; an int64-backed column
+        # whose values fit int32 is RESIDENT as int32 (what the column
+        # holds decides, once per scan: the batch is cached below) and
+        # every stage widens it at trace entry (Pipe.from_batch_data)
+        batch = from_arrow(table, narrow_transfer=True)
+        # wait for the transfer, or transfer_ms is an enqueue time
         batch.block_until_ready()
         t2 = _time.perf_counter()
         metrics.record("scan", fmt=self.fmt, rows=table.num_rows,
                        decode_ms=round((t1 - t0) * 1e3, 2),
-                       transfer_ms=round((t2 - t1) * 1e3, 2))
+                       transfer_ms=round((t2 - t1) * 1e3, 2),
+                       narrowed=batch.narrowed(),
+                       resident_bytes=batch.device_nbytes())
         if hot and store.put(skey, batch, pin=True):
             return batch
         # bounded LRU: parameterized pushed filters must not pin an

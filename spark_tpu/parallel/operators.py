@@ -48,10 +48,8 @@ class ShardScanExec(P.PhysicalPlan):
         return f"ShardScan{list(self.schema.names)}"
 
     def plan_key(self):
-        dicts = tuple(f.dictionary for f in self.schema.fields)
-        return ("ShardScan", self.sharded.per_device_capacity,
-                tuple((f.name, repr(f.dtype)) for f in self.schema.fields),
-                hash(dicts))
+        return P.scan_plan_key("ShardScan", self.sharded.per_device_capacity,
+                               self.sharded.schema, self.sharded.data)
 
 
 @dataclass(eq=False)

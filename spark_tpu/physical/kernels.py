@@ -648,7 +648,9 @@ def orderable_int64(
     This is the analogue of Spark's sort-key *prefix* encoding
     (core/.../unsafe/sort/PrefixComparators.java) — but here the whole key
     fits the prefix, because strings are dictionary ranks."""
-    if rank_table is not None:
+    if rank_table is not None and len(rank_table):
+        # (an empty dictionary, of a scan no row passed, has no rank to
+        # gather: its codes order as they are)
         y = jnp.asarray(rank_table, dtype=jnp.int64)[data]
     elif jnp.issubdtype(data.dtype, jnp.floating):
         bits = jax.lax.bitcast_convert_type(

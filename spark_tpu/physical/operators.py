@@ -76,8 +76,10 @@ class Pipe:
             if d.ndim == 1 and d.dtype != want \
                     and jnp.issubdtype(d.dtype, jnp.integer) \
                     and jnp.issubdtype(want, jnp.integer):
-                # transfer-narrowed column (batch.from_numpy
-                # narrow_transfer): widen back ON DEVICE at trace entry
+                # a column the device holds narrower than its schema
+                # says (batch.from_numpy narrow_transfer: a resident
+                # scan's, a streamed chunk's): widen it at trace entry,
+                # where the convert fuses into its consumers
                 d = d.astype(want)
             cols[f.name] = TV(d, cd.validity, f.dtype, f.dictionary)
         return cls(cols, data.row_mask, schema.names)
@@ -200,6 +202,23 @@ class PhysicalPlan:
 # ---- leaves ----------------------------------------------------------------
 
 
+def scan_plan_key(kind: str, capacity: int, schema: Schema,
+                  data: BatchData, dict_id=None) -> tuple:
+    """A leaf scan's part of a stage's identity, for both engines' leaf
+    types and the executable store: the capacity, each column's logical
+    dtype AND the dtype the device holds it in (a scan keeps an
+    int64-backed column whose values fit as int32, ``from_numpy``; the
+    program that widens it is another program than the one that reads
+    int64), and the dictionaries — by ``hash`` in this process, by
+    ``dict_id`` (a content digest) across processes."""
+    if dict_id is None:
+        dict_id = hash(tuple(f.dictionary for f in schema.fields))
+    return (kind, capacity,
+            tuple((f.name, repr(f.dtype), cd.data.dtype)
+                  for f, cd in zip(schema.fields, data.columns)),
+            dict_id)
+
+
 @dataclass(eq=False)
 class BatchScanExec(PhysicalPlan):
     """Scan over an in-memory device batch (+ input port index for fused
@@ -222,10 +241,8 @@ class BatchScanExec(PhysicalPlan):
         return f"BatchScan{list(self.schema.names)}"
 
     def plan_key(self):
-        dicts = tuple(f.dictionary for f in self.batch.schema.fields)
-        return ("BatchScan", self.batch.capacity,
-                tuple((f.name, repr(f.dtype)) for f in self.batch.schema.fields),
-                hash(dicts))
+        return scan_plan_key("BatchScan", self.batch.capacity,
+                             self.batch.schema, self.batch.data)
 
 
 @dataclass(eq=False)

@@ -187,3 +187,19 @@ def spark():
     from spark_tpu.api.session import SparkSession
 
     return SparkSession.builder.getOrCreate()
+
+
+@pytest.fixture(params=["local", "mesh[4]"])
+def engine(request, spark):
+    """The session's single-device engine, or a mesh[4] session that
+    leaves the suite's own session as it found it."""
+    from spark_tpu.api.session import SparkSession
+
+    if request.param == "local":
+        yield spark
+        return
+    prev = SparkSession._active
+    SparkSession._reset()
+    yield SparkSession.builder.master(request.param).getOrCreate()
+    SparkSession._reset()
+    SparkSession._active = prev

@@ -203,22 +203,6 @@ def test_global_sum_keeps_the_plain_reduction(rng):
     assert (ev["rung"], ev["limbs"]) == ("reduce", True)
 
 
-@pytest.fixture(params=["local", "mesh[4]"])
-def engine(request, spark):
-    """The session's single-device engine, or a mesh[4] session that
-    leaves the suite's own session as it found it."""
-    from spark_tpu.api.session import SparkSession
-
-    if request.param == "local":
-        yield spark
-        return
-    prev = SparkSession._active
-    SparkSession._reset()
-    yield SparkSession.builder.master(request.param).getOrCreate()
-    SparkSession._reset()
-    SparkSession._active = prev
-
-
 def test_q1_sums_are_masked_and_q6_is_a_reduction(engine):
     """Both engines build TPC-H Q1's decimal sums (K = 6 slots) from the
     masked rung and Q6's global sum from the plain reduction."""

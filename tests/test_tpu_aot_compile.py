@@ -119,11 +119,15 @@ Q1_ROWS = 6_001_664  # lineitem at SF1 (6,000,647) in its 1,024-row bucket
 Q1_ROWS_SF10 = 59_990_016  # at SF10 (59,989,771): benchmark tpch_sf10_q1
 
 
-def _q1_stage_at(rows, sliver_sf, one_chip, spark, monkeypatch):
+def _q1_stage_at(rows, sliver_sf, one_chip, spark, monkeypatch,
+                 parquet_dir=None):
     """TPC-H Q1's fused stage, planned by the session on a sliver of
-    data and compiled for the described chip at ``rows`` rows."""
+    data and compiled for the described chip at ``rows`` rows. With
+    ``parquet_dir`` the tables are scanned from parquet written there,
+    as the benchmark's are: the scan decides what the device holds."""
     import spark_tpu.compile as compile_pkg
-    from spark_tpu.tpch.gen import generate_tables, register_views
+    from spark_tpu.tpch.gen import (generate_tables, register_views,
+                                    write_parquet)
     from spark_tpu.tpch.queries import QUERIES
 
     stages = []
@@ -135,7 +139,12 @@ def _q1_stage_at(rows, sliver_sf, one_chip, spark, monkeypatch):
 
     monkeypatch.setattr(compile_pkg, "build_stage_callable", capture)
     # an SF no other test uses: the stage is new to the process's cache
-    register_views(spark, generate_tables(sliver_sf, seed=27))
+    tables = generate_tables(sliver_sf, seed=27)
+    if parquet_dir is None:
+        register_views(spark, tables)
+    else:
+        write_parquet(tables, str(parquet_dir))
+        register_views(spark, path=str(parquet_dir))
     assert len(spark.sql(QUERIES[1]).collect()) == 4
     (stage,) = [s for s in stages if "Aggregate" in s[0].tree_string()]
     _, trace_fn, example_args = stage
@@ -163,6 +172,32 @@ def test_q1_fused_stage_has_no_group_slot_scatter_on_v5e(
     assert not scatters, scatters[:3]
 
 
+def _leaf_splits(text, rows):
+    """The X64SplitLow/High custom calls over a whole leaf column: what
+    the chip runs on every execution to turn an s64 *parameter* into the
+    u32 pairs it computes with (1.41 ms each at SF10; PERF.md, PR 29)."""
+    return [line for line in text.splitlines()
+            if "X64Split" in line and f"u32[{rows}]" in line]
+
+
+def test_q1_stage_over_a_parquet_scan_splits_no_leaf_column_on_v5e(
+        one_chip, as_the_session_runs, spark, monkeypatch, tmp_path):
+    """TPC-H's four decimal(12,2) inputs of Q1 fit 32 bits, so a scan
+    keeps them on the device as int32 and the stage widens them where
+    the convert fuses into its consumers: no split of a leaf column is
+    left, and no fusion is added to the 85 the stage had over int64
+    parameters (sandbox, PR 30). In-memory tables (createDataFrame) are
+    not narrowed and keep their eight splits: the tripwire's control."""
+    _, wide = _q1_stage_at(Q1_ROWS, 0.0029, one_chip, spark, monkeypatch)
+    assert len(_leaf_splits(wide, Q1_ROWS)) == 8
+    _, text = _q1_stage_at(Q1_ROWS, 0.0031, one_chip, spark, monkeypatch,
+                           parquet_dir=tmp_path)
+    assert not _leaf_splits(text, Q1_ROWS), _leaf_splits(text, Q1_ROWS)[:3]
+    fusions = re.findall(r"^\s*%?\S*fusion\S* = ", text, re.M)
+    print(f"q1 at {Q1_ROWS} rows over int32 leaves: {len(fusions)} fusions")
+    assert len(fusions) <= 85
+
+
 def _reduce_fusions(text):
     """The optimised program's grouped reductions: each such fusion is
     one pass over its operands (one to an instruction, at its `= `)."""
@@ -170,12 +205,14 @@ def _reduce_fusions(text):
 
 
 @pytest.fixture(scope="module")
-def q1_stage_at_sf10(one_chip, spark):
+def q1_stage_at_sf10(one_chip, spark, tmp_path_factory):
     """Compiled once for the tests that read it: the session's cache
-    holds the stage after the first plan, so a second capture finds none."""
+    holds the stage after the first plan, so a second capture finds none.
+    Scanned from parquet, as the benchmark's tpch_sf10_q1 is."""
     with _session_settings(), pytest.MonkeyPatch.context() as monkeypatch:
         return _q1_stage_at(Q1_ROWS_SF10, 0.0028, one_chip, spark,
-                            monkeypatch)
+                            monkeypatch,
+                            parquet_dir=tmp_path_factory.mktemp("sf10"))
 
 
 def test_q1_fused_stage_fits_one_v5e_at_sf10(q1_stage_at_sf10):
@@ -190,7 +227,9 @@ def test_q1_fused_stage_fits_one_v5e_at_sf10(q1_stage_at_sf10):
     print(f"q1 at {Q1_ROWS_SF10} rows: arguments {m.argument_size_in_bytes}"
           f" temporaries {m.temp_size_in_bytes} outputs "
           f"{m.output_size_in_bytes}")
-    assert 2e9 < m.argument_size_in_bytes
+    # 25 B a row: four decimal columns resident as int32 (their values
+    # fit; 41 B a row and 2.46 GB as int64), two int32 codes, the mask
+    assert 1.4e9 < m.argument_size_in_bytes < 1.6e9
     assert total < 16e9
 
 

@@ -10,6 +10,13 @@ reductions of G slots; int64 sum, count, f32 min; SF1's and SF10's rows):
   chiprun -- python tools/probe_seg_sum.py            # the rungs, ~5 min
   chiprun -- python tools/probe_seg_sum.py --small-k  # K <= 64 only
   chiprun --timeout 1800 -- python tools/probe_seg_sum.py --passes  # ~6 min
+  chiprun -- python tools/probe_seg_sum.py --narrow   # PR 31, ~2 min
+
+``--narrow``: the K = 6 sum (and q1's five sums and a count) over values
+that fit 32 bits, from int64 parameters against int32 parameters widened
+first thing in the program, at SF10's rows: what the chip's split of an
+int64 parameter into u32 pairs costs an execution, and what a scan that
+keeps such a column as int32 saves.
 
 Every variant is written out from primitives here, so the probe reads the
 same after the engine's own choice changes. Each line of output is one
@@ -65,6 +72,27 @@ def _k_passes(x, seg, mask, k, red, init):
 
 def _masked(x, seg, mask, k):
     return _k_passes(x, seg, mask, k, jnp.sum, 0)
+
+
+def _cumsum(x, seg, mask, k):
+    masked = jnp.where(mask, x, jnp.zeros((), x.dtype))
+    return K._sorted_seg_sum(masked, seg, k)
+
+
+def variants(k, sorted_seg):
+    """name -> fn(data, seg, mask): each rung on the int64 column itself
+    and on its three f64 limbs."""
+    rungs = {"scatter": _scatter}
+    if k <= K._MASKED_SEG_LIMIT:
+        rungs["masked"] = _masked
+    if sorted_seg:
+        rungs["cumsum"] = _cumsum
+    out = {}
+    for name, red in rungs.items():
+        out[f"limb_{name}"] = lambda d, s, m, red=red: _limbs(
+            d, lambda x: red(x, s, m, k))
+        out[f"int64_{name}"] = lambda d, s, m, red=red: red(d, s, m, k)
+    return out
 
 
 def _one_pass(x, seg, mask, k, g, combine, init):
@@ -176,6 +204,40 @@ def probe_passes(rows_list, reps=20):
     return bad
 
 
+def probe_narrow(rows, reps=20):
+    """One pass of K = 6 slots over columns whose values fit int32, as
+    int64 parameters and as int32 parameters the program widens."""
+    k = 6
+    rng = np.random.default_rng(31)
+    # l_extendedprice's range in TPC-H, unscaled: the widest of q1's four
+    cols = [rng.integers(0, 10_494_951, rows, dtype=np.int64)
+            for _ in range(5)]
+    mask = rng.random(rows) < 0.98
+    seg = rng.integers(0, k, rows, dtype=np.int64)
+    d_seg, d_mask = jnp.asarray(seg), jnp.asarray(mask)
+    refs = ([_pass_reference("sum_int64", c, seg, mask, k) for c in cols]
+            + [_pass_reference("count", cols[0], seg, mask, k)])
+    one = _pass_variant("sum_int64", k, k)
+    count = _pass_variant("count", k, k)
+
+    def stage(cs, s, m):
+        cs = [c.astype(jnp.int64) for c in cs]
+        return [one(c, s, m) for c in cs] + [count(cs[0], s, m)]
+
+    bad = 0
+    for param in ("int64", "int32"):
+        d_cols = [jnp.asarray(c.astype(param)) for c in cols]
+        bad += _measure(
+            {"rows": rows, "op": "sum_int64", "k": k, "param": param},
+            lambda x, s, m: [one(x.astype(jnp.int64), s, m)],
+            (d_cols[0], d_seg, d_mask), reps, refs[:1])
+        bad += _measure(
+            {"rows": rows, "op": "q1_stage", "k": k, "param": param},
+            stage, (d_cols, d_seg, d_mask), reps, refs)
+        del d_cols
+    return bad
+
+
 def _time(fn, args, reps):
     """(blocking median ms, pipelined mean ms, compile s, result)."""
     t0 = time.perf_counter()
@@ -206,6 +268,9 @@ def main() -> int:
     ap.add_argument("--passes", action="store_true",
                     help="only the masked rung by pass count, at SF1's "
                     "and SF10's rows (or at --rows)")
+    ap.add_argument("--narrow", action="store_true",
+                    help="only int64 against widened int32 parameters, "
+                    "at SF10's rows (or at --rows)")
     ap.add_argument("--rows", type=int)
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearsal only: times mean nothing")
@@ -214,6 +279,10 @@ def main() -> int:
     if dev.platform != "tpu" and not a.allow_cpu:
         print("probe_seg_sum: no TPU", file=sys.stderr)
         return 2
+    if a.narrow:
+        rows = a.rows or ROWS_SF10
+        print(json.dumps({"device": dev.device_kind, "rows": rows}))
+        return 1 if probe_narrow(rows) else 0
     if a.passes:
         rows_list = [a.rows] if a.rows else [ROWS, ROWS_SF10]
         print(json.dumps({"device": dev.device_kind, "rows": rows_list}))
