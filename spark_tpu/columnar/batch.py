@@ -32,7 +32,8 @@ from spark_tpu import trace
 from spark_tpu.types import Field, Schema
 
 # jitted column-packers for single-transfer host fetches, keyed on
-# (capacity, per-array kind/dtype signature)
+# (capacity, per-array kind/dtype signature); a mesh's packers
+# (parallel/sharded.py::_mesh_packer) on (mesh, that signature)
 _PACKER_CACHE: dict = {}
 
 # one spare thread for overlapping the float-plane fetch with the
@@ -40,6 +41,24 @@ _PACKER_CACHE: dict = {}
 import concurrent.futures as _cf
 
 _FETCH_POOL = _cf.ThreadPoolExecutor(max_workers=1)
+
+
+def pack(ints, flts):
+    """The packers' program: an int64 and a float64 plane."""
+    iplane = jnp.stack([x.astype(jnp.int64) for x in ints])
+    fplane = (jnp.stack([x.astype(jnp.float64) for x in flts])
+              if flts else jnp.zeros((0, 0), jnp.float64))
+    return iplane, fplane
+
+
+def _local_packer(sig):
+    """The jitted packer of one device's arrays of signature ``sig``."""
+    packer = _PACKER_CACHE.get(sig)
+    if packer is None:
+        import jax
+
+        packer = _PACKER_CACHE[sig] = jax.jit(pack)
+    return packer
 
 
 class ColumnData(NamedTuple):
@@ -147,9 +166,11 @@ class Batch:
         so an 8-column result costs 8 of them. Here a tiny jitted packer
         casts every column (+mask/validity) into at most two (k,
         capacity) planes, each fetched with a single transfer, then
-        host-side views restore the dtypes. (Not measured on a directly
-        attached chip; whether two planes beat per-array fetches there
-        is an open question, ROADMAP.md Queue A.)
+        host-side views restore the dtypes. (On a directly attached
+        v5e a result of one integer plane costs 0.34-0.43 ms of
+        ``fetch.copy`` an execution, PERF.md section 5; whether two
+        planes beat per-array fetches there has no probe yet,
+        ROADMAP.md A8.)
 
         Spans: ``query.fetch`` is the whole of it (its self time: the
         packer's dispatch and the host-side views); ``device.wait`` is
@@ -161,13 +182,23 @@ class Batch:
             return self._fetch_host()
 
     def _fetch_host(self):
+        return self._fetch_packed(self.data, _local_packer)
+
+    @staticmethod
+    def _fetch_packed(data: BatchData, packer_for, **copy_fields):
+        """``fetch_host`` of ``data``, packed by ``packer_for(sig)``
+        (``sig``: the capacity and each array's plane and dtype).
+        Arrays sharded over a mesh take a packer whose planes stay
+        sharded (``parallel/sharded.py::MeshResult``): the copies then
+        gather the shards. ``copy_fields`` go on the ``fetch.copy``
+        span."""
         import jax
 
-        cols = self.data.columns
+        cols = data.columns
         # two planes (value-preserving casts only, no 64-bit
         # bitcasts): ints/bools stack as int64, floats stack as float64
         plan = [("i", 0, jnp.bool_)]  # (plane, slot, dtype) for mask
-        int_arrays = [self.data.row_mask]
+        int_arrays = [data.row_mask]
         flt_arrays = []
         extra_arrays = []  # 2D array columns: fetched individually
         for cd in cols:
@@ -183,17 +214,8 @@ class Batch:
             if cd.validity is not None:
                 plan.append(("i", len(int_arrays), jnp.bool_))
                 int_arrays.append(cd.validity)
-        sig = (self.capacity, tuple((p, str(d)) for p, _, d in plan))
-        packer = _PACKER_CACHE.get(sig)
-        if packer is None:
-            def pack(ints, flts):
-                iplane = jnp.stack([x.astype(jnp.int64) for x in ints])
-                fplane = (jnp.stack([x.astype(jnp.float64) for x in flts])
-                          if flts else jnp.zeros((0, 0), jnp.float64))
-                return iplane, fplane
-
-            packer = jax.jit(pack)
-            _PACKER_CACHE[sig] = packer
+        sig = (data.capacity, tuple((p, str(d)) for p, _, d in plan))
+        packer = packer_for(sig)
         iplane, fplane = packer(tuple(int_arrays), tuple(flt_arrays))
         # start the copies behind the programs that make their sources,
         # then wait: a wait with no copy in flight costs this chip a round
@@ -205,7 +227,7 @@ class Batch:
                 x.copy_to_host_async()
         with trace.span("device.wait"):
             jax.block_until_ready(pending)
-        with trace.span("fetch.copy"):
+        with trace.span("fetch.copy", **copy_fields):
             if fplane.size:
                 # fetch the two planes CONCURRENTLY: device_get walks
                 # the tree serially and each blocking transfer pays a
@@ -234,13 +256,13 @@ class Batch:
         out = []
         i = 1
         for cd in cols:
-            data = restore(*plan[i])
+            values = restore(*plan[i])
             i += 1
             valid = None
             if cd.validity is not None:
                 valid = restore(*plan[i])
                 i += 1
-            out.append((data, valid))
+            out.append((values, valid))
         return mask, out
 
     def to_pylist(self) -> list:

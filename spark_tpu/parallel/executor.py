@@ -34,7 +34,7 @@ from spark_tpu.columnar.batch import Batch
 from spark_tpu.expr import expressions as E
 from spark_tpu.parallel import operators as D
 from spark_tpu.parallel.mesh import DATA_AXIS, mesh_size
-from spark_tpu.parallel.sharded import ShardedBatch
+from spark_tpu.parallel.sharded import MeshResult, ShardedBatch
 from spark_tpu.physical import kernels as K
 from spark_tpu.physical import operators as P
 from spark_tpu.physical import stage
@@ -325,10 +325,10 @@ class MeshExecutor:
                 plan = opt(plan)
         with _trace.span("query.plan"):
             physical = self.plan(plan)
-        sharded = self.run(physical)
-        with _trace.span("fetch.copy", op="gather"):
-            # the shards come to the host and go back as one batch
-            return sharded.to_batch()
+        # the result stays sharded: its fetch packs on the mesh and the
+        # copies to the host gather the shards (span ``fetch.copy
+        # op=gather``); its ``data`` gathers to the first device
+        return MeshResult(self.run(physical))
 
     # ---- logical -> distributed physical -----------------------------------
 

@@ -16,14 +16,17 @@ construction; unordered inputs are dealt round-robin for balance.
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from spark_tpu.columnar.batch import Batch, BatchData, ColumnData
+from spark_tpu import trace
+from spark_tpu.columnar.batch import (_PACKER_CACHE, Batch, BatchData,
+                                       ColumnData, _local_packer, pack)
 from spark_tpu.parallel.mesh import DATA_AXIS, mesh_size
 from spark_tpu.physical.kernels import bucket
 from spark_tpu.types import Schema
@@ -98,22 +101,116 @@ class ShardedBatch:
                    mesh)
 
     def to_batch(self) -> Batch:
-        """Gather to one host batch. Flat order = global row order."""
-        cols = tuple(
-            ColumnData(np.asarray(cd.data),
-                       None if cd.validity is None else np.asarray(cd.validity))
-            for cd in self.data.columns)
-        import jax.numpy as jnp
+        """Gather to one single-device batch on the mesh's first device.
+        Flat order = global row order (the shards' concatenation along
+        axis 0, 2-D array columns included).
 
-        return Batch(self.schema,
-                     BatchData(tuple(
-                         ColumnData(jnp.asarray(c.data),
-                                    None if c.validity is None
-                                    else jnp.asarray(c.validity))
-                         for c in cols),
-                         jnp.asarray(np.asarray(self.data.row_mask))))
+        ONE batched transfer through the host, whatever the result's
+        size: every shard's copy to the host is started at once, numpy
+        assembles them, and one ``device_put`` of the whole tree hands
+        them to the first device. No array comes to the host and goes
+        back on its own, and the devices hold their shards and the one
+        copy asked for (a replicated gather would hold D copies). The
+        copies are waited for together.
+
+        Span ``fetch.copy`` with ``op=gather``, ``path=host``,
+        ``arrays`` and ``bytes``.
+
+        ``MeshResult.data`` calls this for a consumer that needs the
+        arrays on a device (``cache()``, ``ml``); rows for the host
+        never come this way."""
+        leaves, treedef = jax.tree_util.tree_flatten(self.data)
+        with trace.span("fetch.copy", op="gather", path="host",
+                        arrays=len(leaves),
+                        bytes=sum(x.nbytes for x in leaves)):
+            for x in leaves:
+                x.copy_to_host_async()
+            one = jax.device_put([np.asarray(x) for x in leaves],
+                                 self.mesh.devices.flat[0])
+        return Batch(self.schema, treedef.unflatten(one))
 
     def __repr__(self):
         return (f"ShardedBatch(D={mesh_size(self.mesh)}, "
                 f"per_device={self.per_device_capacity}, "
                 f"schema={list(self.schema.names)})")
+
+
+class MeshResult(Batch):
+    """A finished mesh query's result as the ``Batch`` every consumer
+    takes, its arrays still sharded over the mesh.
+
+    ``fetch_host`` (``collect``, ``toArrow``, ``toPandas``, the connect
+    server) packs ON the mesh, each device its own shard, into planes
+    that stay sharded, and the planes' copies to the host are the
+    gather: one program, no collective, nothing replicated, one wait
+    (span ``fetch.copy`` with ``op=gather``, ``path=planes``,
+    ``arrays``, ``bytes``). Flat order = global row order: numpy
+    assembles the shards along the row axis.
+
+    A consumer that needs arrays on one device reads ``data``, which
+    gathers once (``ShardedBatch.to_batch``) and lets the shards go:
+    from then on this is a one-device batch, fetch included. What only
+    counts (``capacity``, ``num_valid_rows``, ``device_nbytes``,
+    ``narrowed``: the result cache's accounting) reads whichever copy
+    is held and gathers nothing."""
+
+    __slots__ = ("_held",)
+
+    def __init__(self, sharded: ShardedBatch):
+        self.schema = sharded.schema
+        #: (the arrays, the mesh they are sharded over; None once
+        #: gathered), swapped as one so that a reader sees a pair
+        self._held: Tuple[BatchData, Optional[Mesh]] = (sharded.data,
+                                                        sharded.mesh)
+
+    @property
+    def data(self) -> BatchData:
+        held, mesh = self._held
+        if mesh is not None:
+            held = ShardedBatch(self.schema, held, mesh).to_batch().data
+            self._held = (held, None)
+        return held
+
+    def _as_held(self) -> Batch:
+        return Batch(self.schema, self._held[0])
+
+    @property
+    def capacity(self) -> int:
+        return self._held[0].capacity
+
+    def num_valid_rows(self) -> int:
+        return self._as_held().num_valid_rows()
+
+    def device_nbytes(self) -> int:
+        return self._as_held().device_nbytes()
+
+    def narrowed(self) -> int:
+        return self._as_held().narrowed()
+
+    def _fetch_host(self):
+        held, mesh = self._held
+        if mesh is None:
+            return self._fetch_packed(held, _local_packer)
+        leaves = jax.tree_util.tree_leaves(held)
+        return self._fetch_packed(
+            held, functools.partial(_mesh_packer, mesh),
+            op="gather", path="planes", arrays=len(leaves),
+            bytes=sum(x.nbytes for x in leaves))
+
+
+def _mesh_packer(mesh: Mesh, sig):
+    """``batch.pack`` for arrays of signature ``sig`` sharded over
+    ``mesh``, jitted so that its planes stay sharded along the rows.
+    Cached beside the one-device packers, under ``(mesh, sig)``."""
+    packer = _PACKER_CACHE.get((mesh, sig))
+    if packer is None:
+        planes = NamedSharding(mesh, P(None, DATA_AXIS))
+        # an all-integer result's float plane is empty, and the chip's
+        # compiler replicates an empty output whatever it is told
+        floats = (planes if any(plane == "f" for plane, _ in sig[1])
+                  else NamedSharding(mesh, P()))
+        packer = _PACKER_CACHE[mesh, sig] = jax.jit(
+            pack, out_shardings=(planes, floats))
+        trace.built("gather", mesh=mesh_size(mesh),
+                    capacity=sig[0], arrays=len(sig[1]))
+    return packer

@@ -128,6 +128,9 @@ BUILD_EVENTS = frozenset({
     "join",      # JoinExec.trace: rung (table/index/live), how,
                  # orientation, build rows, probe capacity
     "sort",      # kernels: one per XLA sort built; site, rows, dtype
+    "gather",    # parallel/sharded.py: one per mesh packer built (the
+                 # program a MeshResult's fetch leaves the mesh by);
+                 # mesh, capacity, arrays
 })
 
 #: prefix of the ``jax.named_scope`` round each operator's ``trace()``

@@ -259,3 +259,55 @@ def test_int64_seg_sum_with_64_slots_compiles_at_sf10(one_chip,
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.temp_size_in_bytes
             + m.output_size_in_bytes) < 16e9
+
+
+# q1's result on mesh[4] at SF1 (24 rows a chip), and a bare scan's
+MESH_RESULT_CAPACITIES = (96, 4 * 1_500_416)
+
+
+def _mesh_result_arrays(topo, capacity, floats):
+    """(mesh, int arrays, float arrays) of a result sharded over the
+    four described chips along the rows."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_tpu.parallel.mesh import DATA_AXIS
+
+    mesh = Mesh(np.array(topo.devices), (DATA_AXIS,))
+    rows = NamedSharding(mesh, P(DATA_AXIS))
+
+    def arrays(shape, *dtypes):
+        return tuple(jax.ShapeDtypeStruct(shape, dt, sharding=rows)
+                     for dt in dtypes)
+
+    return (mesh,
+            arrays((capacity,), jnp.bool_, jnp.int32, jnp.int32, jnp.int64,
+                   jnp.bool_, jnp.int64, jnp.bool_),
+            arrays((capacity,), *(jnp.float64, jnp.float32)[:floats]))
+
+
+@pytest.mark.parametrize("floats", [0, 2])
+@pytest.mark.parametrize("capacity", MESH_RESULT_CAPACITIES)
+def test_mesh_result_packer_keeps_its_planes_sharded_on_v5e(
+        topo, as_the_session_runs, capacity, floats):
+    """``MeshResult.fetch_host``'s program for the four chips: each packs
+    its own shard, so no collective, and the planes stay sharded along
+    the rows. An all-integer result's float plane is empty: the chip's
+    compiler replicates it whatever ``out_shardings`` says and jax then
+    refuses the program (the CPU's does neither; PR 34's second chip
+    call died of it)."""
+    from spark_tpu.parallel import sharded as S
+
+    mesh, ints, flts = _mesh_result_arrays(topo, capacity, floats)
+    sig = (capacity, tuple(("i", str(x.dtype)) for x in ints)
+           + tuple(("f", str(x.dtype)) for x in flts))
+    compiled = S._mesh_packer(mesh, sig).lower(ints, flts).compile()
+    text = compiled.as_text()
+    assert not re.search(r"all-gather|all-reduce|all-to-all|collective-permute",
+                         text)
+    iplane, fplane = compiled.output_shardings
+    assert iplane.shard_shape((len(ints), capacity)) == (len(ints),
+                                                         capacity // 4)
+    if floats:
+        assert fplane.shard_shape((floats, capacity)) == (floats,
+                                                          capacity // 4)
