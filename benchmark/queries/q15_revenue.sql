@@ -1,0 +1,9 @@
+select max(total_revenue)
+from (
+  select l_suppkey as supplier_no,
+         sum(l_extendedprice * (1 - l_discount)) as total_revenue
+  from lineitem
+  where l_shipdate >= date '1996-01-01'
+    and l_shipdate < date '1996-01-01' + interval '3' month
+  group by l_suppkey
+) revenue0

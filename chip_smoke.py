@@ -7,10 +7,14 @@ calls (``SparkSession`` -> ``spark.read.parquet`` -> ``spark.sql(...)`` ->
 money columns), and checks every answer against a plain pandas recompute
 over the same parquet files that shares nothing with the engine.
 
-    python chip_smoke.py             one chip: device, load, tpch, pallas, native
+    python chip_smoke.py             one chip: device, load, tpch (q1, q6 and
+                                     Q15's join-free aggregate, with and
+                                     without the max over it), pallas, native
     python chip_smoke.py --chips 4   four chips: ONLY the mesh[4] phase and
                                      its reference
     ... --joins                      also q3 and q5 (see JOIN_QUERIES)
+    ... --sf 10                      another scale factor (the benchmark's
+                                     SF10: 100,000 suppliers' sums, one by one)
 
 Each phase prints one JSON line; the last line of stdout is
 ``{"ok": true, "device": {...}}`` with the device as jax reports it. Any
@@ -27,8 +31,10 @@ from __future__ import annotations
 
 import argparse
 import datetime
+import decimal
 import json
 import os
+import re
 import sys
 import tempfile
 import time
@@ -36,7 +42,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-SF = 1.0                      # TPC-H scale factor of every phase
+SF = 1.0                      # TPC-H scale factor of every phase (--sf)
 QUERIES = (1, 6)              # the default run: single-table scans + aggregates
 # q3 and q5 are left out of the default run BY NAME. Their first
 # (blocking) execution compiles 27 and 30 programs that hold an XLA sort
@@ -45,6 +51,18 @@ QUERIES = (1, 6)              # the default run: single-table scans + aggregates
 # tens of minutes cold, far past the 1200 s a default run may take.
 # ROADMAP.md Queue A (A2) has the fault; --joins runs them.
 JOIN_QUERIES = (3, 5)
+# Q15's aggregate alone (the view and the max over it: benchmark/queries/
+# q15_revenue.sql): join-free, so a default run covers the sort-based
+# aggregate, GROUP BY l_suppkey into 10,000 groups at SF1. The benchmark's
+# cell compares only the max, one sum of them all; REVENUE_VIEW is the same
+# file's derived table alone, every supplier's key and sum, compared
+# exactly. Cold, the two texts compile three programs that hold the
+# aggregate's two sorts (the keys' argsort and the live rows'): the
+# count's stage, the aggregate sized by it and the whole query's stage;
+# the view is the sized aggregate's own program, so it compiles nothing
+# more (360 s the whole default run on the v5e: PERF.md, PR 35).
+REVENUE = "15_revenue"
+REVENUE_VIEW = "15_revenue0"
 PALLAS_ROWS = 1 << 22         # 4M rows
 PALLAS_GROUPS = 200
 
@@ -166,11 +184,51 @@ def ref_q6(path: str) -> List[Tuple]:
     return [(int((li.l_extendedprice * li.l_discount).sum()) / 1e4,)]
 
 
-REFERENCE: Dict[int, Callable[[str], List[Tuple]]] = {
-    1: ref_q1, 3: ref_q3, 5: ref_q5, 6: ref_q6}
+def _money4(units) -> decimal.Decimal:
+    return decimal.Decimal(int(units)).scaleb(-4)
+
+
+def ref_q15_revenue0(path: str) -> List[Tuple]:
+    """Every supplier's revenue of the quarter: the exact decimals the
+    engine must return (integers in 1e-4 units, no float between)."""
+    li = _frame(path, "lineitem", ["l_suppkey", "l_extendedprice",
+                                   "l_discount", "l_shipdate"])
+    li = li[(li.l_shipdate >= _days(1996, 1, 1))
+            & (li.l_shipdate < _days(1996, 4, 1))]
+    revenue = (li.l_extendedprice * (100 - li.l_discount)).groupby(
+        li.l_suppkey).sum()
+    return [(int(k), _money4(v)) for k, v in revenue.items()]
+
+
+def ref_q15_revenue(path: str) -> List[Tuple]:
+    return [(max(v for _k, v in ref_q15_revenue0(path)),)]
+
+
+#: by TPC-H query number, or by name for a text of this file's own
+REFERENCE: Dict[object, Callable[[str], List[Tuple]]] = {
+    1: ref_q1, 3: ref_q3, 5: ref_q5, 6: ref_q6, REVENUE: ref_q15_revenue,
+    REVENUE_VIEW: ref_q15_revenue0}
+#: references in exact decimals: compared with ==, not within a tolerance
+EXACT = (REVENUE, REVENUE_VIEW)
 
 
 # ---- phases -----------------------------------------------------------------
+
+
+def query_text(q) -> str:
+    """A TPC-H query by number, the text the benchmark's cell
+    ``tpch_sf10_q15_revenue`` runs, or that text's derived table alone."""
+    if q in (REVENUE, REVENUE_VIEW):
+        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               "benchmark", "queries",
+                               "q15_revenue.sql")) as f:
+            text = f.read()
+        if q == REVENUE:
+            return text
+        return re.search(r"from \((.*)\) revenue0", text, re.S).group(1)
+    from spark_tpu.tpch.queries import QUERIES as SQL
+
+    return SQL[q]
 
 
 def _emit(phase: str, **fields) -> None:
@@ -208,21 +266,20 @@ def phase_load(spark, sf: float, base: Optional[str] = None) -> str:
     return path
 
 
-def phase_tpch(spark, path: str, queries: Sequence[int],
+def phase_tpch(spark, path: str, queries: Sequence[object],
                executions: int = 3, label: str = "tpch") -> None:
     """Each query ``executions`` times (1: blocking run that records the
     adaptive stats, 2: adaptive re-run, 3: fused steady state), every
     execution's rows against the pandas reference."""
     from spark_tpu import metrics
     from spark_tpu.tpch.oracle import assert_rows_match
-    from spark_tpu.tpch.queries import QUERIES as SQL
 
     for q in queries:
         t0 = time.perf_counter()
         want = REFERENCE[q](path)
         ref_s = time.perf_counter() - t0
         _check(bool(want), f"q{q}: the reference returned no rows")
-        df = spark.sql(SQL[q])
+        df = spark.sql(query_text(q))
         runs = []
         for i in range(executions):
             cache0 = metrics.compile_cache_stats()
@@ -231,7 +288,13 @@ def phase_tpch(spark, path: str, queries: Sequence[int],
             ms = (time.perf_counter() - t0) * 1e3
             print(f"[chip_smoke] {label} q{q} execution {i + 1}: "
                   f"{ms / 1e3:.1f} s", file=sys.stderr, flush=True)
-            assert_rows_match(got, want, label=f"q{q}[execution {i + 1}]")
+            if q in EXACT:
+                _check(sorted(got) == sorted(want),
+                       f"q{q}[execution {i + 1}]: {len(got)} rows are not "
+                       f"the reference's {len(want)}, exactly")
+            else:
+                assert_rows_match(got, want,
+                                  label=f"q{q}[execution {i + 1}]")
             evs = metrics.last_query()
             cache1 = metrics.compile_cache_stats()
             runs.append({
@@ -381,8 +444,12 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     ap.add_argument("--joins", action="store_true",
                     help="also run q3 and q5 (tens of minutes of sort "
                          "compiles when the compile cache is cold)")
+    ap.add_argument("--sf", type=float, default=SF,
+                    help="TPC-H scale factor of every phase (default 1)")
     args = ap.parse_args(argv)
     queries = tuple(sorted(QUERIES + (JOIN_QUERIES if args.joins else ())))
+    if args.chips == 1:
+        queries += (REVENUE, REVENUE_VIEW)
 
     import jax
 
@@ -409,13 +476,13 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
         spark = builder.master("mesh[4]").getOrCreate()
         _emit("session", master="mesh[4]",
               compile_cache_dir=jax.config.jax_compilation_cache_dir)
-        path = phase_load(spark, SF)
+        path = phase_load(spark, args.sf)
         phase_mesh(spark, path, devices[:4], queries)
     else:
         spark = builder.getOrCreate()
         _emit("session", master="local",
               compile_cache_dir=jax.config.jax_compilation_cache_dir)
-        path = phase_load(spark, SF)
+        path = phase_load(spark, args.sf)
         phase_tpch(spark, path, queries)
         phase_resident(devices[:1])
         phase_pallas(spark)

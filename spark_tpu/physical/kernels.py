@@ -375,10 +375,18 @@ def seg_first(data, seg, mask, num_segments: int, capacity: int,
     """Value of the first (by position) masked row in each segment."""
     pos = jnp.where(mask, jnp.arange(capacity), capacity)
     if sorted_seg:
-        first_pos = _sorted_seg_red(pos, seg, num_segments, jnp.minimum)
-        # empty segments read position `capacity`
+        # monotone ids: a segment's first masked row is the first masked
+        # position at or after its start, if that lies inside it. One
+        # reverse running minimum and not _sorted_seg_red's segmented
+        # scan, whose 40 levels of slices and pads the chip's compiler
+        # takes five times as long over as over the rest of an
+        # aggregate's stage (PERF.md, PR 35). Empty segments, and those
+        # with no masked row, read position `capacity`
+        following = jax.lax.cummin(pos, reverse=True)
         starts, ends = seg_bounds(seg, num_segments)
-        first_pos = jnp.where(ends >= starts, first_pos, capacity)
+        first_pos = following[jnp.clip(starts, 0, capacity - 1)]
+        first_pos = jnp.where((ends >= starts) & (first_pos <= ends),
+                              first_pos, capacity)
     elif num_segments <= _MASKED_SEG_LIMIT:
         first_pos = _masked_reduce(pos, seg, mask, num_segments,
                                    jnp.minimum, capacity)

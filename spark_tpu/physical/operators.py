@@ -1076,6 +1076,45 @@ def _compute_agg(agg: E.AggregateExpression, env: Env, seg, mask,
 
 
 @dataclass(eq=False)
+class GroupCountExec(PhysicalPlan):
+    """The number of groups of ``groupings`` among the child's live
+    rows, as one row: the count that sizes the sort-based aggregate's
+    output. The planner runs it as a stage of its own on the first
+    execution of such an aggregate (``planner._sized_aggregate``): the
+    keys are sorted once here to be counted and once more by the
+    aggregate built for the count, which costs a tenth of a second on
+    the chip where the same work run op by op compiled some 550
+    programs (2,093 s cold at SF10; PERF.md, PR 35)."""
+
+    groupings: Tuple[E.Expression, ...]
+    child: PhysicalPlan
+    traceable = True
+
+    def children(self):
+        return (self.child,)
+
+    @property
+    def schema(self) -> Schema:
+        return Schema((Field("groups", T.INT64, nullable=False),))
+
+    def trace(self, child_pipes: List[Pipe]) -> Pipe:
+        pipe = child_pipes[0]
+        key_tvs = [C.evaluate(g, pipe.env()) for g in self.groupings]
+        # the gathers of the columns nobody reads here are dropped by XLA
+        _, _, _, ng = sorted_groups(pipe, key_tvs)
+        return Pipe({"groups": TV(ng.astype(jnp.int64)[None], None, T.INT64,
+                                  None)},
+                    jnp.ones((1,), dtype=jnp.bool_), ["groups"])
+
+    def node_string(self):
+        return f"GroupCount[keys=[{', '.join(map(str, self.groupings))}]]"
+
+    def plan_key(self):
+        return ("GroupCount", tuple(E.expr_key(g) for g in self.groupings),
+                self.child.plan_key())
+
+
+@dataclass(eq=False)
 class HashAggregateExec(PhysicalPlan):
     """Group-by aggregation (reference: HashAggregateExec.scala:47 +
     TungstenAggregationIterator.scala:82 over BytesToBytesMap.java).
@@ -1101,12 +1140,31 @@ class HashAggregateExec(PhysicalPlan):
         return (self.child,)
 
     @property
+    def sorted_slots(self) -> Optional[int]:
+        """Output slots of the traced sort-based path: all a stage keeps
+        of ``adaptive`` (the live groups are counted on the device), so
+        what the stage cache tells two bindings apart by."""
+        return (None if self.adaptive is None
+                else K.bucket(max(1, self.adaptive), 256))
+
+    def _collects(self) -> bool:
+        return any(isinstance(a, E.Collect)
+                   for e in self.aggregates
+                   for a in E.collect_aggregates(e))
+
+    @property
     def traceable(self) -> bool:  # type: ignore[override]
-        if any(isinstance(a, E.Collect)
-               for e in self.aggregates
-               for a in E.collect_aggregates(e)):
+        if self._collects():
             return False  # output width = largest group: blocking only
         return self._static_direct_ok() or self.adaptive is not None
+
+    @property
+    def wants_group_count(self) -> bool:
+        """Blocks for want of its group count alone: a stage once the
+        planner has counted (``planner._sized_aggregate``)."""
+        return (bool(self.groupings) and self.adaptive is None
+                and not self._collects() and not self._static_direct_ok()
+                and not self.has_blocking_exprs())
 
     def _static_direct_ok(self) -> bool:
         """Can we guarantee the direct path from schema info alone?"""
@@ -1150,6 +1208,14 @@ class HashAggregateExec(PhysicalPlan):
         return Schema(tuple(fields))
 
     # -- shared epilogue ------------------------------------------------------
+
+    def _built(self, strategy: str, key_tvs: List[TV], rows: int, k: int,
+               groups: Optional[int]) -> None:
+        """The ``group_by`` build event: what this aggregate was built
+        as (never recorded by an execution of a compiled stage)."""
+        _trace.built("group_by", strategy=strategy,
+                     keys=[str(tv.data.dtype) for tv in key_tvs],
+                     rows=int(rows), k=int(k), groups=groups)
 
     def _finalize(self, key_tvs: List[TV], agg_tvs: List[TV],
                   out_mask: jnp.ndarray, num_segments: int) -> Pipe:
@@ -1199,6 +1265,7 @@ class HashAggregateExec(PhysicalPlan):
             seg, num_segments = K.pack_codes(codes, validities, cards)
             seg = seg.astype(jnp.int32)
 
+        self._built("direct", key_tvs, cap, num_segments, None)
         _, agg_calls = rewrite_agg_outputs(self.groupings, self.aggregates)
         agg_tvs = [_compute_agg(a, env, seg, pipe.mask, num_segments, cap,
                                 merge=merge)
@@ -1221,25 +1288,45 @@ class HashAggregateExec(PhysicalPlan):
                               max(1, num_segments))
 
     # -- sort-based path ------------------------------------------------------
+    #
+    # Two halves, each under a scope of its own inside the operator's
+    # (trace.INNER_SCOPES), so a profile tells the sort and its gathers
+    # from the sums. The direct path opens neither: its operations stay
+    # under spark.HashAggregateExec.
+
+    def _group_sort(self, pipe: Pipe, key_tvs: List[TV]):
+        with _trace.inner_scope("GroupSort"):
+            return sorted_groups(pipe, key_tvs)
+
+    def _group_sum(self, spipe: Pipe, sorted_keys: List[TV], seg, n_groups,
+                   num_segments: int, groups: int) -> Pipe:
+        """Every aggregate over the sorted group ids ``seg`` and the
+        groups' first keys, into ``num_segments`` slots (sized from the
+        ``groups`` observed) of which the first ``n_groups`` (traced, or
+        the same count on the host) are live."""
+        cap = spipe.capacity
+        self._built("sorted", sorted_keys, cap, num_segments, groups)
+        with _trace.inner_scope("GroupSum"):
+            env = spipe.env()
+            _, agg_calls = rewrite_agg_outputs(self.groupings,
+                                               self.aggregates)
+            agg_tvs = [_compute_agg(a, env, seg, spipe.mask, num_segments,
+                                    cap, sorted_seg=True)
+                       for a in agg_calls]
+            out_keys = first_group_keys(sorted_keys, seg, spipe.mask,
+                                        num_segments, cap, sorted_seg=True)
+        out_mask = jnp.arange(num_segments) < n_groups
+        return self._finalize(out_keys, agg_tvs, out_mask, num_segments)
 
     def _trace_sorted(self, pipe: Pipe) -> Pipe:
         """Sort-based aggregation with STATIC output capacity from
         adaptive stats (the group count observed on the first, blocking
         execution of these exact leaf arrays) — no host sync, fusable."""
-        env = pipe.env()
-        cap = pipe.capacity
-        key_tvs = [C.evaluate(g, env) for g in self.groupings]
-        pipe2, sorted_keys, seg, ng = sorted_groups(pipe, key_tvs)
-        num_segments = K.bucket(max(1, self.adaptive), 256)
-        env2 = pipe2.env()
-        _, agg_calls = rewrite_agg_outputs(self.groupings, self.aggregates)
-        agg_tvs = [_compute_agg(a, env2, seg, pipe2.mask, num_segments, cap,
-                                sorted_seg=True)
-                   for a in agg_calls]
-        out_keys = first_group_keys(sorted_keys, seg, pipe2.mask,
-                                    num_segments, cap, sorted_seg=True)
-        out_mask = jnp.arange(num_segments) < ng  # ng stays on device
-        return self._finalize(out_keys, agg_tvs, out_mask, num_segments)
+        key_tvs = [C.evaluate(g, pipe.env()) for g in self.groupings]
+        spipe, sorted_keys, seg, ng = self._group_sort(pipe, key_tvs)
+        # ng stays on device
+        return self._group_sum(spipe, sorted_keys, seg, ng,
+                               self.sorted_slots, self.adaptive)
 
     def execute_blocking(self, child_batches: List[Batch]) -> Batch:
         pipe = Pipe.from_batch_data(child_batches[0].schema,
@@ -1250,27 +1337,22 @@ class HashAggregateExec(PhysicalPlan):
         cap = pipe.capacity
         key_tvs = [C.evaluate(g, env) for g in self.groupings]
 
-        if not key_tvs:
-            seg = jnp.zeros((cap,), dtype=jnp.int32)
-            pipe2, n_groups = pipe, 1
-            sorted_keys: List[TV] = []
-        else:
-            pipe2, sorted_keys, seg, ng = sorted_groups(pipe, key_tvs)
+        if key_tvs:
+            spipe, sorted_keys, seg, ng = self._group_sort(pipe, key_tvs)
             n_groups = max(1, int(ng))  # host sync: output sizing
             _AGG_STATS.put(self.stats_key(), n_groups)
+            return self._group_sum(spipe, sorted_keys, seg, n_groups,
+                                   K.bucket(n_groups, 256),
+                                   n_groups).to_batch()
 
-        num_segments = K.bucket(n_groups, 256)
-        env2 = pipe2.env()
+        # no key and not traceable (a Collect): one group of every row
+        num_segments = K.bucket(1, 256)
+        seg = jnp.zeros((cap,), dtype=jnp.int32)
         _, agg_calls = rewrite_agg_outputs(self.groupings, self.aggregates)
-        sorted_seg = bool(key_tvs)
-        agg_tvs = [_compute_agg(a, env2, seg, pipe2.mask, num_segments, cap,
-                                sorted_seg=sorted_seg)
+        agg_tvs = [_compute_agg(a, env, seg, pipe.mask, num_segments, cap)
                    for a in agg_calls]
-        out_keys = first_group_keys(sorted_keys, seg, pipe2.mask,
-                                    num_segments, cap, sorted_seg=sorted_seg)
-        out_mask = jnp.arange(num_segments) < n_groups
-        return self._finalize(out_keys, agg_tvs, out_mask,
-                              num_segments).to_batch()
+        out_mask = jnp.arange(num_segments) < 1
+        return self._finalize([], agg_tvs, out_mask, num_segments).to_batch()
 
     def node_string(self):
         return (f"HashAggregate[keys=[{', '.join(map(str, self.groupings))}], "

@@ -17,6 +17,7 @@ stage-boundary analogue, reference: AdaptiveSparkPlanExec.scala:247).
 
 from __future__ import annotations
 
+import dataclasses
 import operator
 
 import numpy as np
@@ -135,7 +136,12 @@ def _adaptive_snapshot(plan: P.PhysicalPlan,
                         else scan_key(p.index_scan),
                         None if p.table_scan is None
                         else scan_key(p.table_scan)))
-        elif isinstance(p, (P.HashAggregateExec, P.GenerateExec)):
+        elif isinstance(p, P.HashAggregateExec):
+            # the group count sizes the output in 256-slot buckets and
+            # is counted on the device: a count that moves inside its
+            # bucket finds the stage it had
+            out.append(p.sorted_slots)
+        elif isinstance(p, P.GenerateExec):
             out.append(p.adaptive)
         elif isinstance(p, P.CompactExec):
             # plan_key is transparent for stats stability; the snapshot
@@ -300,6 +306,20 @@ def _run_bound(plan: P.PhysicalPlan, sk, cap) -> Batch:
     return batch
 
 
+def _sized_aggregate(plan: P.HashAggregateExec,
+                     batch: Batch) -> P.HashAggregateExec:
+    """The first execution of an aggregate whose keys have no trace-time
+    cardinality: count its groups in one compiled program (the host
+    sync that sizes the output), record the count for re-executions of
+    these leaves (``_bind_adaptive``), and hand back the aggregate bound
+    to it over its child's batch — a traceable stage."""
+    scan = P.BatchScanExec(batch)
+    counted = _execute(P.GroupCountExec(plan.groupings, scan))
+    groups = max(1, int(np.asarray(counted.data.columns[0].data)[0]))
+    P._AGG_STATS.put(plan.stats_key(), groups)
+    return dataclasses.replace(plan, child=scan, adaptive=groups)
+
+
 def _execute(plan: P.PhysicalPlan) -> Batch:
     from spark_tpu import metrics
 
@@ -313,6 +333,8 @@ def _execute(plan: P.PhysicalPlan) -> Batch:
     for c in plan.children():
         b = _execute(c)
         child_batches.append(_maybe_compact(b, c))
+    if isinstance(plan, P.HashAggregateExec) and plan.wants_group_count:
+        return _execute(_sized_aggregate(plan, child_batches[0]))
     with trace.span("stage.run", op=type(plan).__name__), \
             metrics.stage_timer("blocking", node=plan.node_string(),
                                 cap_in=[b.capacity

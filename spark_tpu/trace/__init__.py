@@ -131,12 +131,30 @@ BUILD_EVENTS = frozenset({
     "gather",    # parallel/sharded.py: one per mesh packer built (the
                  # program a MeshResult's fetch leaves the mesh by);
                  # mesh, capacity, arrays
+    "group_by",  # HashAggregateExec: one per aggregate built; strategy
+                 # (direct/sorted), keys (their dtypes as they travel),
+                 # rows (capacity), k (num_segments), groups (the
+                 # _AGG_STATS count k was sized from; None when direct)
 })
 
 #: prefix of the ``jax.named_scope`` round each operator's ``trace()``
 #: in a fused stage: device operations carry ``spark.<Operator>`` in
 #: their ``op_name`` (lowered text, HLO metadata, the profiler's trace)
 SCOPE_PREFIX = "spark."
+
+
+#: scopes INSIDE an operator's own, for the halves of one operator that
+#: a trace must tell apart. A device operation is booked to its
+#: innermost ``spark.*`` scope (benchmark/op_scopes.py::scope_of), so an
+#: inner scope takes its operations away from the operator's reading.
+#: lint_invariants rule 6 holds every ``trace.inner_scope("<name>")``
+#: literal to this set.
+INNER_SCOPES = frozenset({
+    "GroupSort",  # sort-based aggregate: the lexsort of the grouping
+                  # keys, the gathers by it, the change-flag group ids
+    "GroupSum",   # sort-based aggregate: the aggregates over the sorted
+                  # group ids and the groups' first keys
+})
 
 
 def built(kind: str, **fields: Any) -> None:
@@ -149,6 +167,13 @@ def operator_scope(plan: Any):
     Names only: the compiled program and its cache keys are the same
     with and without it."""
     return jax.named_scope(SCOPE_PREFIX + type(plan).__name__)
+
+
+def inner_scope(name: str):
+    """``jax.named_scope("spark.<name>")`` for one of ``INNER_SCOPES``,
+    opened inside an operator's ``trace()``. Names only, as
+    ``operator_scope``."""
+    return jax.named_scope(SCOPE_PREFIX + name)
 
 
 class SpanContext(NamedTuple):

@@ -138,7 +138,7 @@ def smoke_reference(tmp_path_factory):
     return load_sqlite(tables), path
 
 
-@pytest.mark.parametrize("qnum", [1, 3, 5, 6])
+@pytest.mark.parametrize("qnum", [1, 3, 5, 6, "15_revenue", "15_revenue0"])
 def test_chip_smoke_reference_matches_oracle(smoke_reference, qnum):
     """chip_smoke.py checks the chip's SF1 answers against a pandas
     recompute (the sqlite oracle cannot load SF1 inside a smoke run's
@@ -149,6 +149,6 @@ def test_chip_smoke_reference_matches_oracle(smoke_reference, qnum):
 
     conn, path = smoke_reference
     got = chip_smoke.REFERENCE[qnum](path)
-    want = run_oracle(conn, QUERIES[qnum])
+    want = run_oracle(conn, chip_smoke.query_text(qnum))
     assert want, f"q{qnum}: oracle returned no rows"
     assert_rows_match(got, want, label=f"q{qnum}[pandas reference]")

@@ -21,7 +21,9 @@ Seven rules the engine relies on but Python cannot enforce:
    fault points); an undeclared span name fragments the waterfall and
    the host/device attribution that key off the registry. Likewise
    every ``trace.built("<kind>", ...)`` literal (the build events
-   ``seg_sum`` / ``join`` / ``sort``) against ``trace.BUILD_EVENTS``.
+   ``seg_sum`` / ``join`` / ``sort`` / ``group_by``) against
+   ``trace.BUILD_EVENTS``, and every ``trace.inner_scope("<name>")``
+   literal (``GroupSort`` / ``GroupSum``) against ``trace.INNER_SCOPES``.
 
 3. **fingerprint-purity** — functions on the structural-fingerprint
    path (compile/store.py and planner's two _adaptive_snapshot functions) must
@@ -220,12 +222,14 @@ def _check_span_names(tree: ast.AST, rel: str,
                       out: List[Finding]) -> None:
     """Every literal span name opened via ``trace.span("<name>", ...)``
     (or a bare imported ``span("<name>", ...)``) must be declared in
-    the central ``spark_tpu.trace.SPAN_NAMES`` registry, and every
-    ``trace.built("<kind>", ...)`` kind in ``trace.BUILD_EVENTS``."""
+    the central ``spark_tpu.trace.SPAN_NAMES`` registry, every
+    ``trace.built("<kind>", ...)`` kind in ``trace.BUILD_EVENTS`` and
+    every ``trace.inner_scope("<name>")`` in ``trace.INNER_SCOPES``."""
     from spark_tpu import trace
 
     registries = {"span": ("SPAN_NAMES", set(trace.SPAN_NAMES)),
-                  "built": ("BUILD_EVENTS", set(trace.BUILD_EVENTS))}
+                  "built": ("BUILD_EVENTS", set(trace.BUILD_EVENTS)),
+                  "inner_scope": ("INNER_SCOPES", set(trace.INNER_SCOPES))}
     for node in ast.walk(tree):
         if not (isinstance(node, ast.Call) and node.args):
             continue
