@@ -149,11 +149,16 @@ def group_ids_from_sorted(
 # ---- segment aggregation ----------------------------------------------------
 #
 # TPU reality check (v5e, inside a jit with x64 on; tools/probe_seg_sum.py,
-# chip runs of PR 27 and PR 29). One grouped sum over 6,001,664 rows: XLA
-# scatter-add (jax.ops.segment_sum) costs 360 ms on an int64 column and
-# 1,310-1,340 ms on the three emulated-f64 limbs of one (K = 6 ... 200; 730
-# and 2,370 ms at K = 100,000), whatever K is: it pays per row. cumsum +
-# searchsorted over sorted int64 ids 8 ms at K = 200 and 75 ms at
+# chip runs of PRs 27, 29 and 36). One grouped sum over 6,001,664 rows: XLA
+# scatter-add (jax.ops.segment_sum) costs 360 ms on an int64 column (730 at
+# K = 100,000), whatever K is: it pays per row. An f64 is a pair of f32
+# planes on this chip, so the three f64 limbs an int64 sum was carried as
+# until PR 36 cost 1,310-1,340 ms (2,370) — and, floats taking the
+# row-ordered scatter, 647 of Q15's 1,118 ms an execution at SF10
+# (2,421,760 sorted rows, K = 100,096) where one int64 scatter-add reads
+# 168 and the cumsum rung 45 (49-53 with the COUNT beside it, whose bound
+# searches the compiler merges with the sum's). At 6,001,664 rows cumsum
+# + searchsorted over sorted int64 ids read 8 ms at K = 200 and 75 ms at
 # K = 100,000. A dense masked reduction pays per PASS over its column, and
 # how many it makes is for this file to say, not the compiler: written as
 # one reduction a slot, K minima of an f32 or int32 column are merged into
@@ -282,32 +287,14 @@ def seg_sum(data, seg, mask, num_segments: int, sorted_seg: bool = False):
     """Grouped sum. The rule of the ladder: EXACT sums (integers, scaled
     decimals) follow the integer rungs — reduce, masked, cumsum, scatter,
     chosen from (num_segments, sorted_seg); only USER FLOATS need row
-    order and take the scatter-add whatever K is."""
-    limbs = (data.dtype == jnp.int64
-             and not 1 < num_segments <= _MASKED_SEG_LIMIT)
-    if limbs:
-        # K == 1 and K > 64 keep the path they had (PR 27 rerouted
-        # 1 < K <= 64 only; ROADMAP A0b has what K > 64 pays): three
-        # 21-bit limbs (arithmetic-shift top limb keeps two's complement
-        # identity), each summed exactly in f64 — limb partial sums stay
-        # under 2^53 up to ~4B rows — and routed by that carrier dtype,
-        # so K > 64 scatter-adds three times. int64 wraparound makes the
-        # recombination correct whenever the true total fits 64 bits.
-        m21 = (1 << 21) - 1
-        parts = []
-        for sh in (0, 21, 42):
-            limb = (data >> sh) & m21 if sh < 42 else data >> 42
-            part, rung = _sum_rung(limb.astype(jnp.float64), seg, mask,
-                                   num_segments, sorted_seg)
-            parts.append(part.astype(jnp.int64))
-        out = parts[0] + (parts[1] << 21) + (parts[2] << 42)
-    else:
-        out, rung = _sum_rung(data, seg, mask, num_segments, sorted_seg)
+    order and take the scatter-add whatever K is. int64 addition wraps,
+    so every rung returns a group's exact sum whenever that sum fits 64
+    bits, the cumsum rung's difference of running totals too."""
+    out, rung = _sum_rung(data, seg, mask, num_segments, sorted_seg)
     # trace-time event (as ops/pallas_agg._note): the rung this program
     # was BUILT from; an execution of the compiled stage records nothing
     trace.built("seg_sum", rung=rung, k=int(num_segments),
                 rows=int(data.shape[0]), dtype=str(data.dtype),
-                limbs=limbs,
                 passes=(_masked_passes(num_segments, data.dtype)
                         if rung == "masked" else None))
     return out

@@ -124,7 +124,7 @@ SPAN_NAMES = frozenset({
 #: ``trace.built("<kind>", ...)`` literal to this set.
 BUILD_EVENTS = frozenset({
     "seg_sum",   # kernels.seg_sum: rung (reduce/masked/cumsum/scatter),
-                 # k, rows, dtype, limbs
+                 # k, rows, dtype, passes
     "join",      # JoinExec.trace: rung (table/index/live), how,
                  # orientation, build rows, probe capacity
     "sort",      # kernels: one per XLA sort built; site, rows, dtype

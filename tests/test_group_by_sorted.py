@@ -138,17 +138,18 @@ def test_the_aggregate_is_built_sorted(runs, execution):
     """The first execution counts the groups in one stage and runs the
     aggregate sized by the count as another; the second traces the whole
     query's stage. Each aggregate stage builds two XLA sorts (the key's
-    argsort and the live rows'), one limb ``seg_sum`` past the masked
-    rung's 64 slots, and a ``group_by`` event that says so; the count
-    sorts once more. A tripwire on the program's shape."""
+    argsort and the live rows'), one int64 ``seg_sum`` on the cumsum rung
+    (past the masked rung's 64 slots, over the sorted ids), and a
+    ``group_by`` event that says so; the count sorts once more. A
+    tripwire on the program's shape."""
     _rows_got, events, _n = runs[SEEDS[0]]["executions"][execution]
     stages = [e["node"].split("[")[0] for e in _of(events, "stage_compile")]
     assert stages == [["GroupCount", "HashAggregate"], ["Compact"]][execution]
     assert [(e["site"], e["dtype"]) for e in _of(events, "sort")] == [
         ("lexsort", "int64"), ("lexsort", "bool")] * len(stages)
     (summed,) = _of(events, "seg_sum")
-    assert summed["k"] > 64 and summed["limbs"] is True
-    assert summed["dtype"] == "int64"
+    assert summed["k"] > 64 and summed["rung"] == "cumsum"
+    assert summed["dtype"] == "int64" and "limbs" not in summed
     by_key, of_all = _of(events, "group_by")
     groups = int(SF * 10_000)
     assert by_key == {**by_key, "strategy": "sorted", "keys": ["int64"],
@@ -211,10 +212,11 @@ def test_the_sorted_stage_names_its_two_halves(runs):
     for half in trace.INNER_SCOPES:
         assert any(f"spark.HashAggregateExec/spark.{half}/" in n
                    for n in names)
-    # the sort and the scatters are where they are said to be
+    # the sort and the sums' cumsums are where they are said to be,
+    # and nothing is scattered into the group slots
     assert any(_innermost(n) == "GroupSort" and "sort" in n for n in names)
-    assert any(_innermost(n) == "GroupSum" and "scatter" in n
-               for n in names)
+    assert any(_innermost(n) == "GroupSum" and "cumsum" in n for n in names)
+    assert not any("scatter" in n for n in names)
 
 
 def test_the_direct_path_keeps_the_operators_scope_innermost(runs):

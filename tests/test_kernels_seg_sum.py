@@ -1,13 +1,18 @@
 """kernels.seg_sum: the rung a sum takes and the bits it returns.
 
-Exact sums (int64 / scaled decimals) follow the integer ladder — with
-1 < K <= 64 the dense masked reduction, never a scatter-add (PR 27: q1's
-8.6 s on the v5e were fifteen emulated-f64 scatter-adds); only user
-floats need row order. The masked rung (sum, count, min, max) reads its
-column once for every G slots, not once a slot (PR 29: 36 passes were 24
-of q1's 40 ms at SF10). The StableHLO tripwires and the trace-time
-``seg_sum`` event stop either regressing unseen.
+Exact sums (int64 / scaled decimals) follow the integer ladder as int64
+at every K — with 1 < K <= 64 the dense masked reduction, never a
+scatter-add (PR 27: q1's 8.6 s on the v5e were fifteen emulated-f64
+scatter-adds), past that one cumsum over sorted ids or one int64
+scatter-add (PR 36: three f64-limb scatter-adds were 646 of Q15's
+1,118 ms at SF10); only user floats need row order. The masked rung
+(sum, count, min, max) reads its column once for every G slots, not once
+a slot (PR 29: 36 passes were 24 of q1's 40 ms at SF10). The StableHLO
+tripwires and the trace-time ``seg_sum`` event stop either regressing
+unseen.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +27,8 @@ KS = [2, 6, 64, 65, 200]
 
 
 def _columns(rng):
-    """Limb extremes: values near +-2^62 whose totals wrap, an
-    all-negative column, one whose top limbs are all zero."""
+    """Values near +-2^62 whose totals wrap, an all-negative column,
+    one of money-sized values."""
     wild = rng.integers(-(1 << 62), 1 << 62, N, dtype=np.int64)
     wild[:8] = [(1 << 62) - 1, -(1 << 62), (1 << 42) - 1, 1 << 42,
                 -1, (1 << 21) - 1, -(1 << 21), 0]
@@ -120,11 +125,18 @@ def test_static_and_compacted_layouts_give_the_same_bits(rng, k, sorted_seg):
     assert np.asarray(static).tobytes() == np.asarray(compact).tobytes()
 
 
-CASES = [  # (dtype, K, the rung it must be built from)
-    pytest.param(jnp.int64, 6, "masked", id="int64-k6"),
-    pytest.param(jnp.int64, 64, "masked", id="int64-k64"),
-    pytest.param(jnp.int64, 65, "scatter", id="int64-k65"),
-    pytest.param(jnp.float64, 6, "scatter", id="float64-k6"),
+CASES = [  # (dtype, K, sorted ids, the rung it must be built from)
+    pytest.param(jnp.int64, 1, False, "reduce", id="int64-k1"),
+    pytest.param(jnp.int64, 6, False, "masked", id="int64-k6"),
+    pytest.param(jnp.int64, 64, False, "masked", id="int64-k64"),
+    pytest.param(jnp.int64, 64, True, "masked", id="int64-k64-sorted"),
+    pytest.param(jnp.int64, 65, False, "scatter", id="int64-k65"),
+    pytest.param(jnp.int64, 65, True, "cumsum", id="int64-k65-sorted"),
+    pytest.param(jnp.int64, 100_096, False, "scatter", id="int64-k100096"),
+    pytest.param(jnp.int64, 100_096, True, "cumsum",
+                 id="int64-k100096-sorted"),
+    pytest.param(jnp.float64, 6, False, "scatter", id="float64-k6"),
+    pytest.param(jnp.float64, 65, True, "scatter", id="float64-k65-sorted"),
 ]
 
 
@@ -134,12 +146,29 @@ def _shapes(dtype):
             jax.ShapeDtypeStruct((N,), jnp.bool_))
 
 
-@pytest.mark.parametrize("dtype,k,rung", CASES)
-def test_stablehlo_scatter_tripwire(dtype, k, rung):
-    """No scatter in an exact sum with K <= 64; K > 64 and user floats
-    (which keep row order) still have theirs."""
-    text = _jitted(k, False).lower(*_shapes(dtype)).as_text()
-    assert ("scatter" in text) == (rung == "scatter")
+def _scatter_adds(text):
+    """The scatters of a lowered program that ADD into their slots. (One
+    that sets — the permutation inverse inside a co-sorted
+    ``searchsorted``, which ``seg_bounds`` takes past 4,096 segments —
+    returns its update and is none of a sum's.)"""
+    return len(re.findall(
+        r'"stablehlo\.scatter"\([^\n]*\n[^\n]*\n\s*%\d+ = stablehlo\.add ',
+        text))
+
+
+@pytest.mark.parametrize("dtype,k,sorted_seg,rung", CASES)
+def test_stablehlo_scatter_tripwire(dtype, k, sorted_seg, rung):
+    """No scatter-add in an exact sum with K <= 64 or over sorted ids;
+    K > 64 on unsorted ids has exactly one (the column's own, not one a
+    limb), as a user float's sum (which keeps row order) at any K. An
+    int64 sum never travels as f64, which the chip emulates as a pair
+    of f32."""
+    text = _jitted(k, sorted_seg).lower(*_shapes(dtype)).as_text()
+    assert _scatter_adds(text) == (rung == "scatter")
+    if k <= 4096:
+        assert ("scatter" in text) == (rung == "scatter")
+    if dtype == jnp.int64:
+        assert "f64" not in text
 
 
 def _pass_cases():
@@ -174,17 +203,18 @@ def _events():
     return [e for e in metrics.recent(4096) if e["kind"] == "seg_sum"]
 
 
-@pytest.mark.parametrize("dtype,k,rung", CASES)
-def test_trace_time_event_names_the_rung(rng, dtype, k, rung):
+@pytest.mark.parametrize("dtype,k,sorted_seg,rung", CASES)
+def test_trace_time_event_names_the_rung(rng, dtype, k, sorted_seg, rung):
     metrics.reset()
-    fn = _jitted(k, False)
+    fn = _jitted(k, sorted_seg)
     args = (jnp.asarray(_columns(rng)["money"]).astype(dtype),
-            jnp.asarray(_seg(rng, k, False)), jnp.asarray(_mask(rng, "p70")))
+            jnp.asarray(_seg(rng, k, sorted_seg)),
+            jnp.asarray(_mask(rng, "p70")))
     fn(*args)
     (ev,) = _events()
     assert (ev["rung"], ev["k"], ev["rows"]) == (rung, k, N)
     assert ev["dtype"] == np.dtype(dtype).name
-    assert ev["limbs"] == (dtype == jnp.int64 and k > 64)
+    assert "limbs" not in ev
     assert ev["passes"] == (K._masked_passes(k, dtype)
                             if rung == "masked" else None)
     fn(*args)  # the compiled program runs: nothing is recorded
@@ -200,7 +230,40 @@ def test_global_sum_keeps_the_plain_reduction(rng):
     assert np.array_equal(np.asarray(got),
                           _op_reference("sum", data, seg, mask, 1))
     (ev,) = _events()
-    assert (ev["rung"], ev["limbs"]) == ("reduce", True)
+    assert ev["rung"] == "reduce" and "limbs" not in ev
+
+
+def _sorted_case(rng, k, column):
+    """(data, sorted ids, mask) over max(N, 2 K) rows. ``wraps``: values
+    under 2^56, so a group's few dozen rows sum inside int64 while the
+    column's running total leaves it; ``negative``: the same, negated."""
+    n = max(N, 2 * k)
+    seg = np.sort(rng.integers(0, k - 1, n, dtype=np.int64))
+    data = rng.integers(1 << 54, 1 << 56, n, dtype=np.int64)
+    return (-data if column == "negative" else data), seg, rng.random(n) < 0.7
+
+
+@pytest.mark.parametrize("column", ["wraps", "negative"])
+@pytest.mark.parametrize("k", [65, 200, 100_096])
+def test_sorted_int64_sum_is_one_cumsum_and_exact_where_the_total_wraps(
+        rng, k, column):
+    """Past the masked rung a decimal sum over sorted ids (the sort-based
+    aggregate's) is the cumsum rung on the int64 column itself:
+    ``csum[end] - csum[start] + x[start]`` wraps back to the group's own
+    sum wherever the running total of the whole column has wrapped."""
+    data, seg, mask = _sorted_case(rng, k, column)
+    exact = [0] * k          # Python integers: no wrap
+    for s, x in zip(seg[mask].tolist(), data[mask].tolist()):
+        exact[s] += x
+    assert max(abs(v) for v in exact) < 1 << 63 <= abs(sum(exact))
+    metrics.reset()
+    got = np.asarray(_jitted(k, True)(jnp.asarray(data), jnp.asarray(seg),
+                                      jnp.asarray(mask)))
+    (ev,) = _events()
+    assert (ev["rung"], ev["k"], ev["dtype"]) == ("cumsum", k, "int64")
+    assert got.dtype == np.int64
+    assert np.array_equal(got, _op_reference("sum", data, seg, mask, k))
+    assert got.tolist() == exact
 
 
 def test_q1_sums_are_masked_and_q6_is_a_reduction(engine):
@@ -211,11 +274,12 @@ def test_q1_sums_are_masked_and_q6_is_a_reduction(engine):
 
     # an SF no other test uses, so that the stages are traced here
     register_views(engine, generate_tables(0.0031, seed=27))
-    for query, rung, limbs in ((1, "masked", False), (6, "reduce", True)):
+    for query, rung in ((1, "masked"), (6, "reduce")):
         metrics.reset()
         assert engine.sql(QUERIES[query]).collect()
         events = _events()
         assert events, f"q{query} traced no seg_sum"
-        assert {(e["rung"], e["limbs"], e["dtype"]) for e in events} == {
-            (rung, limbs, "int64")}, events
+        assert {(e["rung"], e["dtype"]) for e in events} == {
+            (rung, "int64")}, events
+        assert not any("limbs" in e for e in events)
         assert {e["k"] for e in events} == {6 if query == 1 else 1}

@@ -119,16 +119,16 @@ Q1_ROWS = 6_001_664  # lineitem at SF1 (6,000,647) in its 1,024-row bucket
 Q1_ROWS_SF10 = 59_990_016  # at SF10 (59,989,771): benchmark tpch_sf10_q1
 
 
-def _q1_stage_at(rows, sliver_sf, one_chip, spark, monkeypatch,
-                 parquet_dir=None):
-    """TPC-H Q1's fused stage, planned by the session on a sliver of
-    data and compiled for the described chip at ``rows`` rows. With
-    ``parquet_dir`` the tables are scanned from parquet written there,
-    as the benchmark's are: the scan decides what the device holds."""
+def _stage_at(rows, sliver_sf, one_chip, spark, monkeypatch, run,
+              parquet_dir=None):
+    """The last fused stage with an aggregate that ``run(spark)`` makes
+    the session plan on a sliver of TPC-H, compiled for the described
+    chip at ``rows`` rows. With ``parquet_dir`` the tables are scanned
+    from parquet written there, as the benchmark's are: the scan decides
+    what the device holds."""
     import spark_tpu.compile as compile_pkg
     from spark_tpu.tpch.gen import (generate_tables, register_views,
                                     write_parquet)
-    from spark_tpu.tpch.queries import QUERIES
 
     stages = []
     build = compile_pkg.build_stage_callable
@@ -145,9 +145,9 @@ def _q1_stage_at(rows, sliver_sf, one_chip, spark, monkeypatch,
     else:
         write_parquet(tables, str(parquet_dir))
         register_views(spark, path=str(parquet_dir))
-    assert len(spark.sql(QUERIES[1]).collect()) == 4
-    (stage,) = [s for s in stages if "Aggregate" in s[0].tree_string()]
-    _, trace_fn, example_args = stage
+    run(spark)
+    _, trace_fn, example_args = [
+        s for s in stages if "Aggregate" in s[0].tree_string()][-1]
     cap = max(a.shape[0] for a in jax.tree.leaves(example_args) if a.ndim)
 
     def at_rows(a):
@@ -160,6 +160,18 @@ def _q1_stage_at(rows, sliver_sf, one_chip, spark, monkeypatch,
     return compiled, text
 
 
+def _q1_stage_at(rows, sliver_sf, one_chip, spark, monkeypatch,
+                 parquet_dir=None):
+    """TPC-H Q1's fused stage (its one execution builds one)."""
+    from spark_tpu.tpch.queries import QUERIES
+
+    def run(spark):
+        assert len(spark.sql(QUERIES[1]).collect()) == 4
+
+    return _stage_at(rows, sliver_sf, one_chip, spark, monkeypatch, run,
+                     parquet_dir)
+
+
 def test_q1_fused_stage_has_no_group_slot_scatter_on_v5e(
         one_chip, as_the_session_runs, spark, monkeypatch):
     """At SF1's row count the six group slots (3 x 2 dictionary codes)
@@ -170,6 +182,66 @@ def test_q1_fused_stage_has_no_group_slot_scatter_on_v5e(
     scatters = [line for line in text.splitlines()
                 if "scatter" in line and re.search(r"f(32|64)\[6\]", line)]
     assert not scatters, scatters[:3]
+
+
+Q15_ROWS, Q15_GROUPS = 2_421_760, 100_000  # tpch_sf10_q15_revenue
+
+
+def _scatter_combiners(text):
+    """The ROOT instruction of every scatter's combiner in an optimised
+    program: ``parameter(1)`` where the update is SET, an ``add`` (of an
+    f32 pair, for an emulated f64) where it is summed into its slot."""
+    roots = []
+    for name in re.findall(r" scatter\(.*to_apply=(%[\w.\-]+)", text):
+        body = re.search(
+            r"^" + re.escape(name) + r" \(.*?\{\n(.*?)\n\}", text,
+            re.S | re.M).group(1)
+        roots += [line.strip() for line in body.splitlines()
+                  if "ROOT" in line]
+    return roots
+
+
+def test_q15_sorted_aggregate_sums_int64_with_no_scatter_add_on_v5e(
+        one_chip, as_the_session_runs, spark, monkeypatch, tmp_path):
+    """The benchmark's tpch_sf10_q15_revenue at its own shape: the whole
+    query's stage over 2,421,760 rows behind the pushed date bounds, the
+    sort-based aggregate sized for 100,000 suppliers (100,096 slots). Its
+    one decimal sum is a cumsum of the int64 column over the sorted ids,
+    so the program holds no float at all — an f64 reaches the optimised
+    text as a pair of f32 planes, and the three f64-limb scatter-adds
+    over ``f32[100096]`` were 646 of the cell's 1,118 ms an execution
+    (PERF.md, PR 36) — and sums nothing into a slot by scatter. The
+    scatters that remain SET u32 ranks: the permutation inverses of
+    ``seg_bounds``' co-sorted ``searchsorted``, one pair for the sum and
+    the COUNT beside it (the compiler merges their searches)."""
+    from spark_tpu.physical import kernels as K, operators as P
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "queries")
+    with open(os.path.join(bench, "q15_revenue.sql")) as f:
+        query = f.read()
+    # the sliver has a few hundred suppliers: size the second execution's
+    # stage as the cell's count of them does
+    recorded = P._AGG_STATS.get
+    monkeypatch.setattr(
+        P._AGG_STATS, "get",
+        lambda key: None if recorded(key) is None else Q15_GROUPS)
+
+    def run(spark):
+        first = spark.sql(query).collect()      # counts the groups
+        assert spark.sql(query).collect() == first and len(first) == 1
+
+    _, text = _stage_at(Q15_ROWS, 0.0033, one_chip, spark, monkeypatch, run,
+                        parquet_dir=tmp_path)
+    assert f"[{K.bucket(Q15_GROUPS, 256)}]" in text
+    floats = re.findall(r"\bf(?:16|32|64)\[\d*\]", text)
+    assert not floats, sorted(set(floats))
+    combiners = _scatter_combiners(text)
+    print(f"q15 at {Q15_ROWS} rows: {len(combiners)} scatters", combiners)
+    assert 1 <= len(combiners) <= 3
+    assert all(" parameter(1)" in root for root in combiners), combiners
+    sorts = re.findall(r" sort\(", text)
+    assert len(sorts) <= 5, len(sorts)   # two lexsorts, one pair of bounds
 
 
 def _leaf_splits(text, rows):

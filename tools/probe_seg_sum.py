@@ -1,5 +1,5 @@
-"""Chip probe behind kernels.seg_sum's ladder (PR 27, PR 29; PERF.md
-section 6).
+"""Chip probe behind kernels.seg_sum's ladder (PR 27, PR 29, PR 36;
+PERF.md section 6).
 
 What one exact (int64 / scaled-decimal) grouped sum costs on the attached
 chip at q1's shape, by rung, each inside a jitted function with x64 on,
@@ -11,12 +11,22 @@ reductions of G slots; int64 sum, count, f32 min; SF1's and SF10's rows):
   chiprun -- python tools/probe_seg_sum.py --small-k  # K <= 64 only
   chiprun --timeout 1800 -- python tools/probe_seg_sum.py --passes  # ~6 min
   chiprun -- python tools/probe_seg_sum.py --narrow   # PR 31, ~2 min
+  chiprun -- python tools/probe_seg_sum.py --carrier  # PR 36, ~3 min
 
 ``--narrow``: the K = 6 sum (and q1's five sums and a count) over values
 that fit 32 bits, from int64 parameters against int32 parameters widened
 first thing in the program, at SF10's rows: what the chip's split of an
 int64 parameter into u32 pairs costs an execution, and what a scan that
 keeps such a column as int32 saves.
+
+``--carrier``: what the f64-limb carrier of an int64 sum cost where the
+engine still used it before PR 36. K == 1 (q6's and q14's global sums):
+a plain int64 ``jnp.sum`` against three f64 limb sums, at q14's, q6's
+and SF10's rows, a call at a time and looped inside one program (the
+device's own time: a call of either costs the host more than the sum). And Q15's aggregate at ``tpch_sf10_q15_revenue``'s own
+shape (2,421,760 rows sorted by group, K = 100,096, int32 ids): three
+limb scatter-adds, one int64 scatter-add, one int64 cumsum; then the sum
+with the COUNT beside it, their segment bounds searched twice or once.
 
 Every variant is written out from primitives here, so the probe reads the
 same after the engine's own choice changes. Each line of output is one
@@ -46,6 +56,10 @@ from spark_tpu.physical import kernels as K  # noqa: E402
 
 ROWS = 6_001_664  # lineitem's 6,000,647 rows in the engine's 1,024 bucket
 ROWS_SF10 = 59_990_016  # SF10's 59,989,771
+# the rows behind the pushed date filters of Q14 and Q6 at SF1, and of
+# Q15 at SF10 with its group slots (100,000 suppliers in 256-slot buckets)
+ROWS_Q14, ROWS_Q6 = 80_896, 121_856
+ROWS_Q15, K_Q15, LIVE_Q15 = 2_421_760, 100_096, 2_421_294
 
 
 def _limbs(data, red):
@@ -238,6 +252,81 @@ def probe_narrow(rows, reps=20):
     return bad
 
 
+def _looped_us(fn, args, rounds):
+    """Microseconds a reduction takes on the device: ``rounds`` of them in
+    ONE program, each over the column plus the round's number so that none
+    is hoisted, which leaves the host's dispatch (0.2 ms a call, more than
+    a small reduction itself) out of the figure."""
+    def many(d, m):
+        return jax.lax.fori_loop(
+            0, rounds, lambda i, acc: acc + fn(d + i, m)[0],
+            jnp.zeros((), jnp.int64))
+
+    ms, _piped, _comp, _out = _time(jax.jit(many), args, 5)
+    return round(ms * 1e3 / rounds, 2)
+
+
+def probe_carrier(reps=20):
+    """The two shapes that took the f64 limbs until PR 36."""
+    bad = 0
+    rng = np.random.default_rng(36)
+    for n in (ROWS_Q14, ROWS_Q6, ROWS_SF10):
+        # a product of two decimal(12,2): at most about 1.05e9 a row
+        data = rng.integers(0, 1_050_000_000, n, dtype=np.int64)
+        mask = rng.random(n) < 0.98
+        ref = [np.sum(data[mask], dtype=np.int64)[None]]
+        args = (jnp.asarray(data), jnp.asarray(mask))
+
+        def plain(x, m):
+            return jnp.sum(jnp.where(m, x, jnp.zeros((), x.dtype)))[None]
+
+        for name, fn in (("int64_reduce", plain),
+                         ("limb_reduce",
+                          lambda d, m: _limbs(d, lambda x: plain(x, m)))):
+            rounds = 512 if n < ROWS else 8
+            bad += _measure({"rows": n, "k": 1, "variant": name,
+                             "looped_us": _looped_us(fn, args, rounds),
+                             "rounds": rounds},
+                            lambda d, m, fn=fn: [fn(d, m)], args, reps, ref)
+        del args
+    n, k = ROWS_Q15, K_Q15
+    data = rng.integers(0, 1_050_000_000, n, dtype=np.int64)
+    mask = np.arange(n) < LIVE_Q15          # sorted: live rows first
+    seg = np.sort(rng.integers(0, 100_000, n)).astype(np.int32)
+    seg[~mask] = seg[LIVE_Q15 - 1]          # dead rows: the last group's id
+    refs = [_reference(data, seg, mask, k),
+            _reference(np.ones(n, np.int64), seg, mask, k)]
+    args = (jnp.asarray(data), jnp.asarray(seg), jnp.asarray(mask))
+
+    def from_bounds(x, bounds):
+        """kernels._sorted_seg_sum with the bounds handed in."""
+        starts, ends = bounds
+        csum = jnp.cumsum(x, dtype=x.dtype)
+        e, s = jnp.clip(ends, 0, n - 1), jnp.clip(starts, 0, n - 1)
+        return jnp.where(ends >= starts, csum[e] - csum[s] + x[s],
+                         jnp.zeros((), x.dtype))
+
+    def count(s, m):
+        return _cumsum(m.astype(jnp.int64), s, m, k)
+
+    def shared(d, s, m):
+        bounds = K.seg_bounds(s, k)
+        return [from_bounds(jnp.where(m, d, jnp.zeros((), d.dtype)), bounds),
+                from_bounds(m.astype(jnp.int64), bounds)]
+
+    sums = variants(k, True)
+    for name, fn in (
+            [(v, lambda d, s, m, v=v: [sums[v](d, s, m)])
+             for v in ("limb_scatter", "int64_scatter", "int64_cumsum")]
+            + [(f"{v}+count",
+                lambda d, s, m, v=v: [sums[v](d, s, m), count(s, m)])
+               for v in ("limb_scatter", "int64_cumsum")]
+            + [("int64_cumsum+count_shared_bounds", shared)]):
+        bad += _measure({"rows": n, "k": k, "sorted": True, "variant": name},
+                        fn, args, 3 if "scatter" in name else reps, refs)
+    return bad
+
+
 def _time(fn, args, reps):
     """(blocking median ms, pipelined mean ms, compile s, result)."""
     t0 = time.perf_counter()
@@ -271,6 +360,10 @@ def main() -> int:
     ap.add_argument("--narrow", action="store_true",
                     help="only int64 against widened int32 parameters, "
                     "at SF10's rows (or at --rows)")
+    ap.add_argument("--carrier", action="store_true",
+                    help="only the f64-limb carrier against int64: K == 1 "
+                    "at q14's, q6's and SF10's rows, and Q15's sorted "
+                    "aggregate at the benchmark's shape")
     ap.add_argument("--rows", type=int)
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearsal only: times mean nothing")
@@ -279,6 +372,9 @@ def main() -> int:
     if dev.platform != "tpu" and not a.allow_cpu:
         print("probe_seg_sum: no TPU", file=sys.stderr)
         return 2
+    if a.carrier:
+        print(json.dumps({"device": dev.device_kind}))
+        return 1 if probe_carrier() else 0
     if a.narrow:
         rows = a.rows or ROWS_SF10
         print(json.dumps({"device": dev.device_kind, "rows": rows}))
