@@ -365,6 +365,7 @@ class DataFrame:
         plan = self._plan
         if self._session is not None:
             from spark_tpu.recovery import run_stage_with_recovery
+            from spark_tpu.scheduler import admission
             from spark_tpu.storage import pin_scope
 
             svc = self._session.compile_service
@@ -386,27 +387,11 @@ class DataFrame:
                 out = run_stage_with_recovery(
                     lambda: run_full(plan), conf=self._session.conf,
                     label=type(self._plan).__name__)
-                self._note_measured_bytes()
+                # the next admission of this query (submit_query
+                # estimates the RAW plan) uses measured, not static, bytes
+                admission.note_query_peak(self._plan, "raw")
                 return out
         return run_full(plan)
-
-    def _note_measured_bytes(self) -> None:
-        """Feed scheduler admission with the measured peak stage
-        footprint of this query (the max stage_bytes event the mesh
-        executor recorded since query_start), keyed by the RAW logical
-        plan — the same plan shape scheduler.submit_query estimates
-        before execution, so the next admission of this query uses
-        measured, not static, bytes."""
-        try:
-            from spark_tpu import metrics
-            from spark_tpu.scheduler import admission
-
-            peak = max((int(e.get("bytes", 0))
-                        for e in metrics.last_query()
-                        if e.get("kind") == "stage_bytes"), default=0)
-            admission.note_measured_bytes(self._plan, peak)
-        except Exception:
-            pass  # observability must never fail the query
 
     def collect(self) -> List[Row]:
         return self._execute(_collect_rows)

@@ -1263,25 +1263,16 @@ class MeshExecutor:
                 devices=tuple(self.mesh.devices.flat))
             _DIST_STAGE_CACHE[key] = entry
         jitted, schema_box = entry
-        ctx = _trace.current()
-        if ctx is not None and ctx.sampled:
-            # device time, block_until_ready-bounded, so the span is
-            # device execution and not async dispatch; only a SAMPLED
-            # trace pays the forced sync (results are identical either
-            # way — the host reads the same buffers right after)
-            with _trace.span("stage.device", op=type(plan).__name__):
-                with _trace.span("stage.dispatch"):
-                    data = jitted(tuple(s.sharded.data for s in scans))
-                data = jax.block_until_ready(data)
-        else:
+        # the enqueue alone, sampled or not: nothing waits for the
+        # device here (the wait is measured where the host blocks
+        # anyway: in fetch_host, and in the read-backs below)
+        with _trace.span("stage.dispatch"):
             data = jitted(tuple(s.sharded.data for s in scans))
         sb = ShardedBatch(schema_box["schema"], data, self.mesh)
         if isinstance(plan, D.FusedSpanExec) and plan.speculate:
             # the last slot of every shard is the overflow sentinel —
             # check it BEFORE any compaction could move or drop it
-            p = sb.per_device_capacity
-            m = np.asarray(sb.data.row_mask).reshape(self.d, p)
-            if bool(m[:, -1].any()):
+            if bool(self._read_mask(sb)[:, -1].any()):
                 raise _FusionOverflow()
         n_ex = _count_exchange_nodes(plan)
         if n_ex and not self._adaptive_enabled():
@@ -1295,16 +1286,24 @@ class MeshExecutor:
             p = sb.per_device_capacity
             metrics.record_exchange(
                 op="fused", mode="fused", devices=self.d,
-                exchanges=n_ex, rows=sb.num_valid_rows(),
+                exchanges=n_ex, rows=int(self._read_mask(sb).sum()),
                 capacity_before=p, capacity_after=p,
                 buffer_bytes=self.d * p * _row_width(sb.schema))
         return self._maybe_compact(sb)
+
+    def _read_mask(self, sb: ShardedBatch) -> np.ndarray:
+        """``sb``'s row mask on the host, a row a device: a read-back
+        between stages, so the host waits here for the stage that made
+        it (``device.wait`` with ``op="readback"``)."""
+        with _trace.span("device.wait", op="readback"):
+            return np.asarray(sb.data.row_mask).reshape(
+                self.d, sb.per_device_capacity)
 
     def _maybe_compact(self, sb: ShardedBatch) -> ShardedBatch:
         p = sb.per_device_capacity
         if p <= 4096:
             return sb
-        m = np.asarray(sb.data.row_mask).reshape(self.d, p)
+        m = self._read_mask(sb)
         max_live = int(m.sum(axis=1).max())
         if max_live * 4 > p:
             return sb

@@ -377,22 +377,6 @@ def backoff_sleep(attempt: int, *, base_s: float = 0.05,
     time.sleep(deadline.cap_sleep(random.uniform(0.0, ceiling)))
 
 
-def _note_measured_resident(lp) -> None:
-    """Seed admission's measured-bytes table keyed by the OPTIMIZED
-    plan after a successful resident run (DataFrame._execute keys by
-    the RAW plan; the grant pre-step and the hybrid join see the
-    optimized plan, so both keys must be populated)."""
-    try:
-        from spark_tpu.scheduler import admission
-
-        peak = max((int(e.get("bytes", 0))
-                    for e in metrics.last_query()
-                    if e.get("kind") == "stage_bytes"), default=0)
-        admission.note_measured_bytes(lp, peak)
-    except Exception:
-        pass  # observability must never fail the query
-
-
 def _grant_planned_chunk(lp, conf):
     """Planned degradation BEFORE execution — the zero-replan path.
     When a MEASURED prior run of this plan shape says its working set
@@ -454,20 +438,28 @@ def run_plan_with_oom_degradation(lp, conf, run_fn):
     from spark_tpu.physical.chunked import (MAX_DEVICE_BATCH_BYTES,
                                             execute_chunked,
                                             find_chunkable)
+    from spark_tpu.scheduler import admission
 
     try:
-        found = find_chunkable(lp, conf)
-        chunk_conf = conf
-        if found is None:
-            found, shadow = _grant_planned_chunk(lp, conf)
-            if found is not None:
-                chunk_conf = shadow
+        # the tier is decided again on every execution (a plan walk,
+        # the scans' estimates, admission's table): the span closes
+        # before the engine starts, as query.plan does
+        decide = trace.span("tier.decide")
+        with decide:
+            found = find_chunkable(lp, conf)
+            chunk_conf, tier = conf, "chunked"
+            if found is None:
+                found, chunk_conf = _grant_planned_chunk(lp, conf)
+                tier = "resident" if found is None else "planned_chunked"
+            decide.attrs["tier"] = tier
         if found is not None:
             return execute_chunked(found, chunk_conf, run_fn)
         # the whole-batch device execution seam
         faults.inject("execute.device", conf)
         out = run_fn(lp)
-        _note_measured_resident(lp)
+        # the grant pre-step and the hybrid join look the OPTIMIZED
+        # plan up, so a resident run is noted under that key too
+        admission.note_query_peak(lp, "optimized")
         return out
     except Exception as e:
         if not (conf.get(OOM_DEGRADE_ENABLED) and is_oom(e)):

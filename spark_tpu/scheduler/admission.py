@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import threading
 
-from spark_tpu import locks
+from spark_tpu import locks, metrics, trace
 
 from spark_tpu import conf as CF
 
@@ -59,6 +59,27 @@ def note_measured_bytes(plan, nbytes: int) -> None:
         _MEASURED[key] = max(int(nbytes), prev)
         while len(_MEASURED) > _MEASURED_MAX_ENTRIES:
             _MEASURED.pop(next(iter(_MEASURED)))
+
+
+def note_query_peak(plan, key: str) -> None:
+    """Note for ``plan`` the peak stage footprint of the query that has
+    just run: the largest ``stage_bytes`` event the ring holds since
+    its ``query_start``. A query is noted under both of its shapes:
+    ``key`` "raw" (DataFrame._execute_traced: the plan
+    scheduler.submit_query estimates before execution) and "optimized"
+    (recovery, after a resident run: what the grant pre-step and the
+    hybrid join see). Span ``admission.note``: ``events`` is how many
+    ring events ``last_query()`` handed back, ``bytes`` the peak."""
+    try:
+        note = trace.span("admission.note", key=key)
+        with note:
+            events = metrics.last_query()
+            peak = max((int(e.get("bytes", 0)) for e in events
+                        if e.get("kind") == "stage_bytes"), default=0)
+            note.attrs.update(events=len(events), bytes=peak)
+            note_measured_bytes(plan, peak)
+    except Exception:
+        pass  # observability must never fail the query
 
 
 def measured_plan_bytes(plan):

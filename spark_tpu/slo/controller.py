@@ -230,7 +230,7 @@ class SloController:
                     name = ev.get("span") or ev.get("name") or ""
                     dur = float(ev.get("duration_ms")
                                 or ev.get("ms") or 0.0)
-                    if name == "stage.device":
+                    if name == "device.wait":
                         device_ms += dur
                     elif name == "pipeline.transfer":
                         transfer_ms += dur

@@ -3,8 +3,8 @@
 The span analogue of the reference's TaskMetrics/SQLMetrics + event-log
 replay: every query gets a ``trace_id``, and every unit of work —
 connect request, router dispatch, scheduler queue/admit/run, plan
-analysis, compile-store probe, per-stage device execution, exchange
-stats fetch, pipeline chunk decode/transfer, fault retry, result-cache/
+analysis, the tier decision, compile-store probe, each stage's enqueue,
+the host's wait on the device, exchange stats fetch, pipeline chunk decode/transfer, fault retry, result-cache/
 mview/storage probe — opens a child span under a contextvar-carried
 parent. Spans land in the existing metrics ring/JSONL as ``span``
 events, and the active (trace_id, span_id, parent_id) triple is stamped
@@ -94,9 +94,10 @@ SPAN_NAMES = frozenset({
     "stage.fused",          # whole-query fused span: multi-exchange
                             # plan as ONE XLA program, zero host sync
     "stage.dispatch",       # the jitted call alone: flatten + enqueue
-    "stage.device",         # device execution, block_until_ready-bounded
     "query.fetch",          # Batch.fetch_host: device -> host, whole
-    "device.wait",          # host blocked until the enqueued work is done
+    "device.wait",          # host blocked until the enqueued work is done:
+                            # fetch_host, and (op="readback") the mesh
+                            # engine's reads of a mask between stages
     "fetch.copy",           # what is left of the device -> host copies
     "query.rows",           # decode dictionaries/dates/decimals, build rows
     "exchange.stats",       # AQE host round-trip fetching device stats
@@ -111,6 +112,10 @@ SPAN_NAMES = frozenset({
     "serve.invalidate",     # one invalidation-log record applied
     "mview.probe",          # materialized-view / cache-manager probe
     "storage.pin",          # HBM pin-scope around query execution
+    "tier.decide",          # resident / chunked / planned_chunked, taken
+                            # before the engine runs (recovery.py)
+    "admission.note",       # the query's peak stage_bytes, read back from
+                            # the ring for the scheduler's admission table
     "join.partition",       # hybrid hash join: grant + partition pass
     "join.spill",           # hybrid hash join: one spill write/read
     "slo.admit",            # SLO feasibility check at submit time

@@ -106,8 +106,7 @@ def format_trace(events_or_id=None, width: int = 40) -> str:
 
 #: span name -> the component of ``trace_breakdown`` it is summed into
 _BREAKDOWN = {"scheduler.queue": "queue_ms",
-              "stage.device": "device_ms",    # the mesh's forced sync
-              "device.wait": "device_ms",     # fetch_host: host blocked
+              "device.wait": "device_ms",     # the host blocked
               "pipeline.transfer": "transfer_ms",
               "fetch.copy": "fetch_ms"}
 
@@ -116,10 +115,10 @@ def trace_breakdown(events_or_id=None) -> Dict[str, float]:
     """Split one trace's wall time into where it went: ``wall_ms`` is
     the root span; ``queue_ms`` the scheduler admission wait
     (scheduler.queue spans), ``device_ms`` the time the host was blocked
-    on the device (stage.device in the mesh engine, device.wait in
-    fetch_host), ``transfer_ms`` the chunk-pipeline host->device staging
-    (pipeline.transfer), ``fetch_ms`` the device->host copy of the
-    result (fetch.copy); ``host_ms`` is the remainder (decode, planning,
+    on the device (device.wait: in fetch_host and in the mesh engine's
+    read-backs between stages), ``transfer_ms`` the chunk-pipeline
+    host->device staging (pipeline.transfer), ``fetch_ms`` the
+    device->host copy of the result (fetch.copy); ``host_ms`` is the remainder (decode, planning,
     glue, HTTP) — so the five components sum to wall by construction.
     Accepts a trace_id, an event list, or nothing (last query)."""
     evs = _trace_events(events_or_id)
@@ -132,16 +131,10 @@ def trace_breakdown(events_or_id=None) -> Dict[str, float]:
     roots = [e for e in spans if e.get("parent_id") is None
              or e.get("parent_id") not in ids]
     wall = max((float(e.get("ms", 0.0)) for e in roots), default=0.0)
-    name_of = {e.get("span_id"): e.get("name") for e in spans}
     for e in spans:
-        ms = float(e.get("ms", 0.0))
         part = _BREAKDOWN.get(e.get("name"))
         if part is not None:
-            out[part] += ms
-        elif e.get("name") == "stage.dispatch" \
-                and name_of.get(e.get("parent_id")) == "stage.device":
-            # the mesh's enqueue lies inside its stage.device: host time
-            out["device_ms"] -= ms
+            out[part] += float(e.get("ms", 0.0))
     out["host_ms"] = max(0.0, wall - sum(out.values()))
     out["wall_ms"] = wall
     return {k: round(v, 3) for k, v in out.items()}

@@ -79,12 +79,14 @@ def test_trace_marker_gets_deadlock_guard(request):
 def test_span_names_registry():
     assert trace.SPAN_NAMES
     for name in ("router.dispatch", "connect.request", "scheduler.run",
-                 "query.execute", "stage.run", "stage.device",
+                 "query.execute", "stage.run", "tier.decide",
                  "pipeline.decode", "pipeline.transfer", "fault.retry",
                  "query.parse", "query.optimize", "query.plan",
                  "stage.dispatch", "query.fetch", "device.wait",
-                 "fetch.copy", "query.rows"):
+                 "fetch.copy", "query.rows", "admission.note"):
         assert name in trace.SPAN_NAMES, name
+    # the host's wait on the device has ONE name
+    assert "stage.device" not in trace.SPAN_NAMES
 
 
 def test_span_ids_unique_and_wire_safe():
@@ -215,6 +217,15 @@ def test_span_tree_well_formed_multi_stage_plan(spark, tmp_path):
     assert all(e.get("trace_id") == next(iter(tids)) for e in stages)
 
 
+def _own_ms(spans, root):
+    """span_id -> self time: a span's ``ms`` less its children's."""
+    own = {e["span_id"]: e["ms"] for e in spans}
+    for e in spans:
+        if e is not root:
+            own[e["parent_id"]] -= e["ms"]
+    return own
+
+
 def _inside(child, parent, slack_ms=0.5):
     """``child``'s interval lies inside ``parent``'s (t0 is on
     time.time(), ms on perf_counter: allow the two clocks a little)."""
@@ -275,12 +286,101 @@ def test_collect_is_one_trace_with_every_phase(spark, tmp_path):
     assert wall_ms - root["ms"] <= max(0.05 * wall_ms, 0.3), (
         wall_ms, root["ms"])
     # self times partition the root
-    own = {e["span_id"]: e["ms"] for e in spans}
-    for e in spans:
-        if e is not root:
-            own[e["parent_id"]] -= e["ms"]
+    own = _own_ms(spans, root)
     assert sum(own.values()) == pytest.approx(root["ms"])
     assert all(v >= -0.05 for v in own.values()), own
+
+
+def _steady_spans(spark, tmp_path, view, text, runs=3):
+    """``text`` run ``runs`` times over a small parquet view ``view``;
+    the span events of the last execution."""
+    _write_parquet(tmp_path / f"{view}.parquet", 96, 6)
+    spark.read.parquet(str(tmp_path / f"{view}.parquet")) \
+        .createOrReplaceTempView(view)
+    for _ in range(runs):
+        rows = spark.sql(text).collect()
+    assert rows
+    return _spans(metrics.last_query())
+
+
+def test_tier_decide_and_admission_note_where_glue_is_spent(spark,
+                                                            tmp_path):
+    """A resident one-chip query: ONE tier.decide, closed before the
+    plan is bound and the stage runs, and TWO admission.note (the
+    optimized plan's, then the raw one's) after the stage, all under
+    storage.pin."""
+    spans = _steady_spans(
+        spark, tmp_path, "tr_td",
+        "SELECT k, SUM(v) AS s FROM tr_td GROUP BY k ORDER BY k")
+    by_id = {e["span_id"]: e for e in spans}
+    decide = [e for e in spans if e["name"] == "tier.decide"]
+    notes = [e for e in spans if e["name"] == "admission.note"]
+    assert [e["tier"] for e in decide] == ["resident"]
+    assert [e["key"] for e in notes] == ["optimized", "raw"]
+    for e in decide + notes:
+        assert by_id[e["parent_id"]]["name"] == "storage.pin"
+    for e in notes:
+        assert e["events"] > 0 and e["bytes"] >= 0
+    at = {e["name"]: e for e in spans if e["name"] != "admission.note"}
+
+    def end(e):
+        return e["t0"] + e["ms"] / 1e3
+
+    assert at["query.optimize"]["t0"] <= decide[0]["t0"]
+    assert end(decide[0]) <= at["query.plan"]["t0"] + 5e-5
+    assert end(at["stage.run"]) <= notes[0]["t0"] + 5e-5
+    assert end(notes[1]) <= at["query.fetch"]["t0"] + 5e-5
+
+
+def test_tier_decide_says_chunked_under_a_small_budget(spark, tmp_path):
+    spark.conf.set("spark.tpu.maxDeviceBatchBytes", 1024)
+    try:
+        spans = _steady_spans(
+            spark, tmp_path, "tr_tc",
+            "SELECT k, COUNT(v) AS n FROM tr_tc GROUP BY k", runs=1)
+    finally:
+        spark.conf.unset("spark.tpu.maxDeviceBatchBytes")
+    decide = [e for e in spans if e["name"] == "tier.decide"]
+    assert [e["tier"] for e in decide] == ["chunked"]
+    # only a resident run is noted under the optimized plan
+    assert [e["key"] for e in spans
+            if e["name"] == "admission.note"] == ["raw"]
+
+
+def test_self_times_partition_every_execution(spark, tmp_path):
+    """With the new spans in the tree the self times of all names still
+    add up to ``query.execute``, execution by execution."""
+    _write_parquet(tmp_path / "tr_pt.parquet", 96, 6)
+    spark.read.parquet(str(tmp_path / "tr_pt.parquet")) \
+        .createOrReplaceTempView("tr_pt")
+    for _ in range(6):
+        spark.sql("SELECT k, SUM(v) AS s FROM tr_pt GROUP BY k").collect()
+        spans = _spans(metrics.last_query())
+        root, = _roots(spans)
+        assert root["name"] == "query.execute"
+        total = sum(max(0.0, v) for v in _own_ms(spans, root).values())
+        assert total == pytest.approx(root["ms"], rel=0.02)
+        assert {"tier.decide", "admission.note"} <= {e["name"]
+                                                     for e in spans}
+
+
+def test_slo_components_read_the_wait_on_one_chip(spark, tmp_path):
+    """The SLO model's device component is the host's wait on the
+    device under its one name, so a one-chip ticket has one."""
+    import types
+
+    from spark_tpu.slo.controller import SloController
+
+    _write_parquet(tmp_path / "tr_slo.parquet", 64, 4)
+    df = spark.read.parquet(str(tmp_path / "tr_slo.parquet"))
+    with trace.span("scheduler.run") as ctx:
+        assert df.groupBy("k").sum("v").collect()
+    device_ms, transfer_ms, cold = SloController._span_components(
+        types.SimpleNamespace(_trace_ctx=ctx))
+    waits = [e["ms"] for e in _spans(metrics.query_events(ctx.trace_id))
+             if e["name"] == "device.wait"]
+    assert waits and device_ms == pytest.approx(sum(waits)) and device_ms > 0
+    assert transfer_ms == 0.0
 
 
 @pytest.mark.parametrize("action", ["collect", "toPandas", "toArrow",
@@ -296,39 +396,101 @@ def test_every_action_fetches_inside_the_root(spark, action):
             "query.rows"} <= names, action
 
 
-def test_mesh_execution_has_the_same_phases():
-    """mesh[2]: the mesh engine's own optimize / plan spans, its
-    stage.device (the forced sync, as it was) with the enqueue as
-    stage.dispatch inside it, and the gather as fetch.copy."""
+@pytest.fixture
+def mesh2():
+    """A ``mesh[2]`` session in place of the suite's; put back after."""
     from spark_tpu.api.session import SparkSession
 
     prev = SparkSession._active
     SparkSession._reset()
     try:
-        mesh = (SparkSession.builder.master("mesh[2]")
-                .appName("trace-mesh").getOrCreate())
-        df = mesh.range(4000).groupBy().sum("id")
-        assert df.collect()[0][0] == sum(range(4000))
-        spans = _spans(metrics.last_query())
+        yield (SparkSession.builder.master("mesh[2]")
+               .appName("trace-mesh").getOrCreate())
     finally:
         SparkSession._reset()
         SparkSession._active = prev
+
+
+def test_mesh_execution_has_the_same_phases(mesh2):
+    """mesh[2]: the mesh engine's own optimize / plan spans, the enqueue
+    as stage.dispatch under stage.run as on one chip, no forced sync of
+    its own (the wait is device.wait, in the fetch), and the gather as
+    fetch.copy."""
+    df = mesh2.range(4000).groupBy().sum("id")
+    assert df.collect()[0][0] == sum(range(4000))
+    spans = _spans(metrics.last_query())
     by_id = {e["span_id"]: e for e in spans}
     roots = _roots(spans)
     assert [r["name"] for r in roots] == ["query.execute"]
     names = {e["name"] for e in spans}
-    assert {"query.optimize", "query.plan", "stage.run", "stage.device",
-            "stage.dispatch", "fetch.copy", "query.fetch",
-            "query.rows"} <= names
+    assert {"query.optimize", "tier.decide", "query.plan", "stage.run",
+            "stage.dispatch", "admission.note", "device.wait",
+            "fetch.copy", "query.fetch", "query.rows"} <= names
+    assert "stage.device" not in names
     for e in spans:
         if e["name"] == "stage.dispatch":
-            assert by_id[e["parent_id"]]["name"] == "stage.device"
+            assert by_id[e["parent_id"]]["name"] == "stage.run"
     assert any(e["name"] == "fetch.copy" and e.get("op") == "gather"
                for e in spans)
     bd = tracing.trace_breakdown(spans)
+    assert bd["device_ms"] == pytest.approx(
+        sum(e["ms"] for e in spans if e["name"] == "device.wait"), abs=0.01)
     assert bd["device_ms"] > 0
     assert bd["device_ms"] + bd["fetch_ms"] + bd["host_ms"] == \
         pytest.approx(bd["wall_ms"], abs=0.01)
+
+
+def test_mesh_schedule_does_not_depend_on_sampling(mesh2, monkeypatch):
+    """A multi-stage mesh query forces the same number of syncs, and
+    returns the same rows, whether its trace samples or not."""
+    import jax
+
+    from spark_tpu.api import functions as F
+
+    a = mesh2.range(6000).withColumnRenamed("id", "k")
+    b = mesh2.range(3000).withColumnRenamed("id", "k2")
+    df = a.join(b, a["k"] == b["k2"]).groupBy().agg(
+        F.sum("k").alias("s"), F.count("k").alias("n"))
+    df.collect()                        # build the stages once
+    syncs = []
+    block = jax.block_until_ready
+
+    def counted(x):
+        syncs.append(1)
+        return block(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counted)
+    seen = {}
+    for ratio in (1.0, 0.0):            # the session goes with the test
+        mesh2.conf.set("spark.tpu.trace.sampleRatio", ratio)
+        del syncs[:]
+        rows = df.collect()
+        evs = metrics.last_query()
+        seen[ratio] = (rows, len(syncs),
+                       sum(e["kind"] == "stage" for e in evs))
+        assert bool(_spans(evs)) == (ratio == 1.0)
+    assert seen[1.0] == seen[0.0]
+    rows, n_syncs, stages = seen[1.0]
+    assert (rows[0][0], rows[0][1]) == (sum(range(3000)), 3000)
+    assert stages >= 2 and n_syncs == 1       # the fetch's, and no other
+
+
+def test_mesh_readback_between_stages_is_a_device_wait(mesh2):
+    """A stage that holds an exchange reads its output's mask back for
+    the exchange's record: the host waits for the stage there, under
+    the one name that wait has."""
+    df = mesh2.range(4000).selectExpr("id % 4 AS k", "id").groupBy("k") \
+        .sum("id").orderBy("k")
+    assert [r[1] for r in df.collect()] == [
+        sum(range(k, 4000, 4)) for k in range(4)]
+    spans = _spans(metrics.last_query())
+    by_id = {e["span_id"]: e for e in spans}
+    readbacks = [e for e in spans if e["name"] == "device.wait"
+                 and e.get("op") == "readback"]
+    assert readbacks
+    assert {by_id[e["parent_id"]]["name"] for e in readbacks} == {"stage.run"}
+    assert any(e["kind"] == "exchange" and e.get("mode") == "fused"
+               for e in metrics.last_query())
 
 
 def test_tracing_off_same_rows_no_span_event(spark, tmp_path, trace_conf):
