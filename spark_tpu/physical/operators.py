@@ -860,17 +860,61 @@ def group_key_codes(key_tvs: List[TV]):
 
 def sorted_groups(pipe: Pipe, key_tvs: List[TV]):
     """Sort rows by grouping keys and assign change-flag group ids.
-    Returns (sorted_pipe, sorted_key_tvs, seg_ids, num_groups_traced)."""
-    keys = [K.SortKey(tv.data, tv.validity, True, True) for tv in key_tvs]
-    perm = K.lexsort_permutation(keys, pipe.mask)
+    Returns (sorted_pipe, sorted_key_tvs, seg_ids, num_groups_traced).
+
+    The rows themselves are sorted, not a permutation of them: ONE stable
+    ``lax.sort`` whose keys are ``~mask`` (live rows first) and, per
+    grouping key, its validity (nulls first) and its data with null rows
+    zeroed — ``K.lexsort_permutation``'s order bit for bit, ties in row
+    order — carries every 1-D column array as an operand: a random gather
+    is the chip's slowest operation (13-21 ns a row on the v5e; PERF.md,
+    PR 38) and the sort moves its operands together. A 2-D column cannot
+    ride (the operands share one shape): the sort carries a row index as
+    well and only such a column is gathered by it. XLA drops the operands
+    whose outputs nothing reads."""
+    n = pipe.mask.shape[0]
+    operands = [~pipe.mask]
+    at: Dict[int, int] = {}       # id(array) -> index of its sorted self
+
+    def ride(a) -> None:
+        at.setdefault(id(a), len(operands))
+        operands.append(a)
+
+    for tv in key_tvs:
+        if tv.validity is None:
+            ride(tv.data)
+        else:
+            ride(tv.validity)
+            operands.append(jnp.where(tv.validity, tv.data,
+                                      jnp.zeros((), tv.data.dtype)))
+    num_keys = len(operands)
+    wide = set()                  # ids of 2-D arrays the sort cannot carry
+    for tv in [*pipe.cols.values(), *key_tvs]:
+        for a in (tv.data, tv.validity):
+            if a is None or id(a) in at:
+                continue
+            if a.ndim == 1:
+                ride(a)
+            else:
+                wide.add(id(a))
+    if wide:
+        ride(jnp.arange(n, dtype=jnp.int32))
+    _trace.built("sort", site="group_rows", rows=int(n), keys=num_keys,
+                 operands=len(operands), gathered=len(wide))
+    out = jax.lax.sort(tuple(operands), num_keys=num_keys, is_stable=True)
+    perm = out[-1] if wide else None
+
+    def moved(a):
+        if a is None:
+            return None
+        return out[at[id(a)]] if id(a) in at else a[perm]
 
     def take(tv: TV) -> TV:
-        return TV(tv.data[perm],
-                  None if tv.validity is None else tv.validity[perm],
-                  tv.dtype, tv.dictionary)
+        return TV(moved(tv.data), moved(tv.validity), tv.dtype,
+                  tv.dictionary)
 
     spipe = Pipe({name: take(tv) for name, tv in pipe.cols.items()},
-                 pipe.mask[perm], pipe.order)
+                 ~out[0], pipe.order)
     sorted_keys = [take(tv) for tv in key_tvs]
     seg, ng = K.group_ids_from_sorted(
         [(tv.data, tv.validity) for tv in sorted_keys], spipe.mask)
@@ -1100,7 +1144,7 @@ class GroupCountExec(PhysicalPlan):
     def trace(self, child_pipes: List[Pipe]) -> Pipe:
         pipe = child_pipes[0]
         key_tvs = [C.evaluate(g, pipe.env()) for g in self.groupings]
-        # the gathers of the columns nobody reads here are dropped by XLA
+        # XLA drops the sort's operands that nobody reads here
         _, _, _, ng = sorted_groups(pipe, key_tvs)
         return Pipe({"groups": TV(ng.astype(jnp.int64)[None], None, T.INT64,
                                   None)},
@@ -1290,9 +1334,9 @@ class HashAggregateExec(PhysicalPlan):
     # -- sort-based path ------------------------------------------------------
     #
     # Two halves, each under a scope of its own inside the operator's
-    # (trace.INNER_SCOPES), so a profile tells the sort and its gathers
-    # from the sums. The direct path opens neither: its operations stay
-    # under spark.HashAggregateExec.
+    # (trace.INNER_SCOPES), so a profile tells the sort from the sums.
+    # The direct path opens neither: its operations stay under
+    # spark.HashAggregateExec.
 
     def _group_sort(self, pipe: Pipe, key_tvs: List[TV]):
         with _trace.inner_scope("GroupSort"):

@@ -132,7 +132,11 @@ BUILD_EVENTS = frozenset({
                  # k, rows, dtype, passes
     "join",      # JoinExec.trace: rung (table/index/live), how,
                  # orientation, build rows, probe capacity
-    "sort",      # kernels: one per XLA sort built; site, rows, dtype
+    "sort",      # one per XLA sort built; site, rows, and dtype
+                 # (kernels.py) or, for site group_rows (the rows of a
+                 # sorted aggregate, operators.sorted_groups), keys (sort
+                 # keys), operands (arrays that ride the sort) and
+                 # gathered (2-D columns gathered by its row index)
     "gather",    # parallel/sharded.py: one per mesh packer built (the
                  # program a MeshResult's fetch leaves the mesh by);
                  # mesh, capacity, arrays
@@ -155,8 +159,8 @@ SCOPE_PREFIX = "spark."
 #: lint_invariants rule 6 holds every ``trace.inner_scope("<name>")``
 #: literal to this set.
 INNER_SCOPES = frozenset({
-    "GroupSort",  # sort-based aggregate: the lexsort of the grouping
-                  # keys, the gathers by it, the change-flag group ids
+    "GroupSort",  # sort-based aggregate: the one sort of its rows by
+                  # the grouping keys, the change-flag group ids
     "GroupSum",   # sort-based aggregate: the aggregates over the sorted
                   # group ids and the groups' first keys
 })

@@ -20,7 +20,7 @@ import jax
 import pytest
 
 from spark_tpu import metrics, trace
-from test_operator_scopes import _captured_stages
+from test_operator_scopes import _captured_stages, _events
 from test_q14_join_replay import _load, _lookups, _new_events
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -137,16 +137,19 @@ def _of(events, kind):
 def test_the_aggregate_is_built_sorted(runs, execution):
     """The first execution counts the groups in one stage and runs the
     aggregate sized by the count as another; the second traces the whole
-    query's stage. Each aggregate stage builds two XLA sorts (the key's
-    argsort and the live rows'), one int64 ``seg_sum`` on the cumsum rung
-    (past the masked rung's 64 slots, over the sorted ids), and a
-    ``group_by`` event that says so; the count sorts once more. A
-    tripwire on the program's shape."""
+    query's stage. Each aggregate stage builds ONE XLA sort of its rows
+    (keys ``~mask`` and the supplier key; the columns ride it, none is
+    gathered: PR 38), one int64 ``seg_sum`` on the cumsum rung (past the
+    masked rung's 64 slots, over the sorted ids), and a ``group_by``
+    event that says so; the count sorts once more. A tripwire on the
+    program's shape."""
     _rows_got, events, _n = runs[SEEDS[0]]["executions"][execution]
     stages = [e["node"].split("[")[0] for e in _of(events, "stage_compile")]
     assert stages == [["GroupCount", "HashAggregate"], ["Compact"]][execution]
-    assert [(e["site"], e["dtype"]) for e in _of(events, "sort")] == [
-        ("lexsort", "int64"), ("lexsort", "bool")] * len(stages)
+    sorts = _of(events, "sort")
+    assert [(e["site"], e["keys"], e["gathered"]) for e in sorts] == [
+        ("group_rows", 2, 0)] * len(stages)
+    assert all(e["operands"] >= 4 for e in sorts)   # the three columns
     (summed,) = _of(events, "seg_sum")
     assert summed["k"] > 64 and summed["rung"] == "cumsum"
     assert summed["dtype"] == "int64" and "limbs" not in summed
@@ -315,3 +318,197 @@ def test_seg_first_over_sorted_ids_is_the_first_masked_row(case):
             assert bool(flags[g]) == bool(rows.size), (case, g)
             if rows.size:
                 assert values[g] == data[rows[0]], (case, g)
+
+
+def _lexsort_and_gather(pipe, key_tvs):
+    """``sorted_groups`` as it was until PR 38, the reference the one
+    row sort is held to: ``lexsort_permutation`` and a gather of every
+    array by the permutation."""
+    from spark_tpu.expr.compiler import TV
+    from spark_tpu.physical import kernels as K, operators as P
+
+    keys = [K.SortKey(tv.data, tv.validity, True, True) for tv in key_tvs]
+    perm = K.lexsort_permutation(keys, pipe.mask)
+
+    def take(tv):
+        return TV(tv.data[perm],
+                  None if tv.validity is None else tv.validity[perm],
+                  tv.dtype, tv.dictionary)
+
+    spipe = P.Pipe({name: take(tv) for name, tv in pipe.cols.items()},
+                   pipe.mask[perm], pipe.order)
+    sorted_keys = [take(tv) for tv in key_tvs]
+    seg, ng = K.group_ids_from_sorted(
+        [(tv.data, tv.validity) for tv in sorted_keys], spipe.mask)
+    return spipe, sorted_keys, seg, ng
+
+
+def _group_case(case, n=3001):
+    """(arrays, columns, keys, operands, gathered) for one case:
+    ``columns`` maps a column's name to (data, validity, dtype,
+    dictionary) by the arrays' names, so one array under two names is one
+    array in the trace; a key names a column, or is ``("mod", column,
+    m)``, an expression the pipe does not hold."""
+    import numpy as np
+
+    from spark_tpu import types as T
+
+    rng = np.random.default_rng(38)
+    arrays = {
+        "mask": rng.random(n) < 0.7,          # dead rows interleaved
+        "i64": rng.integers(-40, 40, n).astype(np.int64),
+        "i64_ok": rng.random(n) < 0.8,
+        "i32": rng.integers(-4, 9, n).astype(np.int32),
+        "i32_ok": rng.random(n) < 0.9,
+        "flag": rng.random(n) < 0.5,
+        "code": rng.integers(0, 5, n).astype(np.int32),
+        "code_ok": rng.random(n) < 0.85,
+        "price": rng.integers(-10**12, 10**12, n).astype(np.int64),
+        "f64": rng.standard_normal(n),
+        "f64_ok": rng.random(n) < 0.9,
+        "arr": rng.integers(0, 100, (n, 3)).astype(np.int32),
+        "fkey": rng.choice(np.array([0.0, -0.0, np.nan, 1.5, -2.0]), n),
+    }
+    dictionary = ("AIR", "MAIL", "RAIL", "SHIP", "TRUCK")
+    columns = {
+        "k64": ("i64", None, T.INT64, None),
+        "k64n": ("i64", "i64_ok", T.INT64, None),
+        "k32n": ("i32", "i32_ok", T.INT32, None),
+        "kb": ("flag", None, T.BOOLEAN, None),
+        "ks": ("code", "code_ok", T.STRING, dictionary),
+        "price": ("price", None, T.INT64, None),
+        "f": ("f64", "f64_ok", T.FLOAT64, None),
+    }
+    keys, gathered = ["k64"], 0
+    if case == "one_nullable_key":
+        keys = ["k64n"]
+    elif case == "two_keys_int32_bool":
+        keys = ["k32n", "kb"]
+    elif case == "dictionary_string_and_int64":
+        keys = ["ks", "k64"]
+    elif case == "all_rows_dead":
+        arrays["mask"] = np.zeros(n, bool)
+        keys = ["k64n", "kb"]
+    elif case == "array_column":
+        columns["arr"] = ("arr", None, T.ArrayType(T.INT32), None)
+        gathered = 1
+    elif case == "aliased_column":
+        columns["k64_again"] = columns["k64"]
+        columns["price_again"] = columns["price"]
+    elif case == "expression_key":
+        keys = [("mod", "k32n", 4), "kb"]
+    elif case == "float_key_zeros_nans":
+        columns["fk"] = ("fkey", "f64_ok", T.FLOAT64, None)
+        keys = ["fk"]
+    # the arrays that ride the sort: ~mask, each key's validity and data,
+    # then the ten distinct 1-D arrays of the seven columns that are not
+    # a key already (a key's raw data is one where it has a validity), an
+    # expression key's data, fkey, and the row index a 2-D column needs
+    operands = {"one_key": 1 + 1 + 9, "one_nullable_key": 1 + 2 + 9,
+                "two_keys_int32_bool": 1 + 3 + 8,
+                "dictionary_string_and_int64": 1 + 3 + 8,
+                "all_rows_dead": 1 + 3 + 8, "array_column": 1 + 1 + 9 + 1,
+                "aliased_column": 1 + 1 + 9,
+                "expression_key": 1 + 3 + 8 + 1,
+                "float_key_zeros_nans": 1 + 2 + 10}[case]
+    return arrays, columns, keys, operands, gathered
+
+
+GROUP_CASES = ["one_key", "one_nullable_key", "two_keys_int32_bool",
+               "dictionary_string_and_int64", "all_rows_dead",
+               "array_column", "aliased_column", "expression_key",
+               "float_key_zeros_nans"]
+
+
+@pytest.mark.parametrize("case", GROUP_CASES)
+def test_the_row_sort_equals_the_lexsort_and_its_gathers(case):
+    """``sorted_groups`` sorts the rows in ONE stable ``lax.sort`` that
+    carries the columns as operands (PR 38) where it gathered every array
+    by ``lexsort_permutation``: every array it returns — the pipe's
+    columns and validities, its mask, the sorted keys, the group ids and
+    their count — is bit for bit the lexsort's and its gathers', floats
+    (-0.0, NaN) and the tie order of equal keys included; a 2-D column
+    takes the gather by a row index the sort carries. The ``sort`` build
+    event says how many arrays rode and how many were gathered."""
+    import numpy as np
+
+    from spark_tpu.expr.compiler import TV
+    from spark_tpu.physical import operators as P
+
+    arrays, columns, keys, operands, gathered = _group_case(case)
+
+    def run(sorter, a):
+        cols = {name: TV(a[d], None if v is None else a[v], dt, dic)
+                for name, (d, v, dt, dic) in columns.items()}
+        key_tvs = []
+        for k in keys:
+            if isinstance(k, tuple):
+                _, name, m = k
+                tv = cols[name]
+                key_tvs.append(TV(tv.data % m, tv.validity, tv.dtype))
+            else:
+                key_tvs.append(cols[k])
+        spipe, sorted_keys, seg, ng = sorter(
+            P.Pipe(cols, a["mask"], list(cols)), key_tvs)
+        return ({n: (tv.data, tv.validity) for n, tv in spipe.cols.items()},
+                spipe.mask, [(tv.data, tv.validity) for tv in sorted_keys],
+                seg, ng)
+
+    args = {k: jax.numpy.asarray(v) for k, v in arrays.items()}
+    want = jax.jit(lambda a: run(_lexsort_and_gather, a))(args)
+    got, events = _events("sort", lambda: jax.jit(
+        lambda a: run(P.sorted_groups, a))(args))
+    flat_want, tree_want = jax.tree.flatten(want)
+    flat_got, tree_got = jax.tree.flatten(got)
+    assert tree_got == tree_want
+    for w, g in zip(flat_want, flat_got):
+        w, g = np.asarray(w), np.asarray(g)
+        assert (w.dtype, w.shape) == (g.dtype, g.shape)
+        assert w.tobytes() == g.tobytes()
+    ((event,),) = [events]
+    assert event == {**event, "site": "group_rows", "rows": 3001,
+                     "keys": 1 + sum(2 if columns[k if isinstance(k, str)
+                                                  else k[1]][1] else 1
+                                     for k in keys),
+                     "operands": operands, "gathered": gathered}
+    if case == "all_rows_dead":
+        assert int(want[-1]) == 0
+    else:
+        assert int(want[-1]) > 1
+
+
+@pytest.mark.parametrize("reads,carried", [
+    ("the_count", ["pred", "s64"]),
+    ("one_column", ["pred", "s64", "s64"]),
+    ("every_column", ["pred", "s64", "s64", "pred", "f64"])])
+def test_the_row_sort_carries_only_what_its_caller_reads(reads, carried):
+    """XLA's sort simplifier drops the operands whose outputs nothing
+    reads, so ``GroupCountExec``, which reads the group count alone,
+    sorts ``(~mask, key)`` and no column: held in the optimised text."""
+    import jax.numpy as jnp
+
+    from spark_tpu import types as T
+    from spark_tpu.expr.compiler import TV
+    from spark_tpu.physical import operators as P
+
+    n = 4096
+    args = {"k": jnp.arange(n) % 7, "p": jnp.arange(n),
+            "pv": jnp.arange(n) % 3 > 0, "q": jnp.ones(n),
+            "m": jnp.arange(n) % 5 > 0}
+
+    def fn(a):
+        cols = {"k": TV(a["k"], None, T.INT64),
+                "p": TV(a["p"], a["pv"], T.INT64),
+                "q": TV(a["q"], None, T.FLOAT64)}
+        spipe, _keys, seg, ng = P.sorted_groups(
+            P.Pipe(cols, a["m"], list(cols)), [cols["k"]])
+        if reads == "the_count":
+            return ng
+        if reads == "one_column":
+            return spipe.cols["p"].data, seg
+        return [(tv.data, tv.validity) for tv in spipe.cols.values()], seg
+
+    text = jax.jit(fn).lower(args).compile().as_text()
+    (sort,) = [line for line in text.splitlines() if " sort(" in line]
+    assert re.findall(r"(\w+)\[" + str(n) + r"\]",
+                      sort.split(" sort(")[0]) == carried

@@ -201,6 +201,18 @@ def _scatter_combiners(text):
     return roots
 
 
+def _row_gathers(text, rows):
+    """The gathers of an optimised program that move a whole column of
+    ``rows`` rows (their result is ``[rows]``: by a permutation of the
+    rows): a random gather is the chip's slowest operation (13-21 ns a
+    row; fourteen of them were 330 of the cell's 408 ms an execution
+    until the rows rode the sort, PERF.md, PR 38). The operand's shape is
+    not in the optimised text; the gathers at the segments' bounds read
+    a whole column into ``[100096]`` and are not these."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(r"= \w+\[" + str(rows) + r"\]\S* gather\(", line)]
+
+
 def test_q15_sorted_aggregate_sums_int64_with_no_scatter_add_on_v5e(
         one_chip, as_the_session_runs, spark, monkeypatch, tmp_path):
     """The benchmark's tpch_sf10_q15_revenue at its own shape: the whole
@@ -241,7 +253,12 @@ def test_q15_sorted_aggregate_sums_int64_with_no_scatter_add_on_v5e(
     assert 1 <= len(combiners) <= 3
     assert all(" parameter(1)" in root for root in combiners), combiners
     sorts = re.findall(r" sort\(", text)
-    assert len(sorts) <= 5, len(sorts)   # two lexsorts, one pair of bounds
+    gathers = _row_gathers(text, Q15_ROWS)
+    print(f"q15 at {Q15_ROWS} rows: {len(sorts)} sorts, "
+          f"{len(gathers)} gathers of the rows", gathers[:3])
+    # one sort of the rows (PR 38; two lexsorts before), the bounds' pair
+    assert len(sorts) <= 4, len(sorts)
+    assert not gathers
 
 
 def _leaf_splits(text, rows):

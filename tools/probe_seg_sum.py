@@ -12,6 +12,7 @@ reductions of G slots; int64 sum, count, f32 min; SF1's and SF10's rows):
   chiprun --timeout 1800 -- python tools/probe_seg_sum.py --passes  # ~6 min
   chiprun -- python tools/probe_seg_sum.py --narrow   # PR 31, ~2 min
   chiprun -- python tools/probe_seg_sum.py --carrier  # PR 36, ~3 min
+  chiprun -- python tools/probe_seg_sum.py --group-sort  # PR 38, ~2 min
 
 ``--narrow``: the K = 6 sum (and q1's five sums and a count) over values
 that fit 32 bits, from int64 parameters against int32 parameters widened
@@ -27,6 +28,15 @@ device's own time: a call of either costs the host more than the sum). And Q15's
 shape (2,421,760 rows sorted by group, K = 100,096, int32 ids): three
 limb scatter-adds, one int64 scatter-add, one int64 cumsum; then the sum
 with the COUNT beside it, their segment bounds searched twice or once.
+
+``--group-sort``: the sort half of Q15's aggregate at the cell's shape
+(2,421,760 rows, one int64 key of 1..100,000 suppliers, two int64
+payloads, 466 dead rows; the three arrive int32 and are widened in the
+program, as a narrowed scan's are): (a) ``lexsort_permutation`` and a
+gather of every array by it, the engine until PR 38; (b) ONE stable sort
+of the rows with the payloads as its operands; (c) one stable argsort of
+``(~live, key)`` and one gather a column; each with the change-flag
+group ids.
 
 Every variant is written out from primitives here, so the probe reads the
 same after the engine's own choice changes. Each line of output is one
@@ -327,6 +337,56 @@ def probe_carrier(reps=20):
     return bad
 
 
+def probe_group_sort(reps=20):
+    """The sort half of the sorted aggregate, three ways. Returns the
+    number of wrong or failed variants."""
+    n = ROWS_Q15
+    rng = np.random.default_rng(38)
+    key = rng.integers(1, 100_001, n).astype(np.int32)
+    pays = [rng.integers(0, 1_050_000_000, n).astype(np.int32),
+            rng.integers(0, 11, n).astype(np.int32)]
+    mask = np.ones(n, bool)
+    mask[rng.choice(n, 466, replace=False)] = False
+    order = np.lexsort((key, ~mask))            # stable: ties in row order
+    skey, smask = key[order].astype(np.int64), mask[order]
+    change = np.concatenate([[False], skey[1:] != skey[:-1]]) & smask
+    seg = np.cumsum(change, dtype=np.int32)
+    refs = [skey, smask, seg, np.int32(seg[-1] + 1)] + [
+        p[order].astype(np.int64) for p in pays]
+    args = (jnp.asarray(key), jnp.asarray(mask),
+            [jnp.asarray(p) for p in pays])
+
+    def ids(k, m):
+        return K.group_ids_from_sorted([(k, None)], m)
+
+    def lexsort_gather(k, m, ps):
+        k, ps = k.astype(jnp.int64), [p.astype(jnp.int64) for p in ps]
+        perm = K.lexsort_permutation([K.SortKey(k, None)], m)
+        sk, sm = k[perm], m[perm]
+        return [sk, sm, *ids(sk, sm)] + [p[perm] for p in ps]
+
+    def one_sort(k, m, ps):
+        k, ps = k.astype(jnp.int64), [p.astype(jnp.int64) for p in ps]
+        dead, sk, *sps = jax.lax.sort((~m, k, *ps), num_keys=2,
+                                      is_stable=True)
+        return [sk, ~dead, *ids(sk, ~dead)] + sps
+
+    def argsort_gather(k, m, ps):
+        k, ps = k.astype(jnp.int64), [p.astype(jnp.int64) for p in ps]
+        _, _, perm = jax.lax.sort((~m, k, jnp.arange(n, dtype=jnp.int32)),
+                                  num_keys=2, is_stable=True)
+        sk, sm = k[perm], m[perm]
+        return [sk, sm, *ids(sk, sm)] + [p[perm] for p in ps]
+
+    bad = 0
+    for name, fn in (("lexsort_gather", lexsort_gather),
+                     ("one_sort", one_sort),
+                     ("argsort_gather", argsort_gather)):
+        bad += _measure({"rows": n, "live": int(mask.sum()), "payloads": 2,
+                         "variant": name}, fn, args, reps, refs)
+    return bad
+
+
 def _time(fn, args, reps):
     """(blocking median ms, pipelined mean ms, compile s, result)."""
     t0 = time.perf_counter()
@@ -364,6 +424,9 @@ def main() -> int:
                     help="only the f64-limb carrier against int64: K == 1 "
                     "at q14's, q6's and SF10's rows, and Q15's sorted "
                     "aggregate at the benchmark's shape")
+    ap.add_argument("--group-sort", action="store_true",
+                    help="only the sort half of Q15's sorted aggregate, "
+                    "three ways, at the benchmark's shape")
     ap.add_argument("--rows", type=int)
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearsal only: times mean nothing")
@@ -372,6 +435,9 @@ def main() -> int:
     if dev.platform != "tpu" and not a.allow_cpu:
         print("probe_seg_sum: no TPU", file=sys.stderr)
         return 2
+    if a.group_sort:
+        print(json.dumps({"device": dev.device_kind}))
+        return 1 if probe_group_sort() else 0
     if a.carrier:
         print(json.dumps({"device": dev.device_kind}))
         return 1 if probe_carrier() else 0
