@@ -134,6 +134,24 @@ def translate_filters(
     return pushed, residual
 
 
+def _selects_a_category(filters: Tuple[E.Expression, ...]) -> bool:
+    """Does a pushed conjunct keep the rows of some string values
+    (``col = 'v'``, ``col IN ('v', ...)``): a market segment, a region,
+    a brand? How many rows that keeps is the data's to say, a little
+    different for every value and every load of the table, so the scan
+    takes ``relative_bucket``'s capacity and the programs compiled for
+    one value or load are found again for the next (ROADMAP N1)."""
+    for f in filters:
+        if isinstance(f, E.Cmp) and f.op == "==":
+            lits = [s for s in (f.left, f.right) if isinstance(s, E.Literal)]
+            if len(lits) == 1 and isinstance(lits[0].value, str):
+                return True
+        if isinstance(f, E.In) and f.values \
+                and all(isinstance(v, str) for v in f.values):
+            return True
+    return False
+
+
 def _filters_to_pads(
     filters: Tuple[E.Expression, ...],
     dtypes=None,
@@ -320,6 +338,7 @@ class FileSource:
 
         from spark_tpu import metrics
         from spark_tpu.columnar.arrow import from_arrow
+        from spark_tpu.physical.kernels import relative_bucket
 
         ds = self._open()  # first: freshness check may clear the cache
         key = (columns, tuple(E.expr_key(f) for f in filters))
@@ -351,7 +370,10 @@ class FileSource:
         # whose values fit int32 is RESIDENT as int32 (what the column
         # holds decides, once per scan: the batch is cached below) and
         # every stage widens it at trace entry (Pipe.from_batch_data)
-        batch = from_arrow(table, narrow_transfer=True)
+        batch = from_arrow(table, narrow_transfer=True,
+                           capacity=(relative_bucket(table.num_rows)
+                                     if _selects_a_category(filters)
+                                     else None))
         # wait for the transfer, or transfer_ms is an enqueue time
         batch.block_until_ready()
         t2 = _time.perf_counter()

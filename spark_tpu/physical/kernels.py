@@ -512,8 +512,10 @@ def build_join_ranges(
 
 
 #: dense lo/cnt lookup tables are built when the packed key domain is at
-#: most this many entries (int32 x2 -> 64 MB @ 8M; orderkey at SF1 is 6M)
-JOIN_TABLE_MAX = 1 << 23
+#: most this many entries (int32 x2 -> 128 MB @ 16M; orderkey is 6M at
+#: SF1 and 15M at SF10, where a probe of the sorted key instead co-sorts
+#: some 32M rows twice an execution)
+JOIN_TABLE_MAX = 1 << 24
 
 
 def make_join_index(build_key: jnp.ndarray, build_ok: jnp.ndarray,
@@ -538,12 +540,50 @@ def make_join_index(build_key: jnp.ndarray, build_ok: jnp.ndarray,
     skey = masked[perm]
     lo_t = cnt_t = None
     if domain is not None and 0 < domain <= JOIN_TABLE_MAX:
-        vals = jnp.arange(domain, dtype=build_key.dtype)
-        lo = searchsorted(skey, vals, "left")
-        hi = searchsorted(skey, vals, "right")
-        lo_t = lo.astype(jnp.int32)
-        cnt_t = (hi - lo).astype(jnp.int32)
+        lo_t, cnt_t = dense_join_tables(skey, domain)
     return perm.astype(jnp.int32), skey, lo_t, cnt_t
+
+
+def _key_counts(key: jnp.ndarray, ok: jnp.ndarray, domain: int):
+    """(rows of each key in [0, domain), the slot of each row): one
+    scatter-add, no sort."""
+    slot = jnp.clip(key, 0, domain - 1)
+    ok = ok & (key >= 0) & (key < domain)
+    return (jnp.zeros((domain,), jnp.int32).at[slot].add(
+        ok.astype(jnp.int32)), slot, ok)
+
+
+def dense_join_tables(sorted_key: jnp.ndarray, domain: int):
+    """make_join_index's dense tables over a packed key domain: ``cnt``
+    the build rows of each key, ``lo`` where its run starts in the sorted
+    key (the rows of smaller keys; dead rows carry the sentinel, past
+    every key). A count and its running sum, where a ``searchsorted`` of
+    the whole domain co-sorts domain + build rows twice."""
+    cnt, _, _ = _key_counts(sorted_key, sorted_key != _pos_sentinel(
+        sorted_key.dtype), domain)
+    return jnp.cumsum(cnt) - cnt, cnt
+
+
+def join_counts(build_key, build_ok, probe_key, probe_ok,
+                domain: Optional[int]):
+    """What sizes an equi-join on packed keys: (its matches, the most
+    matches of one probe row, the most matches of one build row). Over a
+    dense key domain each side's rows are counted by key in a scatter-add
+    and looked up by the other side's keys: no sort, which the chip's
+    compiler takes tens of seconds over (ROADMAP A2). Else (hashed keys,
+    a domain past the tables') the sorted build of either side."""
+    if domain is not None and 0 < domain <= JOIN_TABLE_MAX:
+        bcnt, bslot, bok = _key_counts(build_key, build_ok, domain)
+        pcnt, pslot, pok = _key_counts(probe_key, probe_ok, domain)
+        counts = jnp.where(pok, bcnt[pslot], 0)
+        reverse = jnp.where(bok, pcnt[bslot], 0)
+    else:
+        counts = build_join_ranges(build_key, build_ok, probe_key,
+                                   probe_ok).counts
+        reverse = build_join_ranges(probe_key, probe_ok, build_key,
+                                    build_ok).counts
+    return (counts.astype(jnp.int64).sum(), counts.max(initial=0),
+            reverse.max(initial=0))
 
 
 def ranges_from_index(perm: jnp.ndarray, sorted_key: jnp.ndarray,
@@ -687,3 +727,45 @@ def bucket(n: int, multiple: int = 1024) -> int:
     if n <= 0:
         return multiple
     return ((n + multiple - 1) // multiple) * multiple
+
+
+#: significant bits of ``relative_bucket``: its step is at most an eighth
+RELATIVE_BUCKET_BITS = 3
+
+
+def relative_bucket(n: int, floor: int = 1024) -> int:
+    """``n`` rounded up to RELATIVE_BUCKET_BITS significant bits, and
+    never to a finer step than ``floor`` (below 16,384 it is
+    ``bucket(n)``): the capacity of a count the data moves, a join's
+    matches or the rows a scan keeps of some string values
+    (``io/datasource.py``). A relative step where ``bucket``'s is
+    absolute: a count that
+    another seed of the same data moves by a fraction of a percent lands
+    where the first one did, and the program compiled for it is found
+    again, at under an eighth of padding."""
+    n = max(1, int(n))
+    step = max(floor, 1 << max(0, n.bit_length() - 1 - RELATIVE_BUCKET_BITS))
+    return -(-n // step) * step
+
+
+def group_slots(groups: int, rows: int) -> int:
+    """Output slots of a sort-based aggregate of ``groups`` observed
+    groups over ``rows`` rows' capacity: ``bucket(groups, 256)``, or,
+    where the groups pass a quarter of the rows, ``rows``. No more groups
+    than rows can exist, and a count the data moves (the orders a join
+    keeps, by the seed) then changes no program, at most 4x of slots that
+    hold a fraction of the execution's work; an aggregate of few groups
+    over many rows (100,000 suppliers of 2.4 M lines) keeps its fine
+    bucket."""
+    return rows if groups * 4 > rows else bucket(max(1, groups), 256)
+
+
+def key_packing(lo: int, hi: int, quantum: int = 1024) -> Tuple[int, int]:
+    """(offset, range) that packs an integer join key observed in
+    ``[lo, hi]``: the offset rounded down and the range up to a multiple
+    of ``quantum``, so that a bound the data moves a little (one
+    customer key more or less) packs, and compiles, the same way."""
+    if lo > hi:
+        lo = hi = 0
+    offset = (lo // quantum) * quantum
+    return offset, bucket(hi - offset + 1, quantum)

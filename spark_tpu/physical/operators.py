@@ -20,7 +20,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1185,9 +1185,11 @@ class HashAggregateExec(PhysicalPlan):
 
     @property
     def sorted_slots(self) -> Optional[int]:
-        """Output slots of the traced sort-based path: all a stage keeps
-        of ``adaptive`` (the live groups are counted on the device), so
-        what the stage cache tells two bindings apart by."""
+        """``adaptive`` in 256-slot buckets: all a stage keeps of it (the
+        live groups are counted on the device), so what the stage cache
+        tells two bindings apart by. The slots traced are
+        ``K.group_slots`` of it and of the rows' capacity, which the
+        stage's scans and joins' stats, also in its key, decide."""
         return (None if self.adaptive is None
                 else K.bucket(max(1, self.adaptive), 256))
 
@@ -1365,12 +1367,14 @@ class HashAggregateExec(PhysicalPlan):
     def _trace_sorted(self, pipe: Pipe) -> Pipe:
         """Sort-based aggregation with STATIC output capacity from
         adaptive stats (the group count observed on the first, blocking
-        execution of these exact leaf arrays) — no host sync, fusable."""
+        execution of these exact leaf arrays) — no host sync, fusable;
+        ``K.group_slots`` of them."""
         key_tvs = [C.evaluate(g, pipe.env()) for g in self.groupings]
         spipe, sorted_keys, seg, ng = self._group_sort(pipe, key_tvs)
         # ng stays on device
         return self._group_sum(spipe, sorted_keys, seg, ng,
-                               self.sorted_slots, self.adaptive)
+                               K.group_slots(self.adaptive, pipe.capacity),
+                               self.adaptive)
 
     def execute_blocking(self, child_batches: List[Batch]) -> Batch:
         pipe = Pipe.from_batch_data(child_batches[0].schema,
@@ -1439,9 +1443,10 @@ def _pair_names(left_names, right_names) -> List[str]:
 
 
 #: Adaptive join statistics (the AQE analogue, reference:
-#: adaptive/AdaptiveSparkPlanExec.scala:247): first execution of a join
-#: runs the blocking path and records key-packing ranges + whether the
-#: build side matched each probe row at most once. Keyed on plan
+#: adaptive/AdaptiveSparkPlanExec.scala:247): the first execution of a
+#: join sizes it in compiled programs (planner._size_join) and records
+#: key-packing ranges + whether the build side matched each probe row at
+#: most once (a ``JoinStats``). Keyed on plan
 #: structure AND the identity of the leaf device arrays — jax arrays are
 #: immutable, so identical ids imply identical data, making the cached
 #: stats sound. With stats present, PK-FK joins become fully traceable
@@ -1542,6 +1547,20 @@ class _AdaptiveStatsCache:
 _JOIN_STATS = _AdaptiveStatsCache()
 
 
+class JoinStats(NamedTuple):
+    """What a join's first execution records and later ones bind
+    (``JoinExec.adaptive``): the key packing (``"hash"``, or per key its
+    (offset, range)), whether each side's keys match at most once, the
+    capacity of a sized expansion (None where membership alone is asked)
+    and whether the counts came from the compiled sizing programs."""
+
+    packing: Any
+    unique_build: bool
+    unique_probe: bool
+    cap: Optional[int]
+    sized: bool = False
+
+
 class _JoinIndexCache(_AdaptiveStatsCache):
     """Join-index cache bounded by pinned DEVICE BYTES, not entry count:
     a lineitem-scale index holds ~100 MB of HBM while a dimension-table
@@ -1594,9 +1613,11 @@ class JoinExec(PhysicalPlan):
     """Equi-join via sorted-build + searchsorted ranges (reference:
     ShuffledHashJoinExec.scala:38 / BroadcastHashJoinExec.scala:40 +
     HashedRelation.scala — rebuilt without hash tables, see
-    kernels.build_join_ranges). Blocking on first execution (output
-    capacity is the host-synced match count); unique-build inner/left/
-    semi/anti joins become traceable once _JOIN_STATS has their packing."""
+    kernels.build_join_ranges). An inner/left/semi/anti equi-join is
+    sized on its first execution in compiled programs (``JoinSizeExec``,
+    ``JoinIndexExec``: planner._size_join) and traceable from then on,
+    its packing, uniqueness and output capacity bound from _JOIN_STATS;
+    the others block on every execution (host-synced match count)."""
 
     left: PhysicalPlan
     right: PhysicalPlan
@@ -1640,6 +1661,28 @@ class JoinExec(PhysicalPlan):
         return (self.how in ("left_semi", "left_anti")
                 and self.condition is None and self.adaptive[0] != "hash")
 
+    @property
+    def wants_join_size(self) -> bool:
+        """Blocks for want of its sizes alone: a stage once the planner
+        has sized it (``planner._size_join``). Where stats are not
+        recorded (the chunked tiers' single-shot plans, whose packings
+        differ chunk by chunk) the join runs ``execute_blocking``."""
+        return (self.adaptive is None and bool(self.left_keys)
+                and self.how in ("inner", "left", "left_semi", "left_anti")
+                and not self.has_blocking_exprs() and stats_recording())
+
+    def output_capacity(self, lcap: int, rcap: int) -> int:
+        """Rows of the traced join's output over sides of these
+        capacities: what every operator above it runs at."""
+        st = self.adaptive
+        strat, _ = self._strategy(st[1], st[2], st[3], lcap, rcap)
+        if strat == "swap":
+            return rcap
+        if strat != "expand" or self.how in ("left_semi", "left_anti"):
+            return lcap
+        # a left join's expansion appends its unmatched left rows
+        return st[3] + (lcap if self.how == "left" else 0)
+
     def children(self):
         out = (self.left, self.right)
         if self.index_scan is not None:
@@ -1681,10 +1724,14 @@ class JoinExec(PhysicalPlan):
         perm + sorted key, searched; ``live``: sort + search inside the
         program), once per trace and never per execution."""
 
+        lcap, rcap = child_pipes[0].capacity, child_pipes[1].capacity
+
         def built(rung: str) -> None:
             _trace.built("join", rung=rung, how=self.how, orient=want,
                          build_rows=int(build_key.shape[0]),
-                         probe_cap=int(probe_key.shape[0]))
+                         probe_cap=int(probe_key.shape[0]),
+                         capacity=self.output_capacity(lcap, rcap),
+                         sized=bool(getattr(self.adaptive, "sized", False)))
 
         if self.index_scan is not None and len(child_pipes) > 2 \
                 and self.index_orient == want:
@@ -1808,12 +1855,46 @@ class JoinExec(PhysicalPlan):
 
     # -- traced path (adaptive, unique-build) ---------------------------------
 
-    def _traced_keys(self, lpipe: Pipe, rpipe: Pipe):
+    def key_is_string(self) -> List[bool]:
+        """Per key pair: packed by its unified dictionary (static), not
+        by an observed range."""
+        ls, rs = self.left.schema, self.right.schema
+        return [isinstance(lk.data_type(ls), T.StringType)
+                or isinstance(rk.data_type(rs), T.StringType)
+                for lk, rk in zip(self.left_keys, self.right_keys)]
+
+    def key_ranges(self, lpipe: Pipe, rpipe: Pipe) -> List[Tuple]:
+        """Per key pair, traced: the least and greatest key among the
+        live rows of either side whose key is valid — what its packing
+        is taken from (``planner._size_join``); a dictionary string key
+        reads (0, its unified dictionary's last code)."""
+        lenv, renv = lpipe.env(), rpipe.env()
+        out = []
+        for lk, rk in zip(self.left_keys, self.right_keys):
+            lt, rt = C.evaluate(lk, lenv), C.evaluate(rk, renv)
+            if isinstance(lt.dtype, T.StringType) \
+                    or isinstance(rt.dtype, T.StringType):
+                union, _ = C.unify_dictionaries(
+                    (lt.dictionary or (), rt.dictionary or ()))
+                out.append((jnp.int64(0), jnp.int64(max(1, len(union)) - 1)))
+                continue
+            lo, hi = jnp.iinfo(jnp.int64).max, jnp.iinfo(jnp.int64).min
+            for pipe, tv in ((lpipe, lt), (rpipe, rt)):
+                live = pipe.mask & tv.valid_or_true(pipe.capacity)
+                d = tv.data.astype(jnp.int64)
+                lo = jnp.minimum(lo, jnp.min(jnp.where(live, d, lo)))
+                hi = jnp.maximum(hi, jnp.max(jnp.where(live, d, hi)))
+            out.append((lo, hi))
+        return out
+
+    def _traced_keys(self, lpipe: Pipe, rpipe: Pipe, packing=None):
         """Key packing with STATIC per-key (mn, rg) from adaptive stats —
         no host syncs, so the join fuses into the surrounding program.
         Sound because the planner only binds stats recorded for these
         exact (immutable) leaf arrays. packing == 'hash' reproduces the
-        hash-combined fallback; callers must then verify pairs."""
+        hash-combined fallback; callers must then verify pairs.
+        ``packing`` is the bound stats' unless given (the sizing
+        programs run before any is bound)."""
         lenv, renv = lpipe.env(), rpipe.env()
         lks = [C.evaluate(k, lenv) for k in self.left_keys]
         rks = [C.evaluate(k, renv) for k in self.right_keys]
@@ -1821,7 +1902,8 @@ class JoinExec(PhysicalPlan):
         rcomb = jnp.zeros((rpipe.capacity,), dtype=jnp.int64)
         lvalid = jnp.ones((lpipe.capacity,), dtype=jnp.bool_)
         rvalid = jnp.ones((rpipe.capacity,), dtype=jnp.bool_)
-        packing = self.adaptive[0]
+        if packing is None:
+            packing = self.adaptive[0]
         hashed = packing == "hash"
         prepped = []
         for ki, (lt, rt) in enumerate(zip(lks, rks)):
@@ -1979,51 +2061,28 @@ class JoinExec(PhysicalPlan):
             pair_ok = pair_ok & ctv.data & ctv.valid_or_true(rpipe.capacity)
         return Pipe(cols, pair_ok, order)
 
-    def _record_index(self, sk, orient: str, build_key, build_ok,
-                      packing) -> None:
-        """Build and cache the reusable join index (perm + sorted key
-        [+ dense lo/cnt tables]) for these leaves. One-time device work
-        on the blocking run; later traces consume it as jit arguments
-        via aux BatchScanExec children.
+    def index_layout_stable(self, orient: str) -> bool:
+        """May the build side of ``orient`` keep a recorded index? The
+        index is POSITIONAL, so the build side's row layout must be the
+        same in the execution that recorded it and in every traced one
+        that replays it. Joins and adaptive aggregations emit different
+        layouts on their blocking vs traced paths (expansion order vs
+        gather order), so a build subtree containing one is not indexed
+        — the trace falls back to live build_join_ranges."""
 
-        The index is POSITIONAL, so the build side's row layout must be
-        identical between the blocking run that recorded it and the
-        traced run that replays it. Joins and adaptive aggregations emit
-        different layouts on their blocking vs traced paths (expansion
-        order vs gather order), so a build subtree containing one is
-        skipped — the trace falls back to live build_join_ranges."""
-        build_side = self.left if orient == "rev" else self.right
-
-        def layout_stable(p: PhysicalPlan) -> bool:
+        def stable(p: PhysicalPlan) -> bool:
             if isinstance(p, (JoinExec, HashAggregateExec)):
                 return False
-            return all(layout_stable(c) for c in p.children())
+            return all(stable(c) for c in p.children())
 
-        if not layout_stable(build_side):
-            return
-        domain = None
-        if packing != "hash":
-            domain = 1
-            for _, rg in packing:
-                domain *= rg
-        perm, skey, lo_t, cnt_t = K.make_join_index(
-            build_key, build_ok, domain)
-
-        def aux_batch(named):
-            fields = tuple(
-                Field(name, T.INT32 if a.dtype == jnp.int32 else T.INT64,
-                      nullable=False)
-                for name, a in named)
-            cols = tuple(ColumnData(a, None) for _, a in named)
-            mask = jnp.ones((named[0][1].shape[0],), dtype=jnp.bool_)
-            return Batch(Schema(fields), BatchData(cols, mask))
-
-        ib = aux_batch((("perm", perm), ("skey", skey)))
-        tb = (aux_batch((("lo", lo_t), ("cnt", cnt_t)))
-              if lo_t is not None else None)
-        _JOIN_INDEX.put(sk, (orient, ib, tb))
+        return stable(self.left if orient == "rev" else self.right)
 
     def execute_blocking(self, child_batches: List[Batch]) -> Batch:
+        """The join run operation by operation, its output sized by a
+        host sync: right and full outer joins, condition-only and cross
+        joins, and the single-shot plans of the chunked tiers (which
+        record no stats); every other join is sized in compiled
+        programs on its first execution and traced (``wants_join_size``)."""
         lpipe = Pipe.from_batch_data(child_batches[0].schema,
                                      child_batches[0].data)
         rpipe = Pipe.from_batch_data(child_batches[1].schema,
@@ -2046,60 +2105,15 @@ class JoinExec(PhysicalPlan):
         # matching streamed-side semantics)
         ranges = K.build_join_ranges(rkey, rpipe.mask & rvalid,
                                      lkey, lpipe.mask & lvalid)
-
-        adaptive_how = how in ("inner", "left", "left_semi", "left_anti")
-        sk = self.stats_key() if adaptive_how else None
-        record = adaptive_how and sk not in _JOIN_STATS
-
         if how in ("left_semi", "left_anti") and self.condition is None \
                 and not hashed:
-            if record:
-                maxc = int(jax.device_get(ranges.counts.max()))
-                _JOIN_STATS.put(sk, (packing, maxc <= 1, False, None))
-                self._record_index(sk, "fwd", rkey,
-                                   rpipe.mask & rvalid, packing)
             has_match = ranges.counts > 0
             keep = lpipe.mask & (has_match if how == "left_semi"
                                  else ~has_match)
             return Pipe(lpipe.cols, keep, lpipe.order).to_batch()
-
-        # host sync: output sizing (+ on the FIRST run, max matches per
-        # probe row AND per build row — either direction being unique
-        # makes this join traceable next execution, swapped roles for a
-        # unique probe). The BUCKETED capacity is recorded too: stats are
-        # keyed on the exact leaf arrays, so the match count is
-        # deterministic and re-executions can run the general expansion
-        # fully traced with a static capacity — no host sync, no
-        # blocking stage, even for many-to-many joins.
-        if record:
-            rev = K.build_join_ranges(lkey, lpipe.mask & lvalid,
-                                      rkey, rpipe.mask & rvalid)
-            total, maxc, maxb = (int(v) for v in jax.device_get(
-                (ranges.counts.sum(), ranges.counts.max(),
-                 rev.counts.max())))
-            cap = K.bucket(total)
-            # negative uniqueness results cached too; the capacity makes
-            # the sized-expansion trace available regardless
-            _JOIN_STATS.put(sk, (packing, maxc <= 1, maxb <= 1, cap))
-            # cache the build index for the orientation the NEXT traced
-            # execution will pick, so it skips the argsort + searchsorted
-            _, orient = self._strategy(maxc <= 1, maxb <= 1, cap,
-                                       lpipe.capacity, rpipe.capacity)
-            if orient == "rev":
-                self._record_index(sk, "rev", lkey,
-                                   lpipe.mask & lvalid, packing)
-            else:
-                self._record_index(sk, "fwd", rkey,
-                                   rpipe.mask & rvalid, packing)
-        else:
-            st = _JOIN_STATS.get(sk) if sk is not None else None
-            if st is not None and len(st) > 3 and st[3] is not None:
-                cap = st[3]  # deterministic for these leaves: no sync
-            else:
-                total = int(ranges.counts.sum())  # host sync: sizing
-                cap = K.bucket(total)
+        total = int(ranges.counts.sum())  # host sync: output sizing
         return self._pairs_pipe(lpipe, rpipe, ranges, hashed, prepped,
-                                cap).to_batch()
+                                K.relative_bucket(total)).to_batch()
 
     def _pairs_pipe(self, lpipe: Pipe, rpipe: Pipe, ranges, hashed,
                     prepped, cap: int) -> Pipe:
@@ -2310,6 +2324,107 @@ class JoinExec(PhysicalPlan):
                 tuple(E.expr_key(k) for k in self.right_keys),
                 None if self.condition is None else E.expr_key(self.condition),
                 self.left.plan_key(), self.right.plan_key())
+
+
+def packed_domain(packing) -> Optional[int]:
+    """Keys a join's packing can give (the product of its ranges); None
+    for hashed keys."""
+    if packing == "hash":
+        return None
+    domain = 1
+    for _, rg in packing:
+        domain *= rg
+    return domain
+
+
+@dataclass(eq=False)
+class _JoinSizing(PhysicalPlan):
+    """A program over a join's two sides that sizes the join; run as a
+    stage of its own on the join's first execution (planner._size_join)
+    and never bound into a query's plan. Its children are the join's."""
+
+    join: JoinExec
+    packing: Any = None
+    traceable = True
+
+    def children(self):
+        return (self.join.left, self.join.right)
+
+    def map_children(self, fn) -> "PhysicalPlan":
+        join = self.join.map_children(fn)
+        return self if join is self.join else dataclasses.replace(
+            self, join=join)
+
+    @property
+    def schema(self) -> Schema:
+        raise NotImplementedError("a sizing program's schema is its trace's")
+
+    def _keys(self, child_pipes: List[Pipe]):
+        """(build key, build ok, probe key, probe ok, domain) packed by
+        ``packing``: the right side is built, the left probes."""
+        lpipe, rpipe = child_pipes
+        lkey, lvalid, rkey, rvalid, _, _ = self.join._traced_keys(
+            lpipe, rpipe, self.packing)
+        return (rkey, rpipe.mask & rvalid, lkey, lpipe.mask & lvalid,
+                packed_domain(self.packing))
+
+    def plan_key(self):
+        return (type(self).__name__, self.packing, self.join.plan_key())
+
+
+def _one_row(cols: Dict[str, Any]) -> Pipe:
+    named = {n: TV(jnp.asarray(v, jnp.int64)[None], None, T.INT64, None)
+             for n, v in cols.items()}
+    return Pipe(named, jnp.ones((1,), dtype=jnp.bool_), list(named))
+
+
+@dataclass(eq=False)
+class JoinSizeExec(_JoinSizing):
+    """One row that sizes ``join``. Without a packing, each key's range
+    (``lo<i>``, ``hi<i>``: JoinExec.key_ranges), which the host packs the
+    keys by; with one, the join's ``matches``, the most matches of one
+    probe row (``probe_max``) and of one build row (``build_max``):
+    K.join_counts, two scatter-adds over a dense key domain."""
+
+    def trace(self, child_pipes: List[Pipe]) -> Pipe:
+        if self.packing is None:
+            cols = {}
+            for i, (lo, hi) in enumerate(self.join.key_ranges(*child_pipes)):
+                cols[f"lo{i}"], cols[f"hi{i}"] = lo, hi
+            return _one_row(cols)
+        matches, probe_max, build_max = K.join_counts(
+            *self._keys(child_pipes))
+        return _one_row({"matches": matches, "probe_max": probe_max,
+                         "build_max": build_max})
+
+    def node_string(self):
+        return f"JoinSize[{self.join.node_string()}]"
+
+
+@dataclass(eq=False)
+class JoinIndexExec(_JoinSizing):
+    """The reusable part of ``join``'s sorted build (K.make_join_index)
+    over the side ``orient`` builds ('fwd': the right, 'rev': the left):
+    its sort permutation ``perm`` and sorted packed key ``skey``, at the
+    build side's capacity. The dense tables over the key domain are
+    K.dense_join_tables of ``skey``."""
+
+    orient: str = "fwd"
+
+    def trace(self, child_pipes: List[Pipe]) -> Pipe:
+        rkey, rok, lkey, lok, _ = self._keys(child_pipes)
+        bkey, bok = (lkey, lok) if self.orient == "rev" else (rkey, rok)
+        perm, skey, _, _ = K.make_join_index(bkey, bok, None)
+        return Pipe({"perm": TV(perm, None, T.INT32, None),
+                     "skey": TV(skey, None, T.INT64, None)},
+                    jnp.ones((perm.shape[0],), dtype=jnp.bool_),
+                    ["perm", "skey"])
+
+    def node_string(self):
+        return f"JoinIndex[{self.orient}, {self.join.node_string()}]"
+
+    def plan_key(self):
+        return super().plan_key() + (self.orient,)
 
 
 def append_unmatched_left(cols, pair_ok, order, lpipe, matched):

@@ -20,16 +20,20 @@ from __future__ import annotations
 import dataclasses
 import operator
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from spark_tpu import conf as CF
 from spark_tpu import trace
-from spark_tpu.columnar.batch import Batch
+from spark_tpu import types as T
+from spark_tpu.columnar.batch import Batch, BatchData, ColumnData
 from spark_tpu.expr import expressions as E
 from spark_tpu.physical import kernels as K
 from spark_tpu.physical import operators as P
 from spark_tpu.physical import stage
 from spark_tpu.plan import logical as L
+from spark_tpu.types import Field, Schema
 
 
 def plan_physical(plan: L.LogicalPlan) -> P.PhysicalPlan:
@@ -294,9 +298,24 @@ def _bind(plan: P.PhysicalPlan):
     return plan, sk, _OUTPUT_STATS.get(sk)
 
 
-def _run_bound(plan: P.PhysicalPlan, sk, cap) -> Batch:
+def _run_bound(physical: P.PhysicalPlan, plan: P.PhysicalPlan, sk,
+               cap) -> Batch:
     """Run a bound physical plan: fuse what we can, block where we
-    must."""
+    must. ``physical`` is the plan before ``_bind``: a first execution
+    whose blocking operators all block for want of their stats alone
+    records them (``_size``), binds the plan again, and runs it as the
+    one stage every later execution runs, where the plan's operators
+    bound its output within one capacity bucket (``_rows_bound``)."""
+    if cap is None and P.stats_recording() \
+            and not stage.fully_traceable(plan, P.BatchScanExec) \
+            and _becomes_traceable(plan):
+        _size(plan)
+        plan, sk, cap = _bind(physical)
+        rows = _rows_bound(plan)
+        if cap is None and rows is not None and rows <= _capacity_bucket():
+            # what the first execution would record for any live count
+            cap = _capacity_bucket()
+            _OUTPUT_STATS.put(sk, cap)
     if cap is not None:
         return _execute(P.CompactExec(plan, cap))
     batch = _execute(plan)
@@ -304,6 +323,140 @@ def _run_bound(plan: P.PhysicalPlan, sk, cap) -> Batch:
         live = int(np.asarray(batch.data.row_mask).sum())  # 1st run only
         _OUTPUT_STATS.put(sk, K.bucket(live, _capacity_bucket()))
     return batch
+
+
+def _wants_stats(plan: P.PhysicalPlan) -> bool:
+    """Blocks for want of runtime stats alone: traceable once sized."""
+    return ((isinstance(plan, P.JoinExec) and plan.wants_join_size)
+            or (isinstance(plan, P.HashAggregateExec)
+                and plan.wants_group_count))
+
+
+def _becomes_traceable(plan: P.PhysicalPlan) -> bool:
+    """Would ``plan`` be one stage once every operator that blocks for
+    want of its stats has them?"""
+    if isinstance(plan, P.BatchScanExec):
+        return True
+    return ((plan.traceable or _wants_stats(plan))
+            and not plan.has_blocking_exprs()
+            and all(_becomes_traceable(c) for c in plan.children()))
+
+
+def _rows_bound(plan: P.PhysicalPlan):
+    """The most live rows ``plan`` can return, where its operators alone
+    say so (a LIMIT, an aggregate without keys); None elsewhere."""
+    if isinstance(plan, P.LimitExec):
+        inner = _rows_bound(plan.child)
+        return plan.n if inner is None else min(plan.n, inner)
+    if isinstance(plan, P.HashAggregateExec) and not plan.groupings:
+        return 1
+    if isinstance(plan, (P.ProjectExec, P.FilterExec, P.SortExec,
+                         P.CompactExec)):
+        return _rows_bound(plan.child)
+    return None
+
+
+def _size(plan: P.PhysicalPlan) -> None:
+    """Record the runtime stats of every operator under ``plan`` that
+    blocks for want of them, bottom up (``_size_join``,
+    ``_sized_aggregate``). The stages that compute a sized operator's
+    inputs run; the sized operator itself does not: the plan it is bound
+    into runs next, whole."""
+    if stage.fully_traceable(plan, P.BatchScanExec):
+        return
+    if not _wants_stats(plan):
+        for c in plan.children():
+            _size(c)
+        return
+    _sized(plan, [_maybe_compact(_execute(c), c) for c in plan.children()])
+
+
+def _sized(plan: P.PhysicalPlan, batches) -> P.PhysicalPlan:
+    """``plan`` (``_wants_stats``) sized over its children's batches:
+    its stats recorded, and it bound to them over scans of the batches."""
+    if isinstance(plan, P.JoinExec):
+        return _size_join(plan, batches)
+    return _sized_aggregate(plan, batches[0])
+
+
+def _host_row(plan: P.PhysicalPlan) -> dict:
+    """Run a one-row stage; its columns as Python ints (one host sync)."""
+    batch = _execute(plan)
+    values = jax.device_get([c.data for c in batch.data.columns])
+    return {f.name: int(v[0]) for f, v in zip(batch.schema.fields, values)}
+
+
+def _packing(join: P.JoinExec, ranges: dict):
+    """The key packing from the observed ranges: a dictionary string key
+    by its codes, an integer key by ``K.key_packing``; ``"hash"`` where
+    the packed domain would pass 62 bits."""
+    packing, total = [], 1
+    for i, string in enumerate(join.key_is_string()):
+        lo, hi = ranges[f"lo{i}"], ranges[f"hi{i}"]
+        mn, rg = (0, hi + 1) if string else K.key_packing(lo, hi)
+        total *= rg
+        if total > (1 << 62):
+            return "hash"
+        packing.append((mn, rg))
+    return tuple(packing)
+
+
+def _aux_batch(named) -> Batch:
+    fields = tuple(Field(name, T.INT32 if a.dtype == jnp.int32 else T.INT64,
+                         nullable=False) for name, a in named)
+    mask = jnp.ones((named[0][1].shape[0],), dtype=jnp.bool_)
+    return Batch(Schema(fields), BatchData(
+        tuple(ColumnData(a, None) for _, a in named), mask))
+
+
+_DENSE_JOIN_TABLES = jax.jit(K.dense_join_tables, static_argnums=1)
+
+
+def _size_join(plan: P.JoinExec, batches) -> P.JoinExec:
+    """The first execution of an equi-join, sized in compiled programs
+    where it ran operation by operation, some eighty programs each XLA
+    sort among which the chip's compiler takes tens of seconds over
+    (ROADMAP A2): the keys' ranges (a host sync packs them), the matches
+    (the host sync that sizes the output and finds a unique side), the
+    build side's index. Records the stats and the index for re-executions
+    of these leaves (``_bind_adaptive``) and hands back the join bound to
+    them over its children's batches: a traceable stage."""
+    sides = dataclasses.replace(plan, left=P.BatchScanExec(batches[0]),
+                                right=P.BatchScanExec(batches[1]))
+    sk = plan.stats_key()
+    span = trace.span("join.size", how=plan.how)
+    with span:
+        packing = _packing(plan, _host_row(P.JoinSizeExec(sides)))
+        counted = _host_row(P.JoinSizeExec(sides, packing))
+        unique_build = counted["probe_max"] <= 1
+        if plan.how in ("left_semi", "left_anti") \
+                and plan.condition is None and packing != "hash":
+            stats = P.JoinStats(packing, unique_build, False, None, True)
+        else:
+            stats = P.JoinStats(packing, unique_build,
+                                counted["build_max"] <= 1,
+                                K.relative_bucket(counted["matches"]), True)
+        sides.adaptive = stats
+        lcap, rcap = batches[0].capacity, batches[1].capacity
+        _, orient = sides._strategy(stats.unique_build, stats.unique_probe,
+                                    stats.cap, lcap, rcap)
+        span.attrs.update(rows=counted["matches"],
+                          capacity=sides.output_capacity(lcap, rcap))
+        P._JOIN_STATS.put(sk, stats)
+        if plan.index_layout_stable(orient):
+            ib = _execute(P.JoinIndexExec(sides, packing, orient))
+            tb = None
+            domain = P.packed_domain(packing)
+            if domain is not None and domain <= K.JOIN_TABLE_MAX:
+                lo, cnt = _DENSE_JOIN_TABLES(ib.data.columns[1].data,
+                                             domain)
+                tb = _aux_batch((("lo", lo), ("cnt", cnt)))
+            P._JOIN_INDEX.put(sk, (orient, ib, tb))
+            sides.index_scan = P.BatchScanExec(ib, aux=True)
+            sides.table_scan = (None if tb is None
+                                else P.BatchScanExec(tb, aux=True))
+            sides.index_orient = orient
+    return sides
 
 
 def _sized_aggregate(plan: P.HashAggregateExec,
@@ -333,8 +486,8 @@ def _execute(plan: P.PhysicalPlan) -> Batch:
     for c in plan.children():
         b = _execute(c)
         child_batches.append(_maybe_compact(b, c))
-    if isinstance(plan, P.HashAggregateExec) and plan.wants_group_count:
-        return _execute(_sized_aggregate(plan, child_batches[0]))
+    if _wants_stats(plan):
+        return _execute(_sized(plan, child_batches))
     with trace.span("stage.run", op=type(plan).__name__), \
             metrics.stage_timer("blocking", node=plan.node_string(),
                                 cap_in=[b.capacity
@@ -351,8 +504,9 @@ def execute_logical(plan: L.LogicalPlan, optimize: bool = True) -> Batch:
     # one span from the logical plan to the bound physical one; it
     # closes before the first stage runs
     with trace.span("query.plan"):
-        bound = _bind(plan_physical(plan))
-    return _run_bound(*bound)
+        physical = plan_physical(plan)
+        bound = _bind(physical)
+    return _run_bound(physical, *bound)
 
 
 def execute_logical_on(session, plan: L.LogicalPlan,

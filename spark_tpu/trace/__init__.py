@@ -116,6 +116,9 @@ SPAN_NAMES = frozenset({
                             # before the engine runs (recovery.py)
     "admission.note",       # the query's peak stage_bytes, read back from
                             # the ring for the scheduler's admission table
+    "join.size",            # a join's first execution: its compiled sizing
+                            # programs (key ranges, matches, index);
+                            # how, rows (matches), capacity (of its output)
     "join.partition",       # hybrid hash join: grant + partition pass
     "join.spill",           # hybrid hash join: one spill write/read
     "slo.admit",            # SLO feasibility check at submit time

@@ -4,10 +4,9 @@ runs it, at SF0.03: parquet from benchmark/tpch_gen.py, the SQL text and the
 expected row from benchmark/queries/q15_revenue.sql / .py (the files the
 chip uses, loaded by path). The first execution counts the groups in a stage
 of its own (a host sync sizes the output, ``_AGG_STATS`` records the count)
-and runs the aggregate sized by it as another; the second traces the whole
-query's stage with that count as a static capacity; from the third on
-nothing is built. A second seed moves values and no shape, so it builds no
-program. The two halves run under scopes of their own inside the
+and traces the whole query's stage with that count as a static capacity:
+the stage every later execution runs, so from the second on nothing is
+built. A second seed moves values and no shape, so it builds no program. The two halves run under scopes of their own inside the
 operator's, and only there: Q1's direct path keeps
 ``spark.HashAggregateExec`` innermost (``agg_roofline_pct`` reads it)."""
 
@@ -135,9 +134,9 @@ def _of(events, kind):
 
 @pytest.mark.parametrize("execution", [0, 1])
 def test_the_aggregate_is_built_sorted(runs, execution):
-    """The first execution counts the groups in one stage and runs the
-    aggregate sized by the count as another; the second traces the whole
-    query's stage. Each aggregate stage builds ONE XLA sort of its rows
+    """The first execution counts the groups in one stage and traces the
+    whole query's stage sized by the count; the second runs it and builds
+    nothing. Each aggregate stage builds ONE XLA sort of its rows
     (keys ``~mask`` and the supplier key; the columns ride it, none is
     gathered: PR 38), one int64 ``seg_sum`` on the cumsum rung (past the
     masked rung's 64 slots, over the sorted ids), and a ``group_by``
@@ -145,7 +144,10 @@ def test_the_aggregate_is_built_sorted(runs, execution):
     program's shape."""
     _rows_got, events, _n = runs[SEEDS[0]]["executions"][execution]
     stages = [e["node"].split("[")[0] for e in _of(events, "stage_compile")]
-    assert stages == [["GroupCount", "HashAggregate"], ["Compact"]][execution]
+    assert stages == [["GroupCount", "Compact"], []][execution]
+    if execution:
+        assert [e for e in events if e["kind"] in trace.BUILD_EVENTS] == []
+        return
     sorts = _of(events, "sort")
     assert [(e["site"], e["keys"], e["gathered"]) for e in sorts] == [
         ("group_rows", 2, 0)] * len(stages)
@@ -186,13 +188,13 @@ def test_a_second_seed_builds_no_new_program(runs):
     again, through the first seed's stage, and finds the same count; the
     stages it then needs are the first seed's too."""
     first, second = runs[SEEDS[0]], runs[SEEDS[1]]
-    assert len(first["captured"]) == 3 and second["captured"] == []
+    assert len(first["captured"]) == 2 and second["captured"] == []
     assert second["stage_cache"] == first["stage_cache"]
     events = [e for _r, ev, _n in second["executions"] for e in ev]
     assert [e["kind"] for e in events
             if e["kind"] in ("stage_compile", "sort", "seg_sum")] == []
-    # the outer max of the first execution runs eagerly
-    assert [e["strategy"] for e in _of(events, "group_by")] == ["direct"]
+    # nothing runs operation by operation, not the outer max either
+    assert _of(events, "group_by") == []
     assert all(n == 0 for _r, _ev, n in second["executions"][1:])
 
 

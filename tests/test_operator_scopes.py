@@ -52,8 +52,9 @@ def _captured_stages():
 @pytest.fixture(scope="module")
 def stages(spark):
     """The fused stages of TPC-H Q1 (one execution) and Q14 (three: the
-    join blocks the first time and is traced the second), their rows, and
-    the ``join`` events of each Q14 execution."""
+    first sizes the join in stages of its own and traces it, the others
+    run what it built), their rows, and the ``join`` events of each Q14
+    execution."""
     from spark_tpu.tpch.gen import generate_tables, register_views
     from spark_tpu.tpch.queries import QUERIES
 
@@ -164,8 +165,7 @@ def test_results_are_byte_identical_under_a_profiler_session(
     finally:
         jax.profiler.stop_trace()
     for n in (1, 14):
-        # against the same compiled stage without a session (Q14's eager
-        # blocking run rounds its one float division an ulp apart)
+        # against the same compiled stage without a session
         plain = rows[n][-1]
         assert [tuple(r.asDict().items()) for r in traced[n]] == [
             tuple(r.asDict().items()) for r in plain]
@@ -200,14 +200,17 @@ def test_sort_events_are_recorded_when_a_sort_is_built():
 
 
 def test_join_event_names_the_rung_of_the_traced_join(stages):
-    """Q14's first execution blocks and records none; the second traces
-    its join through the cached index (a dense table at this size): one
-    ``join`` event, ``live`` never; the third runs the compiled stage."""
+    """Q14's first execution sizes its join in compiled programs and
+    traces it through the index they recorded (a dense table at this
+    size): one ``join`` event, ``live`` never, sized by the compiled
+    count and at the probe's capacity (the build side is unique); the
+    second and third run the compiled stage."""
     first, second, third = stages[2]
-    assert first == [] and third == []
-    assert [(e["rung"], e["how"], e["orient"]) for e in second] == [
-        ("table", "inner", "fwd")]
-    assert second[0]["build_rows"] > 0 and second[0]["probe_cap"] > 0
+    assert second == [] and third == []
+    assert [(e["rung"], e["how"], e["orient"], e["sized"])
+            for e in first] == [("table", "inner", "fwd", True)]
+    assert first[0]["build_rows"] > 0 and first[0]["probe_cap"] > 0
+    assert first[0]["capacity"] == first[0]["probe_cap"]
 
 
 def test_build_events_are_registered_and_linted():

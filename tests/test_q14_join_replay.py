@@ -1,10 +1,12 @@
 """TPC-H Q14 through the single-device engine as the benchmark's cell
 ``tpch_sf1_q14`` runs it, at SF0.01: parquet from benchmark/tpch_gen.py, the
 SQL text and the expected row from benchmark/queries/q14.sql / q14.py (the
-files the chip uses, loaded by path). The first execution is the blocking
-run (host syncs size the join, ``_JOIN_STATS`` / ``_JOIN_INDEX`` are
-recorded); the second traces the replay through the cached index; from the
-third on nothing is built. A second seed moves values and no shape."""
+files the chip uses, loaded by path). The first execution sizes the join
+in compiled programs (``join.size``: the keys' ranges, the matches, the
+build side's index; ``_JOIN_STATS`` / ``_JOIN_INDEX`` are recorded), and
+runs no join operation by operation: it traces the replay through the
+cached index, the stage every later execution runs; from the second on
+nothing is built. A second seed moves values and no shape."""
 
 import importlib.util
 import os
@@ -82,30 +84,40 @@ def test_q14_blocks_once_then_replays_the_join_through_its_index(
     def kinds(i, kind):
         return [e for e in runs[i][0] if e["kind"] == kind]
 
-    # the first execution is the blocking run
-    assert any(e.get("op") == "blocking" for e in kinds(0, "stage"))
-    assert not kinds(0, "join")
+    # the first execution sizes the join in compiled stages of its own and
+    # runs none operation by operation
+    assert not any(e.get("op") == "blocking" for e in kinds(0, "stage"))
+    (sized,) = [e for e in kinds(0, "span") if e["name"] == "join.size"]
+    assert sized["how"] == "inner" and sized["rows"] > 0
+    assert sized["capacity"] >= sized["rows"]
+    assert [e["node"].split("[")[0] for e in kinds(0, "stage_compile")
+            if e["node"].startswith("Join")] in ([], [
+                "JoinSize", "JoinSize", "JoinIndex"])
     # the traced program's join was built from the cached index; a seed
     # whose shapes the process has seen finds the stage in the stage
     # cache and traces nothing at all
     joins = [e for i in range(EXECUTIONS) for e in kinds(i, "join")]
-    assert joins or not kinds(1, "stage_compile")
+    assert joins == kinds(0, "join")
+    assert joins or not kinds(0, "stage_compile")
     assert all(e["rung"] in ("index", "table") and e["how"] == "inner"
                and e["build_rows"] >= 2000 and e["probe_cap"] > 0
+               and e["capacity"] == e["probe_cap"] and e["sized"]
                for e in joins)
-    # sorts are built in the first two executions only, and from the
-    # third on nothing is compiled or looked up
-    assert kinds(0, "sort")
-    for i in range(2, EXECUTIONS):
+    # sorts are built in the first execution only, and from the second
+    # on nothing is compiled or looked up
+    assert kinds(0, "sort") or not kinds(0, "stage_compile")
+    for i in range(1, EXECUTIONS):
         events, lookups = runs[i]
         assert lookups == 0, i
         assert not [e for e in events if e["kind"] in (
             "stage_compile", "sort", "join", "seg_sum")], i
+        assert not [e for e in events if e.get("name") == "join.size"], i
         assert any(e.get("op") == "fused" for e in kinds(i, "stage"))
     _SORTS[seed] = [(e["site"], e["rows"], e["dtype"])
                     for i in range(EXECUTIONS) for e in kinds(i, "sort")]
-    # a second seed moves values, never a shape or a sort site
+    # a second seed moves values, never a shape: its sizing programs and
+    # its stage are the first seed's, found in the stage cache
     if len(_SORTS) == len(SEEDS):
-        assert _SORTS[SEEDS[0]] == _SORTS[SEEDS[1]]
-        assert {site for site, _r, _d in _SORTS[seed]} <= {
+        assert _SORTS[SEEDS[1]] == []
+        assert {site for site, _r, _d in _SORTS[SEEDS[0]]} <= {
             "join_index", "searchsorted"}

@@ -138,7 +138,8 @@ def smoke_reference(tmp_path_factory):
     return load_sqlite(tables), path
 
 
-@pytest.mark.parametrize("qnum", [1, 3, 5, 6, "15_revenue", "15_revenue0"])
+@pytest.mark.parametrize("qnum", [1, 3, 5, 6, "15_revenue", "15_revenue0",
+                                  "3_groups"])
 def test_chip_smoke_reference_matches_oracle(smoke_reference, qnum):
     """chip_smoke.py checks the chip's SF1 answers against a pandas
     recompute (the sqlite oracle cannot load SF1 inside a smoke run's

@@ -225,8 +225,12 @@ def test_q15_sorted_aggregate_sums_int64_with_no_scatter_add_on_v5e(
     (PERF.md, PR 36) — and sums nothing into a slot by scatter. The
     scatters that remain SET u32 ranks: the permutation inverses of
     ``seg_bounds``' co-sorted ``searchsorted``, one pair for the sum and
-    the COUNT beside it (the compiler merges their searches)."""
-    from spark_tpu.physical import kernels as K, operators as P
+    the COUNT beside it (the compiler merges their searches). The slot-
+    sized arrays are ``sorted_slots`` of 100,000 groups, 100,096, and
+    none is 131,072 wide: slots rounded up to a power of two moved the
+    segments' bound searches from ``[100096]`` to ``[131072]`` and cost
+    the cell 6.1 % of ``query_ms`` (ledger; PERF.md section 6)."""
+    from spark_tpu.physical import operators as P
 
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "queries")
@@ -245,7 +249,9 @@ def test_q15_sorted_aggregate_sums_int64_with_no_scatter_add_on_v5e(
 
     _, text = _stage_at(Q15_ROWS, 0.0033, one_chip, spark, monkeypatch, run,
                         parquet_dir=tmp_path)
-    assert f"[{K.bucket(Q15_GROUPS, 256)}]" in text
+    slots = P.HashAggregateExec((), (), None, Q15_GROUPS).sorted_slots
+    assert slots == 100_096 and f"[{slots}]" in text
+    assert "[131072]" not in text
     floats = re.findall(r"\bf(?:16|32|64)\[\d*\]", text)
     assert not floats, sorted(set(floats))
     combiners = _scatter_combiners(text)
