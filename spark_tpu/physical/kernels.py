@@ -12,7 +12,8 @@ the same operations as dense, static-shape XLA programs:
                  no sort, no sync) or from sort + change-flag cumsum
 - hash-join   -> sort the build side once, then two `searchsorted`s give
                  every probe row its contiguous match range; expansion to
-                 match pairs is a vectorized gather (no pointers, no probing)
+                 match pairs is a scatter of the ranges' starts and a
+                 running max, then gathers a pair (no pointers, no probing)
 
 Everything is mask-carrying: dead rows ride along and are neutralized per
 reduction, which keeps shapes static under jit.
@@ -610,22 +611,55 @@ def ranges_from_index(perm: jnp.ndarray, sorted_key: jnp.ndarray,
     return JoinRanges(perm, lo, hi)
 
 
+#: a join's expansion searches, instead of scattering every probe row,
+#: from this many probe rows a pair: on the v5e the scatter cost ~7.6 ms
+#: at 1,572,864 probe rows whatever the pairs, a binary search ~0.19 us
+#: a pair, and the two met near 34 (tools/probe_expand.py --crossover)
+EXPAND_SEARCH_RATIO = 32
+
+
 def expand_join_pairs(ranges: JoinRanges, total: int) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Materialize (probe_idx, build_idx, pair_mask) for all match pairs.
     ``total`` is the static output capacity (host-synced count, bucketed).
     Pair j belongs to the probe row p whose exclusive-offset range covers
-    j; its build index is the j-offsets[p]'th sorted match."""
-    counts = ranges.counts
+    j; its build index is the j-offsets[p]'th sorted match, i.e.
+    ``build_perm[base[p] + j]`` with ``base = lo - offsets`` constant on
+    p's run of pairs. Every capacity is below 2^31, so the offsets and
+    positions are int32 (the sum of the counts too: a capacity is sized
+    from it).
+
+    ``starts`` (where the probe rows are fewer than ``EXPAND_SEARCH_RATIO``
+    times the pairs): every row with a match sets its own index at its
+    run's start (rows without one share the next run's start and are
+    sent past the capacity instead, so the indices stay unique) and a
+    running max carries it over the run — one scatter of probe-length
+    rows into pair length, no sort. (Two scatter-adds of the rows' steps,
+    one for p and one for ``base``, crash the v5e compiler once a sorted
+    aggregate follows the join at TPC-H Q3's SF10 shapes.) ``search``
+    (few pairs out of many probe rows): a binary search of every pair,
+    log2(probe rows) rounds of gathers over the pairs. Either way
+    ``base`` is read a pair."""
+    n = ranges.lo.shape[0]
+    lo = ranges.lo.astype(jnp.int32)
+    counts = ranges.hi.astype(jnp.int32) - lo
     offsets = jnp.cumsum(counts) - counts  # exclusive prefix
-    grand_total = offsets[-1] + counts[-1]
-    j = jnp.arange(total)
-    p = searchsorted(offsets, j, side="right") - 1
-    p = jnp.clip(p, 0, counts.shape[0] - 1)
-    k = j - offsets[p]
-    build_sorted_pos = ranges.lo[p] + k
-    build_idx = ranges.build_perm[jnp.clip(build_sorted_pos, 0,
-                                           ranges.build_perm.shape[0] - 1)]
-    pair_mask = j < grand_total
+    j = jnp.arange(total, dtype=jnp.int32)
+    method = "starts" if total * EXPAND_SEARCH_RATIO > n else "search"
+    trace.built("expand", method=method, probe_rows=int(n),
+                pairs=int(total))
+    if method == "starts":
+        q = jnp.arange(n, dtype=jnp.int32 if total + n < 2**31 else jnp.int64)
+        # past the capacity (mode="drop"): rows without a match, and the
+        # starts of runs beyond it
+        at = jnp.where(counts > 0, offsets, total + q)
+        p = jax.lax.cummax(jnp.zeros(total, q.dtype).at[at].set(
+            q, mode="drop", unique_indices=True)).astype(jnp.int32)
+    else:
+        p = jnp.clip(jnp.searchsorted(offsets, j, side="right",
+                                      method="scan") - 1, 0, n - 1)
+    build_idx = ranges.build_perm[jnp.clip(
+        (lo - offsets)[p] + j, 0, ranges.build_perm.shape[0] - 1)]
+    pair_mask = j < counts.sum(dtype=jnp.int32)
     return p, build_idx, pair_mask
 
 

@@ -1954,8 +1954,8 @@ class JoinExec(PhysicalPlan):
         # (Profiled: q3's swapped join emitted at lineitem's 3.05M
         # capacity and the group-by sort-aggregated 3M rows for a
         # 32k-pair join — 1.2 s of gathers/sorts for a ~250 ms query.)
-        # Expansion pays an extra offsets-searchsorted + pair mask,
-        # so it must be ~2x smaller to win.
+        # Expansion pays the pairs' run-start scatter and running max
+        # + pair mask, so it must be ~2x smaller to win.
         strat, _ = self._strategy(unique_build, unique_probe, sized_cap,
                                   lpipe.capacity, rpipe.capacity)
         if strat == "swap":

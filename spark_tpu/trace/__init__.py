@@ -147,6 +147,8 @@ BUILD_EVENTS = frozenset({
                  # (direct/sorted), keys (their dtypes as they travel),
                  # rows (capacity), k (num_segments), groups (the
                  # _AGG_STATS count k was sized from; None when direct)
+    "expand",    # kernels.expand_join_pairs: one per match expansion
+                 # built; method (starts/search), probe_rows, pairs
 })
 
 #: prefix of the ``jax.named_scope`` round each operator's ``trace()``

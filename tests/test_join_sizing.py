@@ -77,7 +77,7 @@ def runs(spark, tmp_path_factory):
         conn = oracle.load_sqlite({t: pq.read_table(
             os.path.join(path, f"{t}.parquet"))
             for t in ("customer", "orders", "lineitem")})
-        out[seed] = {"executions": executions,
+        out[seed] = {"executions": executions, "path": path,
                      "oracle": oracle.run_oracle(conn, text),
                      "groups": sorted(_rows(spark.sql(groups))),
                      "oracle_groups": sorted(oracle.run_oracle(conn, groups))}
@@ -160,6 +160,50 @@ def test_a_second_seed_sizes_again_through_the_same_programs(runs):
     _rows_got, events, _n = runs[SEEDS[1]]["executions"][0]
     assert len(_of(events, "span", "join.size")) == 2
     assert _of(events, "stage_compile") == []
+
+
+def test_both_joins_expand_their_pairs_by_run_starts(runs):
+    """Each join's expansion is built from the scatter of its matched
+    probe rows at their run starts and a running max
+    (``kernels.expand_join_pairs``), in the join's own stage and again
+    in the whole query's: at the cell's SF10
+    the first join's 327,680 customers make 1,572,864 pairs, the second's
+    1,572,864 rows 327,680, and neither is few against its probe rows."""
+    _rows_got, events, _n = runs[SEEDS[0]]["executions"][0]
+    built = [(e["method"], e["probe_rows"], e["pairs"])
+             for e in _of(events, "expand")]
+    joins = [(e["probe_cap"], e["capacity"]) for e in _of(events, "join")]
+    assert len(built) == 4 and [b[1:] for b in built] == joins
+    assert {b[0] for b in built} == {"starts"}
+
+
+def test_few_pairs_out_of_many_probe_rows_expand_by_search(runs, spark):
+    """A selective join on the orders' key: the lineitem scan probes, a
+    few dozen orders match, and the pairs are fewer than a 32nd of the
+    probe rows, so a binary search a pair costs less than a scatter of
+    every probe row."""
+    import pyarrow.parquet as pq
+
+    path = runs[SEEDS[1]]["path"]
+    orders = pq.read_table(os.path.join(path, "orders.parquet"),
+                           columns=["o_orderkey", "o_totalprice"])
+    price = np.sort(orders["o_totalprice"].to_numpy())[-12]
+    keys = orders["o_orderkey"].to_numpy()[
+        orders["o_totalprice"].to_numpy() >= price]
+    lines = pq.read_table(os.path.join(path, "lineitem.parquet"),
+                          columns=["l_orderkey", "l_linenumber"])
+    want = sorted(zip(*(lines[c].to_numpy()[np.isin(
+        lines["l_orderkey"].to_numpy(), keys)] for c in lines.column_names)))
+    last = metrics.recent(1)[-1]["n"]
+    got = sorted(_rows(spark.sql(
+        "SELECT l_orderkey, l_linenumber FROM lineitem JOIN orders "
+        f"ON l_orderkey = o_orderkey WHERE o_totalprice >= {price}")))
+    assert got == [tuple(int(x) for x in r) for r in want]
+    built = [e for e in metrics.recent(256)
+             if e["n"] > last and e["kind"] == "expand"]
+    assert built and {e["method"] for e in built} == {"search"}
+    assert all(e["pairs"] * K.EXPAND_SEARCH_RATIO <= e["probe_rows"]
+               for e in built)
 
 
 # ---- the capacities a seed moves --------------------------------------------

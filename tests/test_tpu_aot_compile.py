@@ -267,6 +267,43 @@ def test_q15_sorted_aggregate_sums_int64_with_no_scatter_add_on_v5e(
     assert not gathers
 
 
+# tpch_sf10_q3's first join: the customer scan's 327,680 probe rows
+# against the orders' index, 1,572,864 pairs
+Q3_PROBE, Q3_PAIRS, Q3_ORDERS = 327_680, 1_572_864, 7_775_232
+
+
+def test_q3_join_expansion_reads_one_int32_probe_array_a_pair_on_v5e(
+        one_chip, as_the_session_runs):
+    """The match expansion at tpch_sf10_q3's first join, with the dense
+    table's ranges as the join hands them over (int64 ``lo`` / ``hi``,
+    the int32 build permutation). Its pairs find their probe row by a
+    scatter of the matched rows at their run starts and a running max,
+    so two ``s32`` gathers a pair are left: the build permutation's and
+    the run's int32 ``lo - offsets``. A co-sorted searchsorted used to
+    find the row, and the pair's reads of the probe side's int64 offsets
+    and ``lo`` were four ``u32`` gathers of 1,572,864 pairs, 118 of the
+    cell's 497 ms an execution (PERF.md section 5)."""
+    from spark_tpu import metrics
+    from spark_tpu.physical import kernels as K
+
+    def expand(perm, lo, hi):
+        return K.expand_join_pairs(K.JoinRanges(perm, lo, hi), Q3_PAIRS)
+
+    shapes = [jax.ShapeDtypeStruct((rows,), dt, sharding=one_chip)
+              for rows, dt in ((Q3_ORDERS, jnp.int32),
+                               (Q3_PROBE, jnp.int64), (Q3_PROBE, jnp.int64))]
+    _, text = _compile(expand, *shapes)
+    (event,) = [e for e in metrics.recent(8) if e["kind"] == "expand"][-1:]
+    assert (event["method"], event["probe_rows"], event["pairs"]) == (
+        "starts", Q3_PROBE, Q3_PAIRS)
+    gathers = _row_gathers(text, Q3_PAIRS)
+    print(f"q3 expansion {Q3_PROBE} -> {Q3_PAIRS}: {len(gathers)} gathers "
+          f"of the pairs", gathers)
+    assert len(gathers) == 2, gathers
+    assert all(re.search(r"= s32\[", g) for g in gathers), gathers
+    assert not re.findall(r" sort\(", text)
+
+
 def _leaf_splits(text, rows):
     """The X64SplitLow/High custom calls over a whole leaf column: what
     the chip runs on every execution to turn an s64 *parameter* into the
