@@ -47,11 +47,12 @@ import numpy as np
 SF = 1.0                      # TPC-H scale factor of every phase (--sf)
 QUERIES = (1, 6)              # the default run: single-table scans + aggregates
 # q3 and q5 are left out of the default run BY NAME. Their first
-# (blocking) execution compiles 27 and 30 programs that hold an XLA sort
-# (argsort / co-sort searchsorted, one per shape and dtype), and the
-# chip's compiler takes 22-69 s for each (measured on the v5e, PR 23):
-# tens of minutes cold, far past the 1200 s a default run may take.
-# ROADMAP.md Queue A (A2) has the fault; --joins runs them.
+# execution compiles each join's sizing and index programs and the whole
+# query, 10 and 19 programs; q5's hold 7 XLA sorts at SF10 (four index
+# argsorts, 42-84 s each on the chip's compiler, and the ORDER BY's
+# three), and its first execution took 351.2 s cold on the v5e, q3's
+# 607.5 s (PERF.md, PRs 40, 42): together past the 1200 s a default run
+# may take. ROADMAP.md Queue A (A2) has the rest; --joins runs them.
 JOIN_QUERIES = (3, 5)
 # Q15's aggregate alone (the view and the max over it: benchmark/queries/
 # q15_revenue.sql): join-free, so a default run covers the sort-based

@@ -611,6 +611,30 @@ def ranges_from_index(perm: jnp.ndarray, sorted_key: jnp.ndarray,
     return JoinRanges(perm, lo, hi)
 
 
+def lookup_join_ranges(build_key: jnp.ndarray, build_ok: jnp.ndarray,
+                       build_code: jnp.ndarray, probe_key: jnp.ndarray,
+                       probe_ok: jnp.ndarray, probe_code: jnp.ndarray,
+                       domain: int) -> JoinRanges:
+    """JoinRanges of a join looked up by a key in [0, ``domain``) that no
+    two live build rows a probe row looks up share, its other keys
+    packed into an int32 ``code`` each side: two dense tables over the
+    domain, set by scatters of the build rows (each key's row, and its
+    code), no sort. A probe row matches where its key has a row and that
+    row's code is its own, so the ranges hold the full keys' matches.
+    ``build_perm`` is the row table and ``lo`` the probe's key: a match's
+    build row is ``build_perm[lo]``, as from a sorted index."""
+    at = jnp.where(build_ok & (build_key >= 0) & (build_key < domain),
+                   build_key, domain)
+    row = jnp.full((domain,), -1, jnp.int32).at[at].set(
+        jnp.arange(build_key.shape[0], dtype=jnp.int32), mode="drop")
+    code = jnp.full((domain,), -1, jnp.int32).at[at].set(
+        build_code.astype(jnp.int32), mode="drop")
+    key = jnp.clip(probe_key, 0, domain - 1)
+    ok = (probe_ok & (probe_key >= 0) & (probe_key < domain)
+          & (code[key] == probe_code.astype(jnp.int32)))
+    return JoinRanges(row, key, key + ok)
+
+
 #: a join's expansion searches, instead of scattering every probe row,
 #: from this many probe rows a pair: on the v5e the scatter cost ~7.6 ms
 #: at 1,572,864 probe rows whatever the pairs, a binary search ~0.19 us

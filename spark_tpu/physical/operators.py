@@ -1214,6 +1214,12 @@ class HashAggregateExec(PhysicalPlan):
 
     def _static_direct_ok(self) -> bool:
         """Can we guarantee the direct path from schema info alone?"""
+        return self.static_slots() is not None
+
+    def static_slots(self) -> Optional[int]:
+        """The direct path's slots, every key's values and its null
+        packed, where the schema alone gives them (a dictionary string,
+        a boolean); None where it does not or they pass the limit."""
         cs = self.child.schema
         total = 1
         for g in self.groupings:
@@ -1224,13 +1230,13 @@ class HashAggregateExec(PhysicalPlan):
                 inner = E.strip_alias(g)
                 if not (isinstance(inner, E.Col) and inner.col_name in cs
                         and cs.field(inner.col_name).dictionary is not None):
-                    return False
+                    return None
                 total *= len(cs.field(inner.col_name).dictionary) + 1
             else:
-                return False
+                return None
             if total > _DIRECT_CARDINALITY_LIMIT:
-                return False
-        return True
+                return None
+        return total
 
     @property
     def schema(self) -> Schema:
@@ -1551,14 +1557,19 @@ class JoinStats(NamedTuple):
     """What a join's first execution records and later ones bind
     (``JoinExec.adaptive``): the key packing (``"hash"``, or per key its
     (offset, range)), whether each side's keys match at most once, the
-    capacity of a sized expansion (None where membership alone is asked)
-    and whether the counts came from the compiled sizing programs."""
+    capacity of a sized expansion (None where membership alone is asked),
+    whether the counts came from the compiled sizing programs and the
+    key a multi-key join is looked up by."""
 
     packing: Any
     unique_build: bool
     unique_probe: bool
     cap: Optional[int]
     sized: bool = False
+    #: where the build side is unique on one key alone, that key's index
+    #: (``planner._lookup``); the other keys are tested on the row it
+    #: finds (``K.lookup_join_ranges``)
+    lookup: Optional[int] = None
 
 
 class _JoinIndexCache(_AdaptiveStatsCache):
@@ -1662,14 +1673,22 @@ class JoinExec(PhysicalPlan):
                 and self.condition is None and self.adaptive[0] != "hash")
 
     @property
+    def sized_in_programs(self) -> bool:
+        """An equi-join of the kinds whose first execution is sized in
+        compiled programs (``planner._size_join``) and traced from then
+        on, where stats are recorded."""
+        return (bool(self.left_keys)
+                and self.how in ("inner", "left", "left_semi", "left_anti")
+                and not self.has_blocking_exprs())
+
+    @property
     def wants_join_size(self) -> bool:
         """Blocks for want of its sizes alone: a stage once the planner
         has sized it (``planner._size_join``). Where stats are not
         recorded (the chunked tiers' single-shot plans, whose packings
         differ chunk by chunk) the join runs ``execute_blocking``."""
-        return (self.adaptive is None and bool(self.left_keys)
-                and self.how in ("inner", "left", "left_semi", "left_anti")
-                and not self.has_blocking_exprs() and stats_recording())
+        return (self.adaptive is None and self.sized_in_programs
+                and stats_recording())
 
     def output_capacity(self, lcap: int, rcap: int) -> int:
         """Rows of the traced join's output over sides of these
@@ -1716,22 +1735,40 @@ class JoinExec(PhysicalPlan):
         return "expand", "fwd"
 
     def _indexed_ranges(self, build_key, build_ok, probe_key, probe_ok,
-                        child_pipes: List[Pipe], want: str):
+                        child_pipes: List[Pipe], want: str, codes=None):
         """Join ranges via the cached index when one with the right
-        orientation is bound; the live build_join_ranges otherwise.
-        Records the ``join`` build event: the rung this traced join was
-        BUILT from (``table``: dense lo / cnt lookup; ``index``: cached
-        perm + sorted key, searched; ``live``: sort + search inside the
-        program), once per trace and never per execution."""
+        orientation is bound; the live build_join_ranges otherwise; the
+        dense row and code tables of ``K.lookup_join_ranges`` where the
+        join is looked up by one key (``codes``: the other keys' codes,
+        probe side and build side). Records the ``join`` build event: the
+        rung this traced join was BUILT from (``table``: dense lo / cnt
+        lookup; ``index``: cached perm + sorted key, searched; ``live``:
+        sort + search inside the program; ``lookup``: the tables set
+        inside the program, no sort), the keys it looked up and those it
+        tested on the row it found (``looked_up``, ``tested``: ``l=r``
+        each), once per trace and never per execution."""
 
         lcap, rcap = child_pipes[0].capacity, child_pipes[1].capacity
+        keys = [f"{lk}={rk}" for lk, rk in zip(self.left_keys,
+                                               self.right_keys)]
+        lookup = getattr(self.adaptive, "lookup", None)
 
         def built(rung: str) -> None:
             _trace.built("join", rung=rung, how=self.how, orient=want,
                          build_rows=int(build_key.shape[0]),
                          probe_cap=int(probe_key.shape[0]),
                          capacity=self.output_capacity(lcap, rcap),
-                         sized=bool(getattr(self.adaptive, "sized", False)))
+                         sized=bool(getattr(self.adaptive, "sized", False)),
+                         looked_up=[k for i, k in enumerate(keys)
+                                    if lookup in (None, i)],
+                         tested=[k for i, k in enumerate(keys)
+                                 if lookup not in (None, i)])
+
+        if codes is not None:
+            built("lookup")
+            return K.lookup_join_ranges(
+                build_key, build_ok, codes[1], probe_key, probe_ok,
+                codes[0], self.adaptive[0][lookup][1])
 
         if self.index_scan is not None and len(child_pipes) > 2 \
                 and self.index_orient == want:
@@ -1887,14 +1924,18 @@ class JoinExec(PhysicalPlan):
             out.append((lo, hi))
         return out
 
-    def _traced_keys(self, lpipe: Pipe, rpipe: Pipe, packing=None):
+    def _traced_keys(self, lpipe: Pipe, rpipe: Pipe, packing=None,
+                     lookup=None):
         """Key packing with STATIC per-key (mn, rg) from adaptive stats —
         no host syncs, so the join fuses into the surrounding program.
         Sound because the planner only binds stats recorded for these
         exact (immutable) leaf arrays. packing == 'hash' reproduces the
-        hash-combined fallback; callers must then verify pairs.
-        ``packing`` is the bound stats' unless given (the sizing
-        programs run before any is bound)."""
+        hash-combined fallback; callers must then verify pairs. Where
+        the join is looked up by one key (``lookup``), that key alone is
+        packed and the others into a code each side, returned last as
+        (probe's, build's); None otherwise. ``packing`` and ``lookup``
+        are the bound stats' unless given (the sizing programs run
+        before any is bound)."""
         lenv, renv = lpipe.env(), rpipe.env()
         lks = [C.evaluate(k, lenv) for k in self.left_keys]
         rks = [C.evaluate(k, renv) for k in self.right_keys]
@@ -1904,7 +1945,9 @@ class JoinExec(PhysicalPlan):
         rvalid = jnp.ones((rpipe.capacity,), dtype=jnp.bool_)
         if packing is None:
             packing = self.adaptive[0]
+            lookup = getattr(self.adaptive, "lookup", None)
         hashed = packing == "hash"
+        lcode = rcode = 0
         prepped = []
         for ki, (lt, rt) in enumerate(zip(lks, rks)):
             if isinstance(lt.dtype, T.StringType) \
@@ -1926,8 +1969,12 @@ class JoinExec(PhysicalPlan):
                 if isinstance(lt.dtype, T.StringType) \
                         or isinstance(rt.dtype, T.StringType):
                     rg = max(rg, packing[ki][1])
-                lcomb = lcomb * rg + jnp.clip(ld - mn, 0, rg - 1)
-                rcomb = rcomb * rg + jnp.clip(rd - mn, 0, rg - 1)
+                if lookup is None or ki == lookup:
+                    lcomb = lcomb * rg + jnp.clip(ld - mn, 0, rg - 1)
+                    rcomb = rcomb * rg + jnp.clip(rd - mn, 0, rg - 1)
+                else:
+                    lcode = lcode * rg + jnp.clip(ld - mn, 0, rg - 1)
+                    rcode = rcode * rg + jnp.clip(rd - mn, 0, rg - 1)
             if lt.validity is not None:
                 lvalid = lvalid & lt.validity
             if rt.validity is not None:
@@ -1935,7 +1982,8 @@ class JoinExec(PhysicalPlan):
         if hashed:
             lcomb, rcomb = _hash_keys([p[0] for p in prepped],
                                       [p[1] for p in prepped])
-        return lcomb, lvalid, rcomb, rvalid, hashed, prepped
+        codes = None if lookup is None else (lcode, rcode)
+        return lcomb, lvalid, rcomb, rvalid, hashed, prepped, codes
 
     def trace(self, child_pipes: List[Pipe]) -> Pipe:
         """Unique-build join as a pure gather: each probe row has at most
@@ -1945,8 +1993,8 @@ class JoinExec(PhysicalPlan):
         lpipe, rpipe = child_pipes[:2]
         unique_build, unique_probe = self.adaptive[1], self.adaptive[2]
         sized_cap = self.adaptive[3] if len(self.adaptive) > 3 else None
-        lcomb, lvalid, rcomb, rvalid, hashed, prepped = self._traced_keys(
-            lpipe, rpipe)
+        lcomb, lvalid, rcomb, rvalid, hashed, prepped, codes = \
+            self._traced_keys(lpipe, rpipe)
         # strategy choice by OUTPUT CAPACITY: every op downstream of
         # this join (further joins, aggregation, sort) runs at the
         # capacity chosen here, so a selective join must shrink the
@@ -1965,7 +2013,7 @@ class JoinExec(PhysicalPlan):
         if strat == "expand":
             ranges = self._indexed_ranges(rcomb, rpipe.mask & rvalid,
                                           lcomb, lpipe.mask & lvalid,
-                                          child_pipes, "fwd")
+                                          child_pipes, "fwd", codes)
             return self._pairs_pipe(lpipe, rpipe, ranges, hashed,
                                     prepped, sized_cap)
         if strat == "member":
@@ -1973,7 +2021,7 @@ class JoinExec(PhysicalPlan):
             # expansion needed at any capacity
             ranges = self._indexed_ranges(rcomb, rpipe.mask & rvalid,
                                           lcomb, lpipe.mask & lvalid,
-                                          child_pipes, "fwd")
+                                          child_pipes, "fwd", codes)
             has = ranges.counts > 0
             keep = lpipe.mask & (has if self.how == "left_semi"
                                  else ~has)
@@ -1981,10 +2029,10 @@ class JoinExec(PhysicalPlan):
         # strat == 'build': unique-build gather at probe capacity
         ranges = self._indexed_ranges(rcomb, rpipe.mask & rvalid,
                                       lcomb, lpipe.mask & lvalid,
-                                      child_pipes, "fwd")
+                                      child_pipes, "fwd", codes)
         has = ranges.counts > 0
         b_idx = ranges.build_perm[
-            jnp.clip(ranges.lo, 0, rpipe.capacity - 1)]
+            jnp.clip(ranges.lo, 0, ranges.build_perm.shape[0] - 1)]
         if hashed:
             p_idx = jnp.arange(lpipe.capacity)
             has = has & _verify_key_pairs(prepped, p_idx, b_idx,
@@ -2068,10 +2116,14 @@ class JoinExec(PhysicalPlan):
         that replays it. Joins and adaptive aggregations emit different
         layouts on their blocking vs traced paths (expansion order vs
         gather order), so a build subtree containing one is not indexed
-        — the trace falls back to live build_join_ranges."""
+        — the trace falls back to live build_join_ranges. A join sized
+        in compiled programs (``sized_in_programs``) is the exception:
+        its first execution runs the trace every later one runs, bound
+        to the same stats, so it lays its rows out the same way."""
 
         def stable(p: PhysicalPlan) -> bool:
-            if isinstance(p, (JoinExec, HashAggregateExec)):
+            if isinstance(p, HashAggregateExec) or (
+                    isinstance(p, JoinExec) and not p.sized_in_programs):
                 return False
             return all(stable(c) for c in p.children())
 
@@ -2345,6 +2397,8 @@ class _JoinSizing(PhysicalPlan):
 
     join: JoinExec
     packing: Any = None
+    #: the key the join is looked up by (``JoinStats.lookup``)
+    lookup: Optional[int] = None
     traceable = True
 
     def children(self):
@@ -2360,16 +2414,21 @@ class _JoinSizing(PhysicalPlan):
         raise NotImplementedError("a sizing program's schema is its trace's")
 
     def _keys(self, child_pipes: List[Pipe]):
-        """(build key, build ok, probe key, probe ok, domain) packed by
-        ``packing``: the right side is built, the left probes."""
+        """(build key, build ok, probe key, probe ok, domain, codes)
+        packed by ``packing``: the right side is built, the left probes;
+        ``codes`` as ``JoinExec._traced_keys``, and the domain the
+        looked-up key's where there is one."""
         lpipe, rpipe = child_pipes
-        lkey, lvalid, rkey, rvalid, _, _ = self.join._traced_keys(
-            lpipe, rpipe, self.packing)
+        lkey, lvalid, rkey, rvalid, _, _, codes = self.join._traced_keys(
+            lpipe, rpipe, self.packing, self.lookup)
+        domain = (packed_domain(self.packing) if self.lookup is None
+                  else self.packing[self.lookup][1])
         return (rkey, rpipe.mask & rvalid, lkey, lpipe.mask & lvalid,
-                packed_domain(self.packing))
+                domain, codes)
 
     def plan_key(self):
-        return (type(self).__name__, self.packing, self.join.plan_key())
+        return (type(self).__name__, self.packing, self.lookup,
+                self.join.plan_key())
 
 
 def _one_row(cols: Dict[str, Any]) -> Pipe:
@@ -2384,7 +2443,9 @@ class JoinSizeExec(_JoinSizing):
     (``lo<i>``, ``hi<i>``: JoinExec.key_ranges), which the host packs the
     keys by; with one, the join's ``matches``, the most matches of one
     probe row (``probe_max``) and of one build row (``build_max``):
-    K.join_counts, two scatter-adds over a dense key domain."""
+    K.join_counts, two scatter-adds over a dense key domain. Looked up
+    by one key (``lookup``), the two most are that key's and the matches
+    the full keys' (``K.lookup_join_ranges``)."""
 
     def trace(self, child_pipes: List[Pipe]) -> Pipe:
         if self.packing is None:
@@ -2392,8 +2453,14 @@ class JoinSizeExec(_JoinSizing):
             for i, (lo, hi) in enumerate(self.join.key_ranges(*child_pipes)):
                 cols[f"lo{i}"], cols[f"hi{i}"] = lo, hi
             return _one_row(cols)
-        matches, probe_max, build_max = K.join_counts(
-            *self._keys(child_pipes))
+        rkey, rok, lkey, lok, domain, codes = self._keys(child_pipes)
+        matches, probe_max, build_max = K.join_counts(rkey, rok, lkey, lok,
+                                                      domain)
+        if codes is not None:
+            # the full keys' matches: the looked-up key's, tested
+            matches = K.lookup_join_ranges(
+                rkey, rok, codes[1], lkey, lok, codes[0],
+                domain).counts.astype(jnp.int64).sum()
         return _one_row({"matches": matches, "probe_max": probe_max,
                          "build_max": build_max})
 
@@ -2412,7 +2479,7 @@ class JoinIndexExec(_JoinSizing):
     orient: str = "fwd"
 
     def trace(self, child_pipes: List[Pipe]) -> Pipe:
-        rkey, rok, lkey, lok, _ = self._keys(child_pipes)
+        rkey, rok, lkey, lok, _, _ = self._keys(child_pipes)
         bkey, bok = (lkey, lok) if self.orient == "rev" else (rkey, rok)
         perm, skey, _, _ = K.make_join_index(bkey, bok, None)
         return Pipe({"perm": TV(perm, None, T.INT32, None),

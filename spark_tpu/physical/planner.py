@@ -185,6 +185,7 @@ def _run_fused(plan: P.PhysicalPlan) -> Batch:
             extra=_stable_adaptive_snapshot(plan))
         _STAGE_CACHE[key] = entry
     jitted, schema_box = entry
+    trace.count("program")
     if fresh:
         # first call traces + XLA-compiles (or loads from the
         # persistent disk cache — metrics.compile_cache_stats says
@@ -301,28 +302,43 @@ def _bind(plan: P.PhysicalPlan):
 def _run_bound(physical: P.PhysicalPlan, plan: P.PhysicalPlan, sk,
                cap) -> Batch:
     """Run a bound physical plan: fuse what we can, block where we
-    must. ``physical`` is the plan before ``_bind``: a first execution
+    must. An execution with no recorded output capacity is a first one:
+    it records that capacity (``_first_execution``) and a ``first_exec``
+    build event of the compiled programs it ran and the sorts it built."""
+    if cap is not None:
+        return _execute(P.CompactExec(plan, cap))
+    if not P.stats_recording():
+        return _execute(plan)
+    with trace.tally() as counts:
+        batch, bound = _first_execution(physical, plan, sk)
+    trace.built("first_exec", programs=counts["program"],
+                sorts=counts["sort"], bound=bound)
+    return batch
+
+
+def _first_execution(physical: P.PhysicalPlan, plan: P.PhysicalPlan, sk):
+    """``physical`` is the plan before ``_bind``: a first execution
     whose blocking operators all block for want of their stats alone
     records them (``_size``), binds the plan again, and runs it as the
     one stage every later execution runs, where the plan's operators
-    bound its output within one capacity bucket (``_rows_bound``)."""
-    if cap is None and P.stats_recording() \
-            and not stage.fully_traceable(plan, P.BatchScanExec) \
+    bound its output within one capacity bucket (``_rows_bound``).
+    Returns the batch and what bounded it (``none`` where nothing did,
+    and the live rows were counted for the next execution)."""
+    if not stage.fully_traceable(plan, P.BatchScanExec) \
             and _becomes_traceable(plan):
         _size(plan)
         plan, sk, cap = _bind(physical)
-        rows = _rows_bound(plan)
+        rows, bound = _rows_bound(plan) or (None, "none")
         if cap is None and rows is not None and rows <= _capacity_bucket():
             # what the first execution would record for any live count
             cap = _capacity_bucket()
             _OUTPUT_STATS.put(sk, cap)
-    if cap is not None:
-        return _execute(P.CompactExec(plan, cap))
+        if cap is not None:
+            return _execute(P.CompactExec(plan, cap)), bound
     batch = _execute(plan)
-    if P.stats_recording():
-        live = int(np.asarray(batch.data.row_mask).sum())  # 1st run only
-        _OUTPUT_STATS.put(sk, K.bucket(live, _capacity_bucket()))
-    return batch
+    live = int(np.asarray(batch.data.row_mask).sum())  # 1st run only
+    _OUTPUT_STATS.put(sk, K.bucket(live, _capacity_bucket()))
+    return batch, "none"
 
 
 def _wants_stats(plan: P.PhysicalPlan) -> bool:
@@ -343,13 +359,18 @@ def _becomes_traceable(plan: P.PhysicalPlan) -> bool:
 
 
 def _rows_bound(plan: P.PhysicalPlan):
-    """The most live rows ``plan`` can return, where its operators alone
-    say so (a LIMIT, an aggregate without keys); None elsewhere."""
+    """(the most live rows ``plan`` can return, which operator says so)
+    where its operators alone say so: a LIMIT (``limit``), an aggregate
+    without keys (``keyless``) or one whose keys' slots are known when
+    it is traced (``direct``); None elsewhere."""
     if isinstance(plan, P.LimitExec):
-        inner = _rows_bound(plan.child)
-        return plan.n if inner is None else min(plan.n, inner)
-    if isinstance(plan, P.HashAggregateExec) and not plan.groupings:
-        return 1
+        rows, bound = _rows_bound(plan.child) or (plan.n, "limit")
+        return min(plan.n, rows), bound if rows < plan.n else "limit"
+    if isinstance(plan, P.HashAggregateExec):
+        if not plan.groupings:
+            return 1, "keyless"
+        slots = plan.static_slots()
+        return None if slots is None else (slots, "direct")
     if isinstance(plan, (P.ProjectExec, P.FilterExec, P.SortExec,
                          P.CompactExec)):
         return _rows_bound(plan.child)
@@ -386,19 +407,46 @@ def _host_row(plan: P.PhysicalPlan) -> dict:
     return {f.name: int(v[0]) for f, v in zip(batch.schema.fields, values)}
 
 
-def _packing(join: P.JoinExec, ranges: dict):
-    """The key packing from the observed ranges: a dictionary string key
-    by its codes, an integer key by ``K.key_packing``; ``"hash"`` where
-    the packed domain would pass 62 bits."""
-    packing, total = [], 1
-    for i, string in enumerate(join.key_is_string()):
-        lo, hi = ranges[f"lo{i}"], ranges[f"hi{i}"]
-        mn, rg = (0, hi + 1) if string else K.key_packing(lo, hi)
+def _key_packings(join: P.JoinExec, ranges: dict) -> tuple:
+    """Per key, its (offset, range) from the observed ranges: a
+    dictionary string key by its codes, an integer key by
+    ``K.key_packing``."""
+    return tuple((0, ranges[f"hi{i}"] + 1) if string
+                 else K.key_packing(ranges[f"lo{i}"], ranges[f"hi{i}"])
+                 for i, string in enumerate(join.key_is_string()))
+
+
+def _packing(keys: tuple):
+    """The keys packed together, or ``"hash"`` where the packed domain
+    would pass 62 bits."""
+    total = 1
+    for _, rg in keys:
         total *= rg
         if total > (1 << 62):
             return "hash"
-        packing.append((mn, rg))
-    return tuple(packing)
+    return keys
+
+
+def _lookup(sides: P.JoinExec, keys: tuple, packing):
+    """A multi-key join whose packed keys take no dense table (hashed,
+    or a domain past ``K.JOIN_TABLE_MAX``) and whose build side is
+    unique on one key alone, whose own domain does: that key's index and
+    the join's counts looked up by it, its other keys packed into an
+    int32 code and tested on the row it finds (``K.lookup_join_ranges``:
+    no sort, and no index to record). Keys are tried widest first, since
+    a key narrower than the build side cannot be unique on it. None
+    where no key alone is."""
+    domain = P.packed_domain(packing)
+    if len(keys) < 2 or (domain is not None and domain <= K.JOIN_TABLE_MAX):
+        return None
+    for i in sorted(range(len(keys)), key=lambda i: -keys[i][1]):
+        rest = P.packed_domain(tuple(k for j, k in enumerate(keys) if j != i))
+        if keys[i][1] > K.JOIN_TABLE_MAX or rest >= 1 << 31:
+            continue
+        counted = _host_row(P.JoinSizeExec(sides, keys, i))
+        if counted["probe_max"] <= 1:
+            return i, counted
+    return None
 
 
 def _aux_batch(named) -> Batch:
@@ -417,25 +465,39 @@ def _size_join(plan: P.JoinExec, batches) -> P.JoinExec:
     where it ran operation by operation, some eighty programs each XLA
     sort among which the chip's compiler takes tens of seconds over
     (ROADMAP A2): the keys' ranges (a host sync packs them), the matches
-    (the host sync that sizes the output and finds a unique side), the
-    build side's index. Records the stats and the index for re-executions
-    of these leaves (``_bind_adaptive``) and hands back the join bound to
-    them over its children's batches: a traceable stage."""
+    (the host sync that sizes the output and finds a unique side; for a
+    multi-key join past the dense tables, first looked up by one key at
+    a time, ``_lookup``), the build side's index. Records the stats and
+    the index for re-executions of these leaves (``_bind_adaptive``) and
+    hands back the join bound to them over its children's batches: a
+    traceable stage."""
     sides = dataclasses.replace(plan, left=P.BatchScanExec(batches[0]),
                                 right=P.BatchScanExec(batches[1]))
     sk = plan.stats_key()
     span = trace.span("join.size", how=plan.how)
     with span:
-        packing = _packing(plan, _host_row(P.JoinSizeExec(sides)))
-        counted = _host_row(P.JoinSizeExec(sides, packing))
-        unique_build = counted["probe_max"] <= 1
-        if plan.how in ("left_semi", "left_anti") \
-                and plan.condition is None and packing != "hash":
-            stats = P.JoinStats(packing, unique_build, False, None, True)
+        keys = _key_packings(plan, _host_row(P.JoinSizeExec(sides)))
+        packing = _packing(keys)
+        lookup = _lookup(sides, keys, packing)
+        if lookup is not None:
+            # at most one candidate a probe row, by the key the build
+            # side is unique on; the expansion is sized by the full keys
+            i, counted = lookup
+            packing = keys
+            stats = P.JoinStats(keys, True, False,
+                                K.relative_bucket(counted["matches"]), True,
+                                i)
         else:
-            stats = P.JoinStats(packing, unique_build,
-                                counted["build_max"] <= 1,
-                                K.relative_bucket(counted["matches"]), True)
+            counted = _host_row(P.JoinSizeExec(sides, packing))
+            unique_build = counted["probe_max"] <= 1
+            if plan.how in ("left_semi", "left_anti") \
+                    and plan.condition is None and packing != "hash":
+                stats = P.JoinStats(packing, unique_build, False, None, True)
+            else:
+                stats = P.JoinStats(packing, unique_build,
+                                    counted["build_max"] <= 1,
+                                    K.relative_bucket(counted["matches"]),
+                                    True)
         sides.adaptive = stats
         lcap, rcap = batches[0].capacity, batches[1].capacity
         _, orient = sides._strategy(stats.unique_build, stats.unique_probe,
@@ -443,8 +505,8 @@ def _size_join(plan: P.JoinExec, batches) -> P.JoinExec:
         span.attrs.update(rows=counted["matches"],
                           capacity=sides.output_capacity(lcap, rcap))
         P._JOIN_STATS.put(sk, stats)
-        if plan.index_layout_stable(orient):
-            ib = _execute(P.JoinIndexExec(sides, packing, orient))
+        if lookup is None and plan.index_layout_stable(orient):
+            ib = _execute(P.JoinIndexExec(sides, packing, orient=orient))
             tb = None
             domain = P.packed_domain(packing)
             if domain is not None and domain <= K.JOIN_TABLE_MAX:

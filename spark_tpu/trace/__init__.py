@@ -44,6 +44,8 @@ and fault points get.
 
 from __future__ import annotations
 
+import collections
+import contextvars
 import itertools
 import os
 import random
@@ -149,6 +151,11 @@ BUILD_EVENTS = frozenset({
                  # _AGG_STATS count k was sized from; None when direct)
     "expand",    # kernels.expand_join_pairs: one per match expansion
                  # built; method (starts/search), probe_rows, pairs
+    "first_exec",  # planner._run_bound: one per first execution of a
+                   # plan (the one that records its output's stats);
+                   # programs (compiled stages it ran), sorts (``sort``
+                   # events built in it), bound (limit/keyless/direct/none:
+                   # what bounded its output to one capacity bucket)
 })
 
 #: prefix of the ``jax.named_scope`` round each operator's ``trace()``
@@ -171,9 +178,35 @@ INNER_SCOPES = frozenset({
 })
 
 
+#: what is counted while a ``tally()`` is open in this context
+_TALLY: contextvars.ContextVar[Optional[collections.Counter]] = \
+    contextvars.ContextVar("build_tally", default=None)
+
+
+@contextmanager
+def tally() -> Iterator[collections.Counter]:
+    """Count, in this context, the build events recorded by kind and
+    whatever else ``count`` names (the planner's compiled programs run),
+    while the block runs."""
+    counts: collections.Counter = collections.Counter()
+    token = _TALLY.set(counts)
+    try:
+        yield counts
+    finally:
+        _TALLY.reset(token)
+
+
+def count(name: str) -> None:
+    """One more ``name`` in the open ``tally()``, if any."""
+    counts = _TALLY.get()
+    if counts is not None:
+        counts[name] += 1
+
+
 def built(kind: str, **fields: Any) -> None:
     """Record a build event (``BUILD_EVENTS``) in the metrics ring."""
     metrics.record(kind, **fields)
+    count(kind)
 
 
 def operator_scope(plan: Any):
